@@ -333,6 +333,9 @@ class FedAvg(Algorithm):
         # budget checks the same predicate the round program allocates by.
         materialize = self.materializes_client_stack
         chunk = cfg.client_chunk_size
+        # Devices the client axis is split over: chunks take their share
+        # of clients from every device (parallel/engine.chunked_accumulate).
+        shards = cfg.mesh_devices or 1
         frac = cfg.participation_fraction
         n_participants = cfg.cohort_size(n_clients)
         # Failure model + quorum policy (robustness/faults.py): every
@@ -535,7 +538,7 @@ class FedAvg(Algorithm):
                 trees, chunk,
                 make_compute(global_params, lr_scale),
                 zero_acc(global_params),
-                per_chunk=payload_key,
+                per_chunk=payload_key, shards=shards,
             )
             return agg, ns, tm
 
@@ -589,7 +592,7 @@ class FedAvg(Algorithm):
                     partial, (ns_g, tm_g) = chunked_accumulate(
                         trees_g, chunk, compute,
                         zero_acc(global_params),
-                        per_chunk=gk,
+                        per_chunk=gk, shards=shards,
                     )
                 agg = jax.tree_util.tree_map(jnp.add, agg, partial)
                 if metrics_full is None:

@@ -303,6 +303,7 @@ class SignSGD(Algorithm):
                             chunked_accumulate(
                                 trees, chunk,
                                 compute, acc0,
+                                shards=cfg.mesh_devices or 1,
                             )
                         )
                     # sign of the summed signs: the majority vote

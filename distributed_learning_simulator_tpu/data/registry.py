@@ -129,6 +129,12 @@ def _to_grayscale(ds: Dataset) -> Dataset:
     )
 
 
+def dataset_file(name: str, data_dir: str | None = None) -> str:
+    """Where :func:`get_dataset` looks for ``name``'s local ``.npz``."""
+    data_dir = data_dir or os.environ.get("DLS_DATA_DIR", "/root/data")
+    return os.path.join(data_dir, f"{name.lower()}.npz")
+
+
 def get_dataset(
     name: str,
     data_dir: str | None = None,
@@ -148,7 +154,6 @@ def get_dataset(
     reference's ``dataset_args`` heterogeneity knob (simulator_backup.py:50).
     """
     key = name.lower()
-    data_dir = data_dir or os.environ.get("DLS_DATA_DIR", "/root/data")
     if key == "digits":
         ds = _load_digits(key, seed=seed)
     elif key == "synthetic":
@@ -160,7 +165,7 @@ def get_dataset(
         )
     elif key in _SHAPES:
         shape, num_classes, full_train, full_test = _SHAPES[key]
-        npz = os.path.join(data_dir, f"{key}.npz")
+        npz = dataset_file(key, data_dir)
         if os.path.exists(npz):
             ds = _load_npz(npz, key, num_classes)
         else:

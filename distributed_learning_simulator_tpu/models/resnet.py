@@ -77,10 +77,13 @@ def _use_pallas_gn() -> bool:
     in-context rejection — the jnp path stands as the measured floor."""
     if not _GN_PALLAS_ENABLED:
         return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # backend not initialized yet
-        return False
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise RuntimeError(
+            "DLS_GN_PALLAS=1 selects the Mosaic GroupNorm kernels, which "
+            f"exist only on TPU; the default backend is {backend!r}"
+        )
+    return True
 
 
 def pack_folded_kernel(w):

@@ -303,7 +303,8 @@ def make_local_train_fn(
     return local_train
 
 
-def chunked_accumulate(trees, chunk: int, compute_fn, acc0, per_chunk=None):
+def chunked_accumulate(trees, chunk: int, compute_fn, acc0, per_chunk=None,
+                       shards: int = 1):
     """Sequential-over-chunks client scan with remainder handling — the ONE
     copy of the slice/reshape/scan/concatenate discipline shared by the
     FedAvg fused reduction (algorithms/fedavg.py train_and_reduce) and the
@@ -321,13 +322,47 @@ def chunked_accumulate(trees, chunk: int, compute_fn, acc0, per_chunk=None):
     ``partial`` is tree-added into ``acc0``; ``per_client`` (leading chunk
     axis, None allowed) is restacked to ``[C, ...]``. Returns
     ``(accumulated, per_client_full)``.
+
+    ``shards``: how many devices the client axis is split over
+    (``config.mesh_devices``). Device ``d`` holds the contiguous clients
+    ``[d*C/shards, (d+1)*C/shards)``, so a chunk of ``chunk`` CONSECUTIVE
+    clients lives on one or two devices: cutting chunks that way makes
+    the SPMD partitioner all-gather the population and train every
+    client on every device. Instead each chunk takes ``chunk // shards``
+    clients from EVERY shard — a reshape/transpose of the sharded axis
+    that moves no data, leaves each scan step split evenly over the
+    devices, and keeps ``chunk`` the number of clients in flight across
+    the mesh. Which clients share a chunk is bookkeeping: per-client
+    results return in client order and only the order of the partial
+    sums differs from ``shards=1``. A cohort that does not divide into
+    ``shards`` falls back to consecutive chunks.
     """
     n = jax.tree_util.tree_leaves(trees)[0].shape[0]
-    n_chunks, rem = divmod(n, chunk)
-    head = jax.tree_util.tree_map(lambda a: a[: n - rem], trees)
-    xs = jax.tree_util.tree_map(
-        lambda a: a.reshape((n_chunks, chunk) + a.shape[1:]), head
-    )
+    if n % shards:
+        shards = 1
+    per = n // shards
+    local = max(1, chunk // shards)
+    n_chunks, rem = divmod(per, local)
+
+    def by_shard(a):
+        return a.reshape((shards, per) + a.shape[1:])
+
+    def chunked(a):
+        # [C, ...] -> [n_chunks, shards * local, ...]
+        a = by_shard(a)[:, : per - rem]
+        a = a.reshape((shards, n_chunks, local) + a.shape[2:])
+        return jnp.swapaxes(a, 0, 1).reshape(
+            (n_chunks, shards * local) + a.shape[3:]
+        )
+
+    def unchunked(a):
+        # [n_chunks, shards * local, ...] -> [shards, per - rem, ...]
+        a = a.reshape((n_chunks, shards, local) + a.shape[2:])
+        return jnp.swapaxes(a, 0, 1).reshape(
+            (shards, per - rem) + a.shape[3:]
+        )
+
+    xs = jax.tree_util.tree_map(chunked, trees)
     keys = None
     if per_chunk is not None:
         keys = jax.random.split(per_chunk, n_chunks + 1)
@@ -342,19 +377,27 @@ def chunked_accumulate(trees, chunk: int, compute_fn, acc0, per_chunk=None):
         return jax.tree_util.tree_map(jnp.add, acc, partial), per_client
 
     acc, stacked = jax.lax.scan(body, acc0, scan_xs)
-    per_client = jax.tree_util.tree_map(
-        lambda a: a.reshape((n - rem,) + a.shape[2:]), stacked
-    )
+    per_client = jax.tree_util.tree_map(unchunked, stacked)
     if rem:
-        tail = jax.tree_util.tree_map(lambda a: a[n - rem:], trees)
+        tail = jax.tree_util.tree_map(
+            lambda a: by_shard(a)[:, per - rem:].reshape(
+                (shards * rem,) + a.shape[1:]
+            ),
+            trees,
+        )
         partial_t, per_client_t = compute_fn(
             tail, None if keys is None else keys[-1]
         )
         acc = jax.tree_util.tree_map(jnp.add, acc, partial_t)
         per_client = jax.tree_util.tree_map(
-            lambda a, b: jnp.concatenate([a, b], axis=0),
+            lambda a, b: jnp.concatenate(
+                [a, b.reshape((shards, rem) + b.shape[1:])], axis=1
+            ),
             per_client, per_client_t,
         )
+    per_client = jax.tree_util.tree_map(
+        lambda a: a.reshape((n,) + a.shape[2:]), per_client
+    )
     return acc, per_client
 
 

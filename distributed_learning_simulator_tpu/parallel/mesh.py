@@ -13,8 +13,6 @@ spans all processes' devices.
 
 from __future__ import annotations
 
-import os
-
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
@@ -31,45 +29,11 @@ def make_mesh(num_devices: int | None = None, axis_name: str = CLIENT_AXIS) -> M
     devices = jax.devices()
     if num_devices is not None:
         if num_devices > len(devices):
-            # A TPU plugin may take platform priority over JAX_PLATFORMS=cpu;
-            # the virtual-CPU devices (xla_force_host_platform_device_count)
-            # are still reachable through the explicit cpu backend. Opt-in
-            # only (DLS_ALLOW_CPU_MESH_FALLBACK=1): a production launch with
-            # a device shortfall must fail fast, not quietly train on host
-            # CPU. dryrun/sharding-validation entry points set the flag.
-            allow_fallback = os.environ.get(
-                "DLS_ALLOW_CPU_MESH_FALLBACK", ""
-            ).lower() in ("1", "true")
-            try:
-                cpu_devices = jax.devices("cpu")
-            except RuntimeError:
-                cpu_devices = []
-            if allow_fallback and num_devices <= len(cpu_devices):
-                from distributed_learning_simulator_tpu.utils.logging import (
-                    get_logger,
-                )
-
-                get_logger().warning(
-                    "mesh fallback: %d devices requested but only %d on "
-                    "platform %r; using %d virtual HOST-CPU devices "
-                    "(orders of magnitude slower than accelerators — "
-                    "intended for sharding validation, not production)",
-                    num_devices, len(devices), devices[0].platform,
-                    num_devices,
-                )
-                devices = cpu_devices
-            else:
-                hint = (
-                    "raise XLA_FLAGS=--xla_force_host_platform_device_count"
-                    if allow_fallback
-                    else "set DLS_ALLOW_CPU_MESH_FALLBACK=1 to validate "
-                    "sharding on virtual host-CPU devices"
-                )
-                raise ValueError(
-                    f"requested {num_devices} mesh devices but only "
-                    f"{len(devices)} visible "
-                    f"(and {len(cpu_devices)} cpu devices; {hint})"
-                )
+            raise ValueError(
+                f"requested {num_devices} mesh devices but only "
+                f"{len(devices)} visible on platform "
+                f"{devices[0].platform!r}"
+            )
         devices = devices[:num_devices]
     return Mesh(np.array(devices), (axis_name,))
 

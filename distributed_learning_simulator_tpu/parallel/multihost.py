@@ -22,29 +22,6 @@ from distributed_learning_simulator_tpu.utils.logging import get_logger
 _initialized_coordinator: str | None = None
 
 
-def distributed_initialized() -> bool:
-    """Whether jax.distributed is up in this process.
-
-    ``jax.distributed.is_initialized`` exists only in some jax
-    versions; where it is absent, the presence of the distributed
-    coordination client (the state ``jax.distributed.initialize``
-    creates) is the same fact.
-    """
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    try:
-        from jax._src import distributed as _dist
-
-        state = _dist.global_state
-        return (
-            getattr(state, "client", None) is not None
-            or getattr(state, "service", None) is not None
-        )
-    except Exception:  # pragma: no cover - exotic jax builds
-        return False
-
-
 def initialize_multihost(
     coordinator_address: str | None = None,
     num_processes: int | None = None,
@@ -69,7 +46,7 @@ def initialize_multihost(
         v is not None
         for v in (coordinator_address, num_processes, process_id)
     )
-    if distributed_initialized():
+    if jax.distributed.is_initialized():
         # Safe to re-call in an already-distributed process (a second
         # run_simulation in the same driver, a retry) — but explicit flags
         # must MATCH the live topology: reusing a single-process runtime
@@ -117,15 +94,9 @@ def initialize_multihost(
             # "Multiprocess computations aren't implemented on the CPU
             # backend". Gloo ships in jaxlib; the knob must be set
             # BEFORE the backend initializes, which this call precedes
-            # by contract (it runs before any device query). Guarded:
-            # absent on exotic builds, and a no-op for TPU/GPU (their
-            # collectives ride ICI/NCCL regardless).
-            try:
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo"
-                )
-            except (AttributeError, ValueError):
-                pass
+            # by contract (it runs before any device query). A no-op
+            # for TPU/GPU (their collectives ride ICI/NCCL regardless).
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
             jax.distributed.initialize(
                 coordinator_address=coordinator_address,
                 num_processes=num_processes,

@@ -61,6 +61,7 @@ from distributed_learning_simulator_tpu.data.residency import (
     tree_bytes,
 )
 from distributed_learning_simulator_tpu.telemetry import clock
+from distributed_learning_simulator_tpu.utils.logging import get_logger
 
 # Straggler injection for the distributed-tracing tests (chaos-harness
 # precedent, robustness/chaos.py): when set, this host sleeps that many
@@ -145,6 +146,12 @@ class CohortStreamer:
             self._cpu = jax.local_devices(backend="cpu")[0]
         except (RuntimeError, IndexError):
             self._cpu = None
+        get_logger().info(
+            "cohort replay runs on %s",
+            self._cpu if self._cpu is not None
+            else f"the default device ({jax.local_devices()[0]}): "
+                 "no local cpu backend",
+        )
         self._pool = ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="cohort-upload"
         )

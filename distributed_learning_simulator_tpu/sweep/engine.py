@@ -75,6 +75,9 @@ from distributed_learning_simulator_tpu.parallel.mesh import (
     shard_client_data,
 )
 from distributed_learning_simulator_tpu.sweep.spec import SweepSpec
+from distributed_learning_simulator_tpu.utils.compile_cache import (
+    configure_compilation_cache,
+)
 from distributed_learning_simulator_tpu.utils.logging import get_logger
 from distributed_learning_simulator_tpu.utils.reporting import (
     build_round_record,
@@ -344,13 +347,7 @@ class SweepScheduler:
         # Same process-global compile-cache discipline as run_simulation:
         # honor (or reset) the config's persistent-cache setting before
         # any trace/compile happens.
-        jax.config.update(
-            "jax_compilation_cache_dir", cfg.compilation_cache_dir or None
-        )
-        if cfg.compilation_cache_dir:
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 1.0
-            )
+        configure_compilation_cache(cfg.compilation_cache_dir)
         if not lean_supported(cfg):
             t0 = time.perf_counter()
             result = run_simulation(
@@ -702,11 +699,7 @@ def run_sweep(spec_or_config, dataset=None, client_data=None) -> dict:
     logger = get_logger()
     strategy = spec.resolve_strategy()
     base = spec.base
-    if base.compilation_cache_dir:
-        jax.config.update(
-            "jax_compilation_cache_dir", base.compilation_cache_dir
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    configure_compilation_cache(base.compilation_cache_dir)
     dataset, client_data = _shared_data(base, dataset, client_data)
     crash_after = os.environ.get(_CRASH_ENV)
     crash_after = int(crash_after) if crash_after else None

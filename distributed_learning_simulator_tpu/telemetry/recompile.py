@@ -38,11 +38,6 @@ import threading
 
 import jax
 
-try:  # the unregister helpers are private; degrade to a dead-listener guard
-    from jax._src import monitoring as _monitoring_src
-except Exception:  # pragma: no cover - import layout change
-    _monitoring_src = None
-
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _COMPILE_LOGGER = "jax._src.dispatch"
 # "Compiling <fn> with global shapes…" (pxla) and "Persistent compilation
@@ -147,15 +142,7 @@ class RecompileMonitor:
         if self._handler is not None:
             logging.getLogger(_COMPILE_LOGGER).removeHandler(self._handler)
             self._handler = None
-        if _monitoring_src is not None:
-            try:
-                _monitoring_src._unregister_event_duration_listener_by_callback(
-                    self._on_duration
-                )
-            except Exception:
-                # Listener stays registered but self._active gates it to a
-                # no-op; harmless beyond a dict entry.
-                pass
+        jax.monitoring.unregister_event_duration_listener(self._on_duration)
 
     def __enter__(self) -> "RecompileMonitor":
         return self.start()

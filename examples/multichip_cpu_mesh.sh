@@ -3,8 +3,8 @@
 # the client axis gets PartitionSpec("clients") over a 1-D mesh and
 # aggregation lowers to cross-device collectives. On a real pod slice, drop
 # the two env vars and set --mesh_devices to the real chip count.
+JAX_PLATFORMS=cpu \
 XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-DLS_ALLOW_CPU_MESH_FALLBACK=1 \
 python -m distributed_learning_simulator_tpu.simulator \
   --dataset_name synthetic --model_name mlp \
   --distributed_algorithm fed \

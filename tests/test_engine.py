@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from distributed_learning_simulator_tpu.models.registry import get_model, init_params
 from distributed_learning_simulator_tpu.parallel.engine import (
@@ -108,3 +109,43 @@ def test_flattened_eval_matches_unflattened(tiny_dataset):
     np.testing.assert_allclose(
         float(out1["loss"]), float(out2["loss"]), atol=1e-5
     )
+
+
+@pytest.mark.parametrize("n,chunk,shards", [
+    (24, 8, 4),    # even split: 2 clients of every shard per chunk
+    (24, 10, 4),   # chunk not a multiple of shards, per-shard remainder
+    (16, 3, 8),    # chunk smaller than the shard count
+    (10, 4, 4),    # cohort does not divide into shards: consecutive chunks
+])
+def test_chunked_accumulate_shard_local_chunks(n, chunk, shards):
+    """Chunks that take their clients from every shard (the mesh path)
+    give the same reduction and the same per-client results, in client
+    order, as consecutive chunks; only the grouping differs."""
+    from distributed_learning_simulator_tpu.parallel.engine import (
+        chunked_accumulate,
+    )
+
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(n, 3)).astype(np.float32))
+    w = jnp.asarray(rng.uniform(size=(n,)).astype(np.float32))
+    seen = []
+
+    def compute(trees, _key):
+        xc, wc, none = trees
+        assert none is None
+        seen.append(xc.shape[0])
+        return jnp.tensordot(wc, xc, axes=(0, 0)), (xc * 2.0, None)
+
+    def run(shards):
+        seen.clear()
+        return chunked_accumulate(
+            (x, w, None), chunk, compute, jnp.zeros(3), shards=shards
+        )
+
+    acc1, (per1, _) = run(1)
+    acc, (per, _) = run(shards)
+    assert max(seen) <= max(chunk, shards)  # clients in flight stay bounded
+    np.testing.assert_allclose(acc, acc1, rtol=1e-5)
+    np.testing.assert_allclose(acc, np.asarray(w) @ np.asarray(x), rtol=1e-5)
+    np.testing.assert_array_equal(per, per1)
+    np.testing.assert_array_equal(per, np.asarray(x) * 2.0)

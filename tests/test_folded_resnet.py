@@ -303,8 +303,9 @@ def test_pallas_gn_matches_jnp():
     """Pallas GroupNorm forward (ops/gn_pallas.py) vs the jnp form: stats
     to f32-reduction tolerance, outputs within one bf16 ulp. The suite
     pins the CPU backend (conftest), where the Mosaic kernels don't
-    exist — this test runs when invoked on a TPU host directly:
-    ``JAX_PLATFORMS= python -m pytest tests/test_folded_resnet.py -k pallas``.
+    exist — on a TPU host run it past the conftest:
+    ``python -m pytest --noconftest tests/test_folded_resnet.py -k pallas``
+    (passed on a TPU v5e with jax 0.9.0 / libtpu 0.0.34, PR 21).
     """
     import pytest
 
@@ -341,3 +342,15 @@ def test_pallas_gn_matches_jnp():
     )
     # one output ulp at these magnitudes
     assert d.max() <= 0.0625, d.max()
+
+
+def test_pallas_gn_requested_off_tpu_is_an_error(monkeypatch):
+    """DLS_GN_PALLAS=1 must not hand back the jnp path in silence on a
+    backend that has no Mosaic."""
+    import pytest
+
+    import distributed_learning_simulator_tpu.models.resnet as R
+
+    monkeypatch.setattr(R, "_GN_PALLAS_ENABLED", True)
+    with pytest.raises(RuntimeError, match="only on TPU"):
+        R._use_pallas_gn()
