@@ -6,20 +6,23 @@ and before this subsystem the reproduction measured cost as one opaque
 layer every execution path (vmap simulator, threaded oracle, multihost
 engine) reports through:
 
-* :mod:`.phases` — per-round phase timers (client step / aggregate /
-  eval / host-sync / post-round) around the existing ``annotate()``
-  regions, with ``block_until_ready`` fencing only when
+* :mod:`.clock` + :mod:`.spans` — the ONE monotonic/wall clock
+  convention every timing subsystem shares, and the ONE span recorder
+  of a run: alive from the first line of ``run_simulation`` to its
+  return whenever ``telemetry_level != 'off'``, one call per boundary
+  (profiler annotation + span with parent and round + phase
+  accumulation), set-up kept whole, counters for rounds, host syncs and
+  jax's trace/lower/compile durations, readable afterwards through
+  ``spans.last_run()``. ``span_trace='on'`` adds the per-host
+  ``spans_<host_id>.jsonl`` journals (DCN barrier waits, prefetch
+  occupancy, checkpoint barriers), the crash flight recorder and
+  ``scripts/trace_timeline.py``'s cross-host timeline
+  (docs/OBSERVABILITY.md § Spans).
+* :mod:`.phases` — the per-round phase accumulation ``phase_seconds`` is
+  built from (client step / aggregate / eval / host-sync / post-round),
+  fed by the recorder's spans; ``block_until_ready`` fencing only when
   ``telemetry_level='detailed'`` asks for it, so the default program is
-  untouched.
-* :mod:`.clock` + :mod:`.spans` — the distributed tracing layer:
-  the ONE monotonic/wall clock convention every timing subsystem
-  shares, and the per-host span recorder (``span_trace='on'``) that
-  journals phase boundaries, DCN barrier waits (the cross-host skew
-  signal), prefetch worker occupancy, and checkpoint barriers to
-  ``spans_<host_id>.jsonl`` — doubling as a crash flight recorder;
-  ``scripts/trace_timeline.py`` stitches all hosts' journals into a
-  perfetto-loadable timeline (docs/OBSERVABILITY.md § Distributed
-  tracing).
+  untouched. The threaded oracle times its own phases with it.
 * :mod:`.recompile` — an XLA recompilation counter hooked on
   ``jax.monitoring`` compile events (names recovered from the
   ``jax_log_compiles`` log stream): any compile after the warmup round
@@ -82,9 +85,11 @@ from distributed_learning_simulator_tpu.telemetry.recompile import (
     log_round_compiles,
 )
 from distributed_learning_simulator_tpu.telemetry.spans import (
-    SpanPhaseTimer,
+    NullTracer,
     SpanRecorder,
     journal_filename,
+    last_run,
+    start_run,
 )
 from distributed_learning_simulator_tpu.telemetry.topologies import (
     TOPOLOGIES,
@@ -113,9 +118,9 @@ __all__ = [
     "ClientStats",
     "ClientValuation",
     "NullPhaseTimer",
+    "NullTracer",
     "PhaseTimer",
     "RecompileMonitor",
-    "SpanPhaseTimer",
     "SpanRecorder",
     "Topology",
     "ValuationAuditor",
@@ -130,6 +135,7 @@ __all__ = [
     "get_topology",
     "hbm_limit_bytes",
     "journal_filename",
+    "last_run",
     "ledger_totals",
     "log_round_compiles",
     "make_phase_timer",
@@ -137,5 +143,6 @@ __all__ = [
     "pearson_corr",
     "predict_round",
     "spearman_corr",
+    "start_run",
     "valuation_record",
 ]

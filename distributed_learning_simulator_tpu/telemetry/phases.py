@@ -1,4 +1,10 @@
-"""Per-round phase timing around the simulator's ``annotate()`` regions.
+"""Per-round phase accounting: the ``phase_seconds`` of a round's record.
+
+In ``run_simulation`` the clocks are the span recorder's
+(telemetry/spans.py): one ``tracer.span(name, ..., phase=<phase>)`` per
+boundary reads the clock once per edge and feeds the duration through
+:meth:`PhaseTimer.add`. The threaded oracle (execution/threaded.py) has
+no recorder and times its phases with :meth:`PhaseTimer.phase`.
 
 A round's wall-clock splits into: ``client_step`` (the fused round
 program's dispatch — local training AND the in-program aggregation; XLA
@@ -71,11 +77,21 @@ class PhaseTimer:
         try:
             yield box
         finally:
-            if self._fence and box.value is not None:
-                jax.block_until_ready(box.value)
-            dt = clock.monotonic() - t0
-            acc = self._acc.setdefault(round_idx, {})
-            acc[name] = acc.get(name, 0.0) + dt
+            self.fence(box)
+            self.add(round_idx, name, clock.monotonic() - t0)
+
+    def fence(self, box) -> None:
+        """Wait for what the phase body parked in ``box`` (``detailed``
+        only); called before the clock stops."""
+        if self._fence and box.value is not None:
+            jax.block_until_ready(box.value)
+
+    def add(self, round_idx: int, name: str, seconds: float) -> None:
+        """Accumulate ``seconds`` a caller timed itself: the span
+        recorder (telemetry/spans.py) feeds every phase through here
+        from its own two clock reads."""
+        acc = self._acc.setdefault(round_idx, {})
+        acc[name] = acc.get(name, 0.0) + seconds
 
     def take(self, round_idx: int) -> dict[str, float]:
         """Pop the round's accumulated phase seconds (empty dict if the
@@ -109,6 +125,12 @@ class NullPhaseTimer:
     @contextlib.contextmanager
     def phase(self, round_idx: int, name: str):
         yield _FenceBox()
+
+    def fence(self, box) -> None:
+        return None
+
+    def add(self, round_idx: int, name: str, seconds: float) -> None:
+        return None
 
     def take(self, round_idx: int) -> None:
         return None

@@ -28,6 +28,15 @@ Two hooks, combined:
 
 One monitor active per process at a time (it owns process-global logging
 state); the simulator scopes it to the round loop.
+
+The monitor's is the program's ONE ``jax.monitoring`` listener. A span
+recorder (telemetry/spans.py) makes the run's monitor with an
+``on_event`` callback and calls :meth:`RecompileMonitor.listen` at the
+first line of ``run_simulation``: from then on every tracing, lowering
+and backend-compile duration event is handed to it stamped
+(:data:`DURATION_EVENTS`), so the op-by-op model init is seen. Counting
+and names still begin at :meth:`RecompileMonitor.start`, at the round
+loop, so the warm-up rule does not move.
 """
 
 from __future__ import annotations
@@ -38,7 +47,18 @@ import threading
 
 import jax
 
+from distributed_learning_simulator_tpu.telemetry import clock
+
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: ``jax.monitoring`` duration events -> the counter each feeds (jax
+#: 0.9.0: ``JAXPR_TRACE_EVENT``, ``JAXPR_TO_MLIR_MODULE_EVENT`` and
+#: ``BACKEND_COMPILE_EVENT`` of ``jax._src.dispatch``). The backend event
+#: wraps compile-or-load-from-the-persistent-cache.
+DURATION_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    _COMPILE_EVENT: "compile_s",
+}
 _COMPILE_LOGGER = "jax._src.dispatch"
 # "Compiling <fn> with global shapes…" (pxla) and "Persistent compilation
 # cache hit…" (compiler) log at the same forced-WARNING level; suspend
@@ -80,7 +100,11 @@ class RecompileMonitor:
             events = mon.take(round_idx)   # [(fn_name, seconds), ...]
     """
 
-    def __init__(self):
+    def __init__(self, on_event=None):
+        # ``on_event(counter, t_end, seconds)``: every DURATION_EVENTS
+        # event from listen() on, counted or not (the span recorder's).
+        self._on_event = on_event
+        self._listening = False
         self._lock = threading.Lock()
         self._count = 0          # monitoring-event ground truth
         self._named: list[tuple[str, float]] = []
@@ -93,10 +117,14 @@ class RecompileMonitor:
 
     # -- listener callbacks ---------------------------------------------------
     def _on_duration(self, event: str, duration: float, **kwargs) -> None:
-        if not self._active or event != _COMPILE_EVENT:
+        counter = DURATION_EVENTS.get(event)
+        if counter is None:
             return
-        with self._lock:
-            self._count += 1
+        if self._on_event is not None:
+            self._on_event(counter, clock.monotonic(), float(duration))
+        if self._active and event == _COMPILE_EVENT:
+            with self._lock:
+                self._count += 1
 
     def _record_name(self, name: str, seconds: float) -> None:
         if not self._active:
@@ -105,11 +133,20 @@ class RecompileMonitor:
             self._named.append((name, seconds))
 
     # -- lifecycle ------------------------------------------------------------
+    def listen(self) -> None:
+        """Register the ``jax.monitoring`` listener (idempotent) without
+        counting yet: ``on_event`` sees the events from here on."""
+        if not self._listening:
+            self._listening = True
+            jax.monitoring.register_event_duration_secs_listener(
+                self._on_duration
+            )
+
     def start(self) -> "RecompileMonitor":
         if self._active:
             return self
         self._active = True
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
+        self.listen()
         self._handler = _CaptureHandler(self)
         logging.getLogger(_COMPILE_LOGGER).addHandler(self._handler)
         self._null_handlers = {}
@@ -129,6 +166,11 @@ class RecompileMonitor:
         return self
 
     def stop(self) -> None:
+        if self._listening:
+            self._listening = False
+            jax.monitoring.unregister_event_duration_listener(
+                self._on_duration
+            )
         if not self._active:
             return
         self._active = False
@@ -142,7 +184,6 @@ class RecompileMonitor:
         if self._handler is not None:
             logging.getLogger(_COMPILE_LOGGER).removeHandler(self._handler)
             self._handler = None
-        jax.monitoring.unregister_event_duration_listener(self._on_duration)
 
     def __enter__(self) -> "RecompileMonitor":
         return self.start()

@@ -1,28 +1,56 @@
-"""Cross-host distributed tracing: span journals + crash flight recorder.
+"""The one span recorder of a run: host spans, counters, journals.
 
-PR 15 made the simulator genuinely distributed, but every telemetry
-layer stayed single-process: no way to see which HOST stalled an
-allgather, how skewed barrier arrivals are, or what a killed host was
-doing when it died. This module is the per-host half of the fix:
+``run_simulation`` makes one :class:`SpanRecorder` at its first line
+whenever ``telemetry_level != 'off'`` or ``span_trace == 'on'`` and keeps
+it to its return (:func:`start_run`, then ``start()`` ... ``finish()``
+around the root span ``run``; the most recent one stays readable through
+:func:`last_run`). Every timed boundary of the program is ONE
+call, ``tracer.span(name, cat, round_idx=..., phase=...)``, which
 
-* :class:`SpanRecorder` — a trace-time-cheap structured span recorder.
-  ``begin``/``end`` stamp ``clock.monotonic()`` and append one dict to a
-  bounded in-memory ring (``deque(maxlen=...)``; overflow counts into
-  ``dropped``, never blocks the hot path). ``flush()`` drains completed
-  spans to a per-host ``spans_<host_id>.jsonl`` journal once per round.
-* Flight recorder — the same ring, read out under failure. Spans marked
-  ``eager=True`` (the per-round envelope, DCN barrier waits, checkpoint
-  barriers — anything that can deadlock or die mid-span) additionally
-  write an ``open`` journal line at BEGIN, flushed to the OS before the
-  span body runs: a SIGKILL'd process leaves its open-line on disk, so
-  the postmortem names the span it died inside without any cleanup code
-  running. ``flush_inflight(reason)`` is the soft-failure path (SIGTERM,
-  fault-quorum rejection, unhandled crash): last-K completed spans +
-  a ``flight`` marker + one ``inflight`` line per still-open span.
-* :class:`SpanPhaseTimer` — a proxy wrapping the existing
-  :class:`~..telemetry.phases.PhaseTimer` (or its Null twin) so every
-  phase boundary emits begin/end spans at ANY ``telemetry_level``,
-  without touching the phase-accounting contract.
+1. enters a ``jax.profiler.TraceAnnotation`` of the same name (metadata
+   ``cat`` and ``round``), so a profiler capture holds the host spans on
+   the device trace's own clock (``utils/tracing.attribute_idle_gaps``);
+2. records the span: ``name``, ``cat``, ``t0``/``dur``
+   (``clock.monotonic()``, i.e. ``time.perf_counter``), ``id``,
+   ``parent`` (the innermost span open on the same thread when it began),
+   ``round`` (the request identifier: every span of one federated round
+   carries the same one, also when pipelining runs round r's fetch inside
+   round r+1's iteration) and ``thread``;
+3. feeds the per-round phase accumulation ``phase_seconds`` is built
+   from (``telemetry/phases.PhaseTimer.add``), from the same two clock
+   reads, and fences first under ``telemetry_level='detailed'``.
+
+Spans that end before the first round completes (set-up) are kept in a
+list of their own that is never evicted; later ones in a bounded ring
+(``deque(maxlen=capacity)``; an entry evicted before it reached a
+journal counts into ``dropped``; ``evicted_until`` says up to when the
+ring has lost anything, so a reader knows whether its window is whole).
+Counters sit at the same boundaries: completed rounds with their stamps
+(:meth:`SpanRecorder.round_done`), completed ``host_sync`` spans, and
+the ``jax.monitoring`` durations of tracing, lowering and backend
+compiles (or cache loads), heard from the first line of the run by the
+run's ONE listener, the :class:`RecompileMonitor`'s (``trace_s``,
+``lower_s``, ``compile_s``; :meth:`SpanRecorder.counters`).
+
+At ``telemetry_level='off'`` with ``span_trace='off'`` there is no
+recorder: :class:`NullTracer` enters the profiler annotation and nothing
+else (no clock, no record).
+
+``span_trace='on'`` adds, at any level, the cross-host part (PR 16):
+
+* a per-host ``spans_<host_id>.jsonl`` journal, drained by ``flush()``
+  once per round;
+* the flight recorder. Spans marked ``eager=True`` (the per-round
+  ``finalize`` envelope, DCN barrier waits, checkpoint barriers —
+  anything that can deadlock or die mid-span) write an ``open`` journal
+  line at BEGIN, flushed to the OS before the span body runs: a
+  SIGKILL'd process leaves its open-line on disk, so the postmortem
+  names the span it died inside without any cleanup code running.
+  ``flush_inflight(reason)`` is the soft-failure path (SIGTERM,
+  fault-quorum rejection, unhandled crash): last-K completed spans + a
+  ``flight`` marker + one ``inflight`` line per still-open span;
+* the multihost seams (spill exchange, prefetch occupancy, checkpoint
+  shards) and the schema-v12 ``spans`` record sub-object.
 
 Journal line taxonomy (all JSONL, one object per line):
 
@@ -33,35 +61,50 @@ Journal line taxonomy (all JSONL, one object per line):
 ``open``     eager begin marker (flight recorder); matched by a later
              ``span`` line with the same ``id`` unless the host died.
 ``span``     completed span: ``t0`` (monotonic), ``dur`` seconds.
-``event``    instant event (recompiles, dispatch marks).
+``event``    instant event (recompiles).
 ``flight``   force-flush marker with the triggering ``reason`` and, when
              an exception unwound through a span first, the ``in_span``
              it escaped from (name/cat/round + exception type).
 ``inflight`` a span still open at force-flush time.
 
-Span categories (``cat``): ``round`` (per-round envelope), ``phase``
-(PhaseTimer phases), ``dcn_wait`` (barrier arrival waits — the skew
-signal), ``dcn`` (payload collectives), ``io`` (checkpoint shard
-writes), ``stream`` (prefetch worker occupancy), ``compile`` (recompile
-events), ``dispatch``. ``round_summary()`` folds a round's spans into
-the schema-v12 ``spans`` record sub-object (``utils/reporting.py``).
+Span categories (``cat``). The leaves the cross-host analytics sum:
+``phase`` (the boundaries ``phase_seconds`` is built from), ``io``
+(checkpoint shard and manifest writes), ``dcn_wait`` (barrier arrival
+waits — the skew signal), ``dcn`` (payload collectives), ``stream``
+(prefetch worker occupancy), ``compile`` (recompile events), and
+``round`` (the eager per-round ``finalize`` envelope).
+``round_summary()`` folds a round's spans of these into the schema-v12
+``spans`` record sub-object (``utils/reporting.py``). The envelopes of
+the in-memory record (:data:`ENVELOPE_CATS`: ``run`` the root, ``setup``
+the set-up sections, ``iter`` the per-iteration ``round`` span, ``host``
+for ``record``, ``checkpoint`` and ``valuation_audit``) enclose those
+leaves, so they stay out of ``seconds_by_cat``, of the span counts and
+of the stitcher's busy time: a leaf is counted once.
 
-Everything here is jax-free and thread-safe (the streaming prefetch
-worker emits occupancy spans from its own thread).
-
-``span_trace='off'`` (default) constructs none of this — the simulator
-keeps the exact pre-feature program (off-gate contract).
+Thread-safe (the streaming prefetch worker emits occupancy spans from
+its own thread; its spans have no parent on the main thread).
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
+import itertools
 import json
 import os
 import threading
 
+import jax
+
 from distributed_learning_simulator_tpu.telemetry import clock
+from distributed_learning_simulator_tpu.telemetry.phases import (
+    NullPhaseTimer,
+    make_phase_timer,
+)
+from distributed_learning_simulator_tpu.telemetry.recompile import (
+    DURATION_EVENTS,
+    RecompileMonitor,
+)
 
 JOURNAL_VERSION = 1
 
@@ -74,16 +117,112 @@ def journal_filename(host_id: int) -> str:
     return JOURNAL_PATTERN.format(host_id=int(host_id))
 
 
-class SpanRecorder:
-    """Bounded in-memory span ring + per-host JSONL journal.
+#: Categories of the spans that enclose other spans of the same round
+#: (see the module docstring): recorded and journaled like any other,
+#: left out of the per-round and per-run category sums and counts.
+ENVELOPE_CATS = frozenset({"run", "setup", "iter", "host"})
 
-    Hot-path cost is one dict build and a deque append under a lock;
-    journal I/O happens only in ``flush()`` (once per round), at eager
-    begins (a handful per round), and in the failure paths.
+_LAST_RUN: "SpanRecorder | None" = None
+
+
+def last_run() -> "SpanRecorder | None":
+    """The recorder of the most recent ``run_simulation`` call in this
+    process (set-up list, ring and counters stay readable after the call
+    returned and the journal closed); ``None`` if that call ran with
+    ``telemetry_level='off'`` and ``span_trace='off'``, or none ran."""
+    return _LAST_RUN
+
+
+def union_seconds(intervals, lo: float = float("-inf"),
+                  hi: float = float("inf")) -> float:
+    """Length of the union of ``(start, end)`` intervals, clipped to
+    ``[lo, hi]``: overlapping and nested intervals count once."""
+    total, end = 0.0, lo
+    for a, b in sorted(intervals):
+        a, b = max(a, end), min(b, hi)
+        if b > a:
+            total += b - a
+            end = b
+    return total
+
+
+def self_seconds(spans) -> dict[int, float]:
+    """``{span id: self time}``: a span's duration minus the part of its
+    interval that its child spans cover (children that overlap each
+    other count once)."""
+    children: dict[int, list] = {}
+    for s in spans:
+        if s.get("parent") is not None and "dur" in s:
+            children.setdefault(s["parent"], []).append(
+                (s["t0"], s["t0"] + s["dur"])
+            )
+    return {
+        s["id"]: s["dur"] - union_seconds(
+            children.get(s["id"], ()), s["t0"], s["t0"] + s["dur"]
+        )
+        for s in spans if "dur" in s and "id" in s
+    }
+
+
+def _annotation(name: str, cat: str, round_idx):
+    """The profiler's view of a span: same name, ``cat`` and ``round`` as
+    metadata. Inert (a flag check) while no profiler session runs."""
+    if round_idx is None:
+        return jax.profiler.TraceAnnotation(name, cat=cat)
+    return jax.profiler.TraceAnnotation(name, cat=cat, round=int(round_idx))
+
+
+class _SpanBox(dict):
+    """What a span's body is handed: a dict of result attrs
+    (``box["bytes"] = n``, merged into the span record) that is also the
+    slot a phase parks its output in (``box.fence(value)``: waited on
+    before the clock stops under ``telemetry_level='detailed'``)."""
+
+    __slots__ = ("value",)
+
+    def __init__(self):
+        super().__init__()
+        self.value = None
+
+    def fence(self, value) -> None:
+        self.value = value
+
+
+class _Sections:
+    """``section()``, over a tracer's own ``span()``."""
+
+    _section = None  # the open section's context
+
+    def section(self, name: str | None, cat: str = "setup") -> None:
+        """Sequential top-level sections (set-up): close the open one,
+        then open ``name`` (``None``: only close). The set-up code reads
+        top to bottom, so a section lasts until the next begins."""
+        if self._section is not None:
+            ctx, self._section = self._section, None
+            ctx.__exit__(None, None, None)
+        if name is not None:
+            self._section = self.span(name, cat)
+            self._section.__enter__()
+
+
+class SpanRecorder(_Sections):
+    """Set-up list + bounded in-memory span ring + per-host JSONL journal.
+
+    Hot-path cost is one inert profiler annotation, two clock reads, one
+    dict build and a list/deque append under a lock; journal I/O happens
+    only in ``flush()`` (once per round), at eager begins (a handful per
+    round), and in the failure paths.
     """
 
+    #: NullTracer's twin answers False: "is anything being recorded".
+    recording = True
+    #: span_trace='on' (set by :func:`start_run`): the journal, the
+    #: flight recorder and the multihost seams are wanted too.
+    journal = False
+
     def __init__(self, host_id: int = 0, n_hosts: int = 1,
-                 capacity: int = 4096, flush_last_k: int = 64):
+                 capacity: int = 4096, flush_last_k: int = 64,
+                 phases=None):
         if capacity < 1:
             raise ValueError(f"span buffer capacity must be >= 1: {capacity}")
         if flush_last_k < 1:
@@ -92,8 +231,37 @@ class SpanRecorder:
         self.n_hosts = int(n_hosts)
         self.capacity = int(capacity)
         self.flush_last_k = int(flush_last_k)
+        # Where ``span(..., phase=<name>)`` accumulates: the
+        # ``phase_seconds`` of the records (inert at telemetry 'off').
+        self.phases = phases if phases is not None else NullPhaseTimer()
+        self.main_thread = threading.get_ident()
         self._lock = threading.Lock()
+        self._tls = threading.local()
+        # Set-up (until the first round completes) is never evicted;
+        # the ring holds the newest ``capacity`` records after it.
+        self._in_setup = True
+        self._setup: list[dict] = []
+        self._setup_flushed = 0
         self._ring: collections.deque = collections.deque(maxlen=capacity)
+        self._unflushed = 0  # newest ring entries not yet in a journal
+        # The end of the newest record the ring has evicted (None: all
+        # still held). Readers of a window check it (``evicted_until``).
+        self._evicted_until: float | None = None
+        # Counters: completed rounds with their stamps, completed
+        # host_sync spans, and the jax.monitoring durations (counter,
+        # t_end, secs) the run's monitor hands over from start() on.
+        self.monitor = RecompileMonitor(on_event=self._on_duration)
+        self._rounds = 0
+        self._first_round_t: float | None = None
+        self._round_stamps: collections.deque = collections.deque(
+            maxlen=capacity
+        )
+        self._host_syncs = 0
+        self._jax_setup: list[tuple] = []
+        self._jax_ring: collections.deque = collections.deque(
+            maxlen=capacity
+        )
+        self._root = None  # the root span's context, start() to finish()
         self._open: dict[int, dict] = {}
         self._next_id = 0
         self._dropped = 0
@@ -160,7 +328,12 @@ class SpanRecorder:
         SIGKILL mid-span still leaves the span's identity on disk.
         """
         t0 = clock.monotonic()
-        span = {"id": -1, "name": name, "cat": cat, "t0": t0}
+        stack = self._stack()
+        span = {
+            "id": -1, "name": name, "cat": cat, "t0": t0,
+            "parent": stack[-1] if stack else None,
+            "thread": threading.get_ident(),
+        }
         if round_idx is not None:
             span["round"] = int(round_idx)
         if attrs:
@@ -177,12 +350,18 @@ class SpanRecorder:
                     line["attrs"] = attrs
                 self._file.write(json.dumps(line) + "\n")
                 self._file.flush()
+        stack.append(sid)
         return sid
 
     def end(self, span_id: int, **attrs) -> float:
         """Close a span; returns its duration in seconds. Extra attrs
         merge into the span record (e.g. measured skew on a wait)."""
         t1 = clock.monotonic()
+        stack = self._stack()
+        if stack and stack[-1] == span_id:
+            stack.pop()
+        elif span_id in stack:  # closed out of order
+            stack.remove(span_id)
         with self._lock:
             span = self._open.pop(span_id, None)
             if span is None:
@@ -197,27 +376,79 @@ class SpanRecorder:
 
     @contextlib.contextmanager
     def span(self, name: str, cat: str, round_idx: int | None = None,
-             eager: bool = False, **attrs):
-        """Context-manager form of begin/end. Yields a dict the body may
-        mutate to attach result attrs (e.g. byte counts)."""
-        extra: dict = {}
-        sid = self.begin(name, cat, round_idx=round_idx, eager=eager,
-                         **attrs)
+             eager: bool = False, phase: str | None = None, **attrs):
+        """THE timing context of a boundary: profiler annotation, span
+        record and (``phase=<name>``) the round's phase accumulation,
+        from one clock read per edge. Yields a :class:`_SpanBox`."""
+        box = _SpanBox()
+        with _annotation(name, cat, round_idx):
+            sid = self.begin(name, cat, round_idx=round_idx, eager=eager,
+                             **attrs)
+            try:
+                yield box
+            except BaseException as e:
+                # Remember the innermost span this exception escaped
+                # from — the span itself closes below (clean journals),
+                # but the flight marker needs to name where the failure
+                # struck.
+                err = {"name": name, "cat": cat, "error": type(e).__name__}
+                if round_idx is not None:
+                    err["round"] = int(round_idx)
+                with self._lock:
+                    if self._last_error is None:
+                        self._last_error = err
+                raise
+            finally:
+                if phase is not None:
+                    # 'detailed' waits for the parked output before the
+                    # clock stops, so span and phase measure device time.
+                    self.phases.fence(box)
+                dur = self.end(sid, **box)
+                if phase is not None:
+                    self.phases.add(round_idx, phase, dur)
+
+    def start(self) -> None:
+        """Open the root span ``run`` and switch the monitor's
+        ``jax.monitoring`` listener on: the first line of
+        ``run_simulation``. Paired with :meth:`finish` in a
+        ``try``/``finally`` there (no ``with``, no wrapper frame:
+        PERF.md § 6, PR 25)."""
+        self.monitor.listen()
+        self._root = self.span("run", "run")
+        self._root.__enter__()
+
+    def finish(self) -> None:
+        """Close the open section and the root span, switch the listener
+        off, then drain and close the journal (the root is its last
+        span line). Idempotent."""
+        root, self._root = self._root, None
+        if root is None:
+            return
         try:
-            yield extra
-        except BaseException as e:
-            # Remember the innermost span this exception escaped from —
-            # the span itself closes below (clean journals), but the
-            # flight marker needs to name where the failure struck.
-            err = {"name": name, "cat": cat, "error": type(e).__name__}
-            if round_idx is not None:
-                err["round"] = int(round_idx)
-            with self._lock:
-                if self._last_error is None:
-                    self._last_error = err
-            raise
+            self.section(None)
+            root.__exit__(None, None, None)
         finally:
-            self.end(sid, **extra)
+            self.monitor.stop()
+            self.close()
+
+    def round_done(self, round_idx: int, t: float) -> None:
+        """Count a completed round at the program's own stamp ``t`` (the
+        end of its ``round_seconds``). The first one ends set-up."""
+        with self._lock:
+            self._rounds += 1
+            self._round_stamps.append((int(round_idx), t))
+            if self._in_setup:
+                self._in_setup = False
+                self._first_round_t = t
+
+    def _on_duration(self, counter: str, t_end: float,
+                     seconds: float) -> None:
+        """The monitor's ``on_event``: one tracing, lowering or backend
+        compile (or cache load) ended at ``t_end``."""
+        with self._lock:
+            (self._jax_setup if self._in_setup else self._jax_ring).append(
+                (counter, t_end, seconds)
+            )
 
     def event(self, name: str, cat: str, round_idx: int | None = None,
               **attrs) -> None:
@@ -260,20 +491,19 @@ class SpanRecorder:
     # draining
 
     def flush(self) -> int:
-        """Drain completed spans/events to the journal. Returns the
-        number of lines written (0 when unattached — the ring then just
-        keeps the last ``capacity`` entries as a pure flight recorder)."""
+        """Write completed spans/events not yet journaled. Returns the
+        number of lines written (0 when unattached — set-up list and
+        ring then are the in-memory record alone). Nothing is removed:
+        the records stay readable (:meth:`spans`)."""
         with self._lock:
             if self._file is None:
                 return 0
-            n = 0
-            while self._ring:
-                rec = self._ring.popleft()
+            recs = self._take_unflushed_locked()
+            for rec in recs:
                 self._file.write(json.dumps(self._line_locked(rec)) + "\n")
-                n += 1
-            if n:
+            if recs:
                 self._file.flush()
-            return n
+            return len(recs)
 
     def flush_inflight(self, reason: str) -> int:
         """Force-flush for the failure paths (SIGTERM, quorum rejection,
@@ -284,8 +514,7 @@ class SpanRecorder:
             if self._file is None or self._closed:
                 return 0
             n = 0
-            tail = list(self._ring)[-self.flush_last_k:]
-            self._ring.clear()
+            tail = self._take_unflushed_locked()[-self.flush_last_k:]
             for rec in tail:
                 self._file.write(json.dumps(self._line_locked(rec)) + "\n")
                 n += 1
@@ -310,7 +539,8 @@ class SpanRecorder:
             return n
 
     def close(self) -> None:
-        """Final drain + close the journal (idempotent)."""
+        """Final flush + close the journal (idempotent); what was
+        recorded stays readable."""
         self.flush()
         with self._lock:
             self._closed = True
@@ -320,6 +550,61 @@ class SpanRecorder:
                     self._file.close()
                 finally:
                     self._file = None
+
+    # ------------------------------------------------------------------
+    # reading (the benchmark's metric readers, reports, tests)
+
+    def spans(self) -> list[dict]:
+        """Completed spans, set-up list first, then the ring (copies of
+        the containers, not of the records: do not mutate)."""
+        with self._lock:
+            recs = self._setup + list(self._ring)
+        return [r for r in recs if r.get("kind") != "event"]
+
+    @property
+    def evicted_until(self) -> float | None:
+        """End of the newest record the ring has evicted; ``None`` while
+        every span since the run's entry is still held. A reader whose
+        window begins before it is reading a window with holes."""
+        with self._lock:
+            return self._evicted_until
+
+    def round_stamps(self) -> list[tuple[int, float]]:
+        """``(round, t)`` of the completed rounds still held (the newest
+        ``capacity``), ``t`` on :func:`clock.monotonic`."""
+        with self._lock:
+            return list(self._round_stamps)
+
+    def duration_events(self) -> list[tuple[str, float, float]]:
+        """``(counter, t_end, seconds)`` per ``jax.monitoring`` duration
+        event: all of set-up's, the newest ``capacity`` after it. Traces
+        nest (a jitted function traced inside another's trace, or inside
+        a lowering), so sum them as intervals ``(t_end - seconds,
+        t_end)`` through :func:`union_seconds`, never as numbers."""
+        with self._lock:
+            return self._jax_setup + list(self._jax_ring)
+
+    def counters(self) -> dict:
+        """``trace_s`` / ``lower_s`` / ``compile_s`` as ``[before,
+        after]`` the first round completed (unions of the events'
+        intervals), ``host_syncs`` (completed ``host_sync`` spans) and
+        ``rounds`` (completed rounds)."""
+        events = self.duration_events()
+        with self._lock:
+            cut = self._first_round_t
+            host_syncs = self._host_syncs
+            rounds = self._rounds
+        cut = float("inf") if cut is None else cut
+        out: dict = {}
+        for key in DURATION_EVENTS.values():
+            ivs = [(t - d, t) for k, t, d in events if k == key]
+            out[key] = [
+                union_seconds(iv for iv in ivs if iv[1] <= cut),
+                union_seconds(iv for iv in ivs if iv[1] > cut),
+            ]
+        out["host_syncs"] = host_syncs
+        out["rounds"] = rounds
+        return out
 
     # ------------------------------------------------------------------
     # per-round summary (schema-v12 `spans` sub-object)
@@ -383,12 +668,46 @@ class SpanRecorder:
         }
 
     # ------------------------------------------------------------------
-    # internals (call with self._lock held)
+    # internals
+
+    def _stack(self) -> list[int]:
+        """Ids of the spans open on the calling thread, outermost first."""
+        try:
+            return self._tls.stack
+        except AttributeError:
+            self._tls.stack = []
+            return self._tls.stack
+
+    # (the rest: call with self._lock held)
 
     def _append_locked(self, rec: dict) -> None:
+        if self._in_setup:
+            self._setup.append(rec)
+            return
         if len(self._ring) == self._ring.maxlen:
-            self._dropped += 1
+            # The oldest entry goes. It is lost to the journal only if
+            # it never reached one (always, when none is attached).
+            old = self._ring[0]
+            self._evicted_until = (
+                old["t"] if "t" in old else old["t0"] + old["dur"]
+            )
+            if self._unflushed >= len(self._ring):
+                self._dropped += 1
+                self._unflushed -= 1
         self._ring.append(rec)
+        self._unflushed += 1
+
+    def _take_unflushed_locked(self) -> list[dict]:
+        """The records no journal holds yet, oldest first; marks them
+        journaled."""
+        recs = self._setup[self._setup_flushed:]
+        self._setup_flushed = len(self._setup)
+        if self._unflushed:
+            recs.extend(itertools.islice(
+                self._ring, len(self._ring) - self._unflushed, None
+            ))
+            self._unflushed = 0
+        return recs
 
     def _agg_for_locked(self, round_idx: int) -> dict:
         return self._round_agg.setdefault(int(round_idx), {
@@ -398,6 +717,10 @@ class SpanRecorder:
     def _aggregate_locked(self, span: dict) -> None:
         cat = span.get("cat", "")
         dur = span.get("dur", 0.0)
+        if span["name"] == "host_sync":
+            self._host_syncs += 1
+        if cat in ENVELOPE_CATS:
+            return
         self._run["count"] += 1
         self._run["by_cat"][cat] = self._run["by_cat"].get(cat, 0.0) + dur
         rnd = span.get("round")
@@ -419,38 +742,44 @@ class SpanRecorder:
         return {"kind": "span", **rec}
 
 
-class SpanPhaseTimer:
-    """PhaseTimer proxy: same phase-accounting contract, plus a span per
-    phase. Wraps either timer class — spans work at any
-    ``telemetry_level``, including 'off' (the Null inner still yields
-    its inert fence box; only the span clocks run)."""
+class NullTracer(_Sections):
+    """``telemetry_level='off'`` with ``span_trace='off'``: the same
+    calls, no clock and no record. A boundary still enters its profiler
+    annotation, so a ``profile_dir`` capture is labelled at any level."""
 
-    def __init__(self, inner, recorder: SpanRecorder):
-        self._inner = inner
-        self._rec = recorder
+    recording = False
+    journal = False
+    phases = NullPhaseTimer()
 
-    @property
-    def enabled(self) -> bool:
-        return self._inner.enabled
+    def start(self) -> None:
+        return None
+
+    def finish(self) -> None:
+        self.section(None)
 
     @contextlib.contextmanager
-    def phase(self, round_idx: int, name: str):
-        # Dispatch boundary: the client_step phase entry IS where the
-        # round program is handed to the runtime — an instant event so
-        # the timeline marks it even under async dispatch (where the
-        # phase's duration is trace+dispatch cost, not device time).
-        if name == "client_step":
-            self._rec.event("dispatch", "dispatch", round_idx=round_idx)
-        # Span outside the inner phase: a fencing timer's
-        # block_until_ready runs before the span closes, so 'detailed'
-        # mode spans measure true device time like the phase table does.
-        with self._rec.span(name, "phase", round_idx=round_idx):
-            with self._inner.phase(round_idx, name) as box:
-                yield box
+    def span(self, name: str, cat: str, round_idx: int | None = None,
+             eager: bool = False, phase: str | None = None, **attrs):
+        with _annotation(name, cat, round_idx):
+            yield _SpanBox()
 
-    def take(self, round_idx: int):
-        return self._inner.take(round_idx)
+    def round_done(self, round_idx: int, t: float) -> None:
+        return None
 
-    def carve(self, round_idx: int, name: str, seconds: float,
-              source: str) -> None:
-        self._inner.carve(round_idx, name, seconds, source)
+
+def start_run(level: str, journal: bool, capacity: int = 4096,
+              flush_last_k: int = 64) -> "SpanRecorder | NullTracer":
+    """The tracer of one ``run_simulation`` call, made at its first
+    line: a recorder (in memory only) whenever ``telemetry_level`` is
+    not 'off' or ``span_trace`` is 'on' (``journal``), else the null
+    twin. It becomes what :func:`last_run` returns."""
+    global _LAST_RUN
+    if level == "off" and not journal:
+        _LAST_RUN = None
+        return NullTracer()
+    _LAST_RUN = SpanRecorder(
+        capacity=capacity, flush_last_k=flush_last_k,
+        phases=make_phase_timer(level),
+    )
+    _LAST_RUN.journal = journal
+    return _LAST_RUN

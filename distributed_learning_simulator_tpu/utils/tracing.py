@@ -2,12 +2,15 @@
 
 The reference has NO profiling instrumentation (SURVEY §5: the only
 performance-adjacent output is compression-ratio logging). This module
-exceeds parity: named trace annotations around the round / eval / post-round
-phases (visible in TensorBoard/Perfetto), plus an opt-in programmatic
-profiler session writing an XPlane trace directory.
+exceeds parity: an opt-in programmatic profiler session writing an XPlane
+trace directory, the reductions of such a trace (device-op ledgers and
+rankings), and :func:`attribute_idle_gaps`, which names the device's idle
+gaps by the program's own host spans: every boundary the span recorder
+times (telemetry/spans.py) is also a ``TraceAnnotation`` of the same name,
+visible in TensorBoard/Perfetto on the device trace's clock.
 
 Usage: set ``config.profile_dir`` — the simulator wraps the run in
-``start_trace``/``stop_trace`` and annotates each phase.
+``start_trace``/``stop_trace``.
 """
 
 from __future__ import annotations
@@ -285,9 +288,119 @@ def device_op_report(trace_dir: str, k: int = 10) -> dict:
     }
 
 
-def annotate(name: str):
-    """Named region visible in TPU traces (wraps jax.profiler annotations)."""
-    return jax.profiler.TraceAnnotation(name)
+# The trace-viewer JSON holds at most this many events and drops the
+# rest in silence (a four-chip flagship trace overflows it).
+_EVENT_CAP = 1_000_000
+
+
+def _session_events(trace_dir: str) -> list[dict]:
+    """Events of the newest profiling session under ``trace_dir`` in the
+    trace-viewer JSON's shape. Read from that JSON where it is whole; at
+    the event cap, from the ``.xplane.pb`` (every event): the device
+    planes' ``XLA Modules`` lines and the host plane's events that carry
+    a ``cat`` (the program's own spans, below)."""
+    paths = sorted(
+        glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                               "*.trace.json.gz")),
+        key=os.path.getmtime,
+    )
+    if not paths:
+        return []
+    with gzip.open(paths[-1], "rt") as f:
+        events = json.load(f).get("traceEvents", [])
+    if len(events) < _EVENT_CAP:
+        return events
+    pbs = glob.glob(os.path.join(os.path.dirname(paths[-1]), "*.xplane.pb"))
+    if not pbs:
+        return events
+    out = []
+    for pid, plane in enumerate(
+        jax.profiler.ProfileData.from_file(pbs[0]).planes
+    ):
+        device = plane.name.startswith("/device:")
+        if not device and not plane.name.startswith("/host:CPU"):
+            continue
+        out.append({"ph": "M", "pid": pid, "name": "process_name",
+                    "args": {"name": plane.name}})
+        for tid, line in enumerate(plane.lines):
+            if device and line.name != "XLA Modules":
+                continue
+            out.append({"ph": "M", "pid": pid, "tid": tid,
+                        "name": "thread_name", "args": {"name": line.name}})
+            for ev in line.events:
+                args = {} if device else dict(ev.stats)
+                if not device and "cat" not in args:
+                    continue
+                out.append({
+                    "ph": "X", "pid": pid, "tid": tid, "name": ev.name,
+                    "ts": ev.start_ns / 1e3, "dur": ev.duration_ns / 1e3,
+                    "args": args,
+                })
+    return out
+
+
+def attribute_idle_gaps(trace_dir: str) -> list[dict]:
+    """Name the device's idle gaps by what the host was doing in them.
+
+    A gap is the time between two successive executed programs on one
+    chip (the ``XLA Modules`` lane of a ``/device:`` plane; the rule of
+    ``benchmark/harness/trace.py``). Every timed boundary of the program
+    is also a ``TraceAnnotation`` of the span's name with ``cat`` and
+    ``round`` as metadata (telemetry/spans.py), so the capture holds the
+    host spans on the device trace's own clock, in its ``/host:CPU``
+    plane. Each gap is named by the innermost such span that covers its
+    midpoint, ``"<none>"`` where none does.
+
+    Returns rows ``{"span", "between", "count", "seconds"}``, one per
+    (span, ``<program before>-><program after>``) pair summed over
+    chips, longest first. Missing/empty trace dirs return ``[]``.
+    """
+    events = _session_events(trace_dir)
+    procs, threads = {}, {}
+    for ev in events:
+        if ev.get("ph") != "M":
+            continue
+        if ev.get("name") == "process_name":
+            procs[ev["pid"]] = ev["args"]["name"]
+        elif ev.get("name") == "thread_name":
+            threads[(ev["pid"], ev.get("tid"))] = ev["args"]["name"]
+    modules: dict[int, list] = {}
+    host_spans = []
+    for ev in events:
+        if ev.get("ph") != "X":
+            continue
+        plane = procs.get(ev.get("pid"), "")
+        if plane.startswith("/device:"):
+            if threads.get((ev["pid"], ev.get("tid"))) == "XLA Modules":
+                modules.setdefault(ev["pid"], []).append(ev)
+        elif plane.startswith("/host:") and "cat" in (ev.get("args") or {}):
+            host_spans.append(
+                (float(ev["ts"]), float(ev["ts"]) + float(ev["dur"]),
+                 ev["name"])
+            )
+    rows: dict[tuple, dict] = {}
+    for mods in modules.values():
+        mods.sort(key=lambda e: e["ts"])
+        for prev, nxt in zip(mods, mods[1:]):
+            end = float(prev["ts"]) + float(prev["dur"])
+            gap = float(nxt["ts"]) - end
+            if gap <= 0:
+                continue
+            mid = end + gap / 2
+            covering = [h for h in host_spans if h[0] <= mid < h[1]]
+            # Spans nest, so the shortest that covers is the innermost.
+            span = min(covering, key=lambda h: h[1] - h[0])[2] \
+                if covering else "<none>"
+            between = "->".join(
+                re.sub(r"\(\d+\)$", "", m["name"]) for m in (prev, nxt)
+            )
+            row = rows.setdefault((span, between), {
+                "span": span, "between": between, "count": 0,
+                "seconds": 0.0,
+            })
+            row["count"] += 1
+            row["seconds"] += gap / 1e6
+    return sorted(rows.values(), key=lambda r: (-r["seconds"], r["span"]))
 
 
 @contextlib.contextmanager
@@ -301,7 +414,8 @@ def profile_session(profile_dir: str | None):
     # program — and the trace-viewer JSON this module parses is capped at
     # 1,000,000 events, so device ops were dropped from it (v5e, PR 21:
     # 2,148 of the 6,576 captured ops survived a three-round trace).
-    # ``annotate`` regions and device ops do not come from that tracer.
+    # The program's span annotations (telemetry/spans.py) and device ops
+    # do not come from that tracer.
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     jax.profiler.start_trace(profile_dir, profiler_options=options)

@@ -16,7 +16,9 @@ content as machine-readable JSON (``--json``). ``--trace`` points at a
 ``jax.profiler`` trace directory (``config.profile_dir``) and adds the
 deterministic device-op totals plus top-ops-by-bytes AND
 top-ops-by-time tables (same selection rule as bench.py's regression
-proxy: utils/tracing.py).
+proxy: utils/tracing.py), and the device's idle gaps named by the host
+span that covers each (``utils/tracing.attribute_idle_gaps``: the
+program's spans are in the capture's host plane, on its clock).
 
 Reads all metrics schemas: v1 (pre-telemetry; accuracy/timing only), v2
 (``telemetry`` sub-object), v3 (``client_stats`` sub-object), v4
@@ -432,6 +434,7 @@ def summarize_spans(records: list[dict]) -> dict | None:
 def summarize_run(records: list[dict], trace_stats: dict | None = None,
                   top_ops: list[dict] | None = None,
                   top_ops_time: list[dict] | None = None,
+                  idle_gaps: list[dict] | None = None,
                   costmodel: dict | None = None,
                   span_timeline: dict | None = None) -> dict:
     """Aggregate metrics records into the machine-readable summary the
@@ -634,6 +637,8 @@ def summarize_run(records: list[dict], trace_stats: dict | None = None,
         summary["top_device_ops"] = top_ops
     if top_ops_time is not None:
         summary["top_device_ops_time"] = top_ops_time
+    if idle_gaps is not None:
+        summary["device_idle_gaps"] = idle_gaps
     return summary
 
 
@@ -1129,6 +1134,16 @@ def render_summary(summary: dict) -> list[str]:
             f"  {op['device_ms']:>8.2f} ms  {op['bytes_gb']:>8.3f} GB  "
             f"x{op['count']:<5} {op['name']}"
         )
+    if summary.get("device_idle_gaps"):
+        # utils/tracing.attribute_idle_gaps: each gap between two
+        # executed programs, named by the innermost host span
+        # (telemetry/spans.py) covering its midpoint.
+        lines.append("device idle gaps by host span:")
+    for gap in summary.get("device_idle_gaps", []):
+        lines.append(
+            f"  {1e3 * gap['seconds']:>8.3f} ms  x{gap['count']:<5} "
+            f"{gap['span']:<14} {gap['between']}"
+        )
     return lines
 
 
@@ -1176,6 +1191,7 @@ def main(argv: list[str] | None = None) -> int:
                 host=args.host,
             )
         trace_stats = top_ops = top_ops_time = costmodel = None
+        idle_gaps = None
         if args.trace:
             # Deferred: utils.tracing imports jax. One gzip pass serves
             # the totals and both rankings; a second builds the cost
@@ -1186,6 +1202,7 @@ def main(argv: list[str] | None = None) -> int:
                 ledger_totals,
             )
             from distributed_learning_simulator_tpu.utils.tracing import (
+                attribute_idle_gaps,
                 categorize_ops,
                 device_op_report,
             )
@@ -1194,6 +1211,7 @@ def main(argv: list[str] | None = None) -> int:
             trace_stats = report["totals"]
             top_ops = report["by_bytes"]
             top_ops_time = report["by_time"]
+            idle_gaps = attribute_idle_gaps(args.trace)[:args.top]
             ledger = categorize_ops(args.trace)
             if ledger and ledger_totals(ledger)["bytes_gb"] > 0:
                 # Anchor on this run's measured steady rounds (round 0
@@ -1212,7 +1230,7 @@ def main(argv: list[str] | None = None) -> int:
                 )
         summary = summarize_run(records, trace_stats=trace_stats,
                                 top_ops=top_ops, top_ops_time=top_ops_time,
-                                costmodel=costmodel,
+                                idle_gaps=idle_gaps, costmodel=costmodel,
                                 span_timeline=span_timeline)
     except (FileNotFoundError, ValueError) as e:
         print(str(e), file=sys.stderr)

@@ -1,8 +1,10 @@
-"""Distributed tracing unit tests: recorder, journals, stitcher.
+"""Span recorder unit tests: recorder, journals, stitcher.
 
-Fast and device-free (telemetry/spans.py and scripts/trace_timeline.py
-deliberately import no jax): the span ring's bounds, the journal line
-taxonomy, the flight-recorder guarantees (eager open-lines survive a
+Fast and device-free (scripts/trace_timeline.py deliberately imports no
+jax; telemetry/spans.py touches it only for the inert profiler
+annotation): parent/child and self-time arithmetic, the set-up list and
+the span ring's bounds, one clock read per edge, the counters, the
+journal line taxonomy, the flight-recorder guarantees (eager open-lines survive a
 kill; ``flush_inflight`` names still-open spans), the off-gate
 ``config_hash`` invariance, and the cross-host stitcher on SYNTHETIC
 two-host journals with a known clock offset — so the alignment math
@@ -20,9 +22,17 @@ import sys
 import pytest
 
 from distributed_learning_simulator_tpu.config import ExperimentConfig
+from distributed_learning_simulator_tpu.telemetry import clock, spans
+from distributed_learning_simulator_tpu.telemetry.phases import PhaseTimer
+from distributed_learning_simulator_tpu.telemetry.recompile import (
+    DURATION_EVENTS,
+)
 from distributed_learning_simulator_tpu.telemetry.spans import (
+    NullTracer,
     SpanRecorder,
     journal_filename,
+    self_seconds,
+    union_seconds,
 )
 from distributed_learning_simulator_tpu.utils.reporting import config_hash
 
@@ -54,6 +64,7 @@ def test_recorder_validates_bounds():
 
 def test_ring_is_bounded_and_counts_drops():
     rec = SpanRecorder(capacity=4)
+    rec.round_done(0, clock.monotonic())  # set-up over: the ring is in use
     for _ in range(10):
         sid = rec.begin("s", "phase", round_idx=0)
         rec.end(sid)
@@ -66,6 +77,233 @@ def test_ring_is_bounded_and_counts_drops():
     # Unattached flushes are safe no-ops.
     assert rec.flush() == 0
     assert rec.flush_inflight("sigterm") == 0
+
+
+def test_setup_list_survives_a_ring_overflow():
+    """Spans that end before the first round completes are set-up: kept
+    in a list of their own, whole, however far the ring overflows
+    afterwards."""
+    rec = SpanRecorder(capacity=4)
+    rec.start()
+    rec.section("setup/data")
+    rec.section("setup/model_init")
+    rec.section(None)
+    rec.round_done(0, clock.monotonic())
+    assert rec.evicted_until is None
+    for r in range(1, 50):
+        with rec.span("round", "iter", round_idx=r):
+            with rec.span("host_sync", "phase", round_idx=r):
+                pass
+    rec.finish()
+    names = [s["name"] for s in rec.spans()]
+    assert names[:2] == ["setup/data", "setup/model_init"]
+    assert len(names) == 2 + 4  # the set-up list + a full ring
+    assert names[-1] == "run"  # the root ends last, so the ring holds it
+    assert rec.run_summary()["dropped"] == 2 * 49 + 1 - 4
+    # The ring says up to when it has lost spans: the end of the newest
+    # one evicted, just before the oldest it still holds began.
+    oldest = rec.spans()[2]
+    assert rec.evicted_until <= oldest["t0"] + oldest["dur"]
+    assert rec.evicted_until > rec.spans()[1]["t0"]
+    # The counters are run-wide, evicted spans included.
+    assert rec.counters()["host_syncs"] == 49
+    assert rec.counters()["rounds"] == 1
+
+
+def test_parent_child_and_self_time():
+    """A span's parent is the innermost span open on the same thread when
+    it began; every span of one round carries that round; self time is
+    the duration less what the children cover (overlaps counted once)."""
+    rec = SpanRecorder()
+    with rec.span("run", "run"):
+        with rec.span("round", "iter", round_idx=5):
+            with rec.span("dispatch", "phase", round_idx=5):
+                pass
+            # Pipelining: round 4's fetch inside round 5's iteration.
+            with rec.span("finalize", "round", round_idx=4):
+                with rec.span("host_sync", "phase", round_idx=4):
+                    pass
+    by = {s["name"]: s for s in rec.spans()}
+    assert by["run"]["parent"] is None
+    assert by["round"]["parent"] == by["run"]["id"]
+    assert by["dispatch"]["parent"] == by["round"]["id"]
+    assert by["finalize"]["parent"] == by["round"]["id"]
+    assert by["host_sync"]["parent"] == by["finalize"]["id"]
+    assert (by["host_sync"]["round"], by["finalize"]["round"]) == (4, 4)
+    assert by["dispatch"]["round"] == by["round"]["round"] == 5
+    assert {s["thread"] for s in rec.spans()} == {rec.main_thread}
+
+    # Another thread's spans do not nest under the main thread's.
+    import threading
+
+    def worker():
+        with rec.span("prefetch_upload", "stream", round_idx=5):
+            pass
+
+    with rec.span("outer", "phase"):
+        t = threading.Thread(target=worker)
+        t.start()
+        t.join()
+    up = [s for s in rec.spans() if s["name"] == "prefetch_upload"][0]
+    assert up["parent"] is None and up["thread"] != rec.main_thread
+
+    # The arithmetic, on hand-made spans: children [1,3] and [2,4]
+    # overlap, so they cover 3 of the parent's [0,10]; a child that
+    # sticks out past its parent is clipped to it.
+    made = [
+        {"id": 0, "parent": None, "t0": 0.0, "dur": 10.0},
+        {"id": 1, "parent": 0, "t0": 1.0, "dur": 2.0},
+        {"id": 2, "parent": 0, "t0": 2.0, "dur": 2.0},
+        {"id": 3, "parent": 0, "t0": 9.0, "dur": 5.0},
+        {"id": 4, "parent": 2, "t0": 2.5, "dur": 0.5},
+    ]
+    own = self_seconds(made)
+    assert own[0] == pytest.approx(10.0 - 3.0 - 1.0)
+    assert own[1] == 2.0 and own[2] == 1.5 and own[4] == 0.5
+    assert union_seconds([(1, 3), (2, 4), (6, 7)]) == 4.0
+    assert union_seconds([(1, 3), (2, 4), (6, 7)], lo=2.5, hi=6.5) == 2.0
+    assert union_seconds([]) == 0.0
+
+
+def test_one_clock_read_per_edge_feeds_span_and_phase(monkeypatch):
+    """One boundary = one call: the span record and the round's phase
+    accumulation come from the same two clock reads."""
+    reads = []
+    real = clock.monotonic
+
+    def counting():
+        reads.append(real())
+        return reads[-1]
+
+    monkeypatch.setattr(clock, "monotonic", counting)
+    rec = SpanRecorder(phases=PhaseTimer(fence=False))
+    with rec.span("dispatch", "phase", round_idx=2, phase="client_step"):
+        pass
+    with rec.span("record", "host", round_idx=2):
+        pass
+    assert len(reads) == 4
+    span = rec.spans()[0]
+    assert (span["t0"], span["t0"] + span["dur"]) == pytest.approx(
+        (reads[0], reads[1])
+    )
+    # The phase got exactly the span's duration, under the PHASE's name;
+    # a span without ``phase=`` feeds none.
+    assert rec.phases.take(2) == {"client_step": span["dur"]}
+
+
+def test_detailed_fences_before_the_clock_stops():
+    """``phase=`` spans wait for what the body parked (``box.fence``)
+    under a fencing PhaseTimer, so span and phase hold device time."""
+
+    class Slow:
+        waited = False
+
+    import jax
+
+    real = jax.block_until_ready
+    try:
+        jax.block_until_ready = lambda v: setattr(Slow, "waited", True)
+        rec = SpanRecorder(phases=PhaseTimer(fence=True))
+        with rec.span("dispatch", "phase", round_idx=0,
+                      phase="client_step") as box:
+            box.fence(object())
+        assert Slow.waited
+        Slow.waited = False
+        with rec.span("record", "host", round_idx=0) as box:
+            box.fence(object())  # not a phase: never waited on
+        assert not Slow.waited
+    finally:
+        jax.block_until_ready = real
+
+
+def test_counters_split_at_the_first_round_and_union_nested_traces():
+    """jax's tracing events nest (a jitted function traced inside
+    another's trace): the counters are unions of the events' intervals,
+    split before/after the first round completes."""
+    rec = SpanRecorder()
+    trace, lower, comp = DURATION_EVENTS
+
+    def at(t, event, dur):
+        # As jax.monitoring calls the run's one listener, the monitor's.
+        real = clock.monotonic
+        clock.monotonic = lambda: t
+        try:
+            rec.monitor._on_duration(event, dur)
+        finally:
+            clock.monotonic = real
+
+    at(1.0, trace, 0.5)      # inner trace [0.5, 1.0]
+    at(2.0, trace, 2.0)      # outer trace [0.0, 2.0] contains it
+    at(3.0, lower, 1.0)
+    at(5.0, comp, 2.0)
+    at(5.0, "/jax/some/other/event", 9.0)  # not ours
+    rec.round_done(0, 6.0)
+    at(7.0, comp, 0.25)
+    got = rec.counters()
+    assert got["trace_s"] == [2.0, 0.0]
+    assert got["lower_s"] == [1.0, 0.0]
+    assert got["compile_s"] == [2.0, 0.25]
+    assert got["rounds"] == 1 and got["host_syncs"] == 0
+    assert [k for k, _, _ in rec.duration_events()] == [
+        "trace_s", "trace_s", "lower_s", "compile_s", "compile_s",
+    ]
+    assert rec.round_stamps() == [(0, 6.0)]
+
+
+def test_null_tracer_is_inert(monkeypatch):
+    """telemetry 'off' with span_trace 'off': the same calls, no clock
+    read and nothing recorded (the body's fence box is a dead slot)."""
+    monkeypatch.setattr(
+        clock, "monotonic",
+        lambda: pytest.fail("the null tracer read the clock"),
+    )
+    tracer = NullTracer()
+    tracer.start()
+    tracer.section("setup/data")
+    with tracer.span("dispatch", "phase", round_idx=0,
+                     phase="client_step") as box:
+        box.fence(object())
+    tracer.round_done(0, 0.0)
+    tracer.finish()
+    assert tracer._section is None
+    assert not tracer.recording and not tracer.phases.enabled
+    assert tracer.phases.take(0) is None
+
+
+def test_start_run_and_last_run():
+    assert isinstance(spans.start_run("off", False), NullTracer)
+    assert spans.last_run() is None
+    rec = spans.start_run("basic", False, capacity=7)
+    assert spans.last_run() is rec and rec.capacity == 7
+    assert rec.phases.enabled and not rec.phases._fence
+    assert spans.start_run("detailed", False).phases._fence
+    on = spans.start_run("off", True)  # span_trace at level 'off'
+    assert spans.last_run() is on and not on.phases.enabled
+
+
+def test_flush_writes_setup_and_keeps_records_readable(tmp_path):
+    """The journal gets set-up spans too (recorded before it was
+    attached), and a flush removes nothing from memory."""
+    rec = SpanRecorder()
+    rec.start()
+    rec.section("setup/data")
+    rec.section(None)
+    path = rec.attach(str(tmp_path))
+    rec.round_done(0, clock.monotonic())
+    with rec.span("round", "iter", round_idx=1):
+        pass
+    assert rec.flush() == 2
+    assert rec.flush() == 0
+    rec.finish()
+    lines = [json.loads(l) for l in open(path)]
+    assert [l["name"] for l in lines if l["kind"] == "span"] == [
+        "setup/data", "round", "run",
+    ]
+    assert all("parent" in l and "thread" in l
+               for l in lines if l["kind"] == "span")
+    assert [s["name"] for s in rec.spans()] == [
+        "setup/data", "round", "run",
+    ]
 
 
 def test_journal_lines_and_round_summary(tmp_path):
@@ -322,6 +560,70 @@ def test_stitcher_summary_attributes_straggler(two_host_dir, tt):
     only0 = tt.summarize(journals, host=0)
     assert [h["host_id"] for h in only0["hosts"]] == [0]
     assert only0["postmortem"] == []
+
+
+def test_stitcher_counts_a_checkpoint_round_once(tmp_path, tt):
+    """A checkpoint round as the simulator and the sharded checkpoint
+    writer emit it: the in-memory envelopes (`round` per iteration,
+    `record`, `checkpoint`) enclose the leaves the cross-host analytics
+    sum, so they are in the journal but in neither the stitcher's busy
+    time nor the v12 ``seconds_by_cat``: busy = the sum of the leaf
+    spans, the barrier wait is wait (not busy), ``round`` is the
+    ``finalize`` envelope alone."""
+    rec = SpanRecorder(host_id=1, n_hosts=2)
+    path = rec.attach(str(tmp_path))
+    rec.start()
+    rec.section("setup/model_init")
+    rec.section(None)
+    with rec.span("round", "iter", round_idx=3):
+        with rec.span("dispatch", "phase", round_idx=3,
+                      phase="client_step"):
+            pass
+        with rec.span("finalize", "round", round_idx=3, eager=True):
+            with rec.span("host_sync", "phase", round_idx=3,
+                          phase="host_sync"):
+                pass
+            with rec.span("record", "host", round_idx=3):
+                pass
+            with rec.span("checkpoint", "host", round_idx=3):
+                with rec.span("ckpt_shard_write", "io", round_idx=3):
+                    pass
+                with rec.span("ckpt_barrier_wait", "dcn_wait",
+                              round_idx=3, eager=True):
+                    pass
+                with rec.span("ckpt_manifest", "io", round_idx=3):
+                    pass
+    summary = rec.round_summary(3)
+    run = rec.run_summary()
+    rec.finish()
+
+    by = {s["name"]: s["dur"] for s in rec.spans()}
+    leaves = ("dispatch", "host_sync", "ckpt_shard_write", "ckpt_manifest")
+    journal = tt.load_journal(path)
+    assert {s["name"] for s in journal["spans"]} == set(by)  # all there
+    host = tt.summarize([journal])["totals"]["1"]
+    assert host["busy_s"] == pytest.approx(
+        sum(by[n] for n in leaves), abs=1e-6
+    )
+    assert host["dcn_wait_s"] == pytest.approx(
+        by["ckpt_barrier_wait"], abs=1e-6
+    )
+
+    assert set(summary["seconds_by_cat"]) == {
+        "phase", "round", "io", "dcn_wait",
+    }
+    assert summary["seconds_by_cat"]["io"] == pytest.approx(
+        by["ckpt_shard_write"] + by["ckpt_manifest"], abs=2e-6
+    )
+    assert summary["seconds_by_cat"]["round"] == pytest.approx(
+        by["finalize"], abs=1e-6
+    )
+    assert summary["dcn_wait_s"] == pytest.approx(
+        by["ckpt_barrier_wait"], abs=1e-6
+    )
+    assert summary["count"] == len(leaves) + 2  # + finalize, the barrier
+    assert run["count"] == summary["count"]
+    assert set(run["seconds_by_cat"]) == set(summary["seconds_by_cat"])
 
 
 def test_stitcher_chrome_trace(two_host_dir, tt):

@@ -301,6 +301,202 @@ def test_simulator_telemetry_stable_run(tiny_config, tmp_path):
     assert "client_step" in rendered and "accuracy" in rendered
 
 
+def _top_level(rec):
+    root = [s for s in rec.spans()
+            if s["name"] == "run" and s["parent"] is None]
+    assert len(root) == 1
+    return root[0], [s for s in rec.spans() if s["parent"] == root[0]["id"]]
+
+
+def test_simulator_spans_cover_the_call(tiny_config, tmp_path):
+    """At 'basic' the ONE recorder lives from the first line of
+    run_simulation to its return: run -> setup/* -> one `round` per
+    iteration; the top-level spans cover the call; one host_sync a
+    round; the jax.monitoring counters see the compiles of set-up (the
+    op-by-op model init), before any round; last_run() reads it after
+    the return."""
+    import time
+
+    from distributed_learning_simulator_tpu.simulator import run_simulation
+    from distributed_learning_simulator_tpu.telemetry import spans
+
+    cfg = dataclasses.replace(
+        tiny_config, round=4, telemetry_level="basic",
+        compilation_cache_dir=None, log_root=str(tmp_path / "log"),
+        # A model the process has not initialised yet: its set-up compiles.
+        model_name="mlp", model_args={"hidden": 24},
+    )
+    t0 = time.perf_counter()
+    result = run_simulation(cfg)
+    wall = time.perf_counter() - t0
+    rec = spans.last_run()
+    assert rec is not None and rec.recording
+    assert result["span_summary"] is None  # span_trace is off
+    root, top = _top_level(rec)
+    assert root["dur"] == pytest.approx(wall, rel=0.02)
+    names = [s["name"] for s in top]
+    sections = [n for n in names if n.startswith("setup/")]
+    assert sections[:3] == ["setup/entry", "setup/data", "setup/model_init"]
+    assert {"setup/resume", "setup/build"} <= set(sections)
+    assert [s["round"] for s in top if s["name"] == "round"] == [0, 1, 2, 3]
+    assert names[-1] == "teardown"
+    covered = spans.union_seconds(
+        (s["t0"], s["t0"] + s["dur"]) for s in top
+    )
+    assert covered >= 0.95 * wall
+    # The loop body: dispatch -> eval_dispatch under `round`; the fetch,
+    # post_round and the record under `finalize` (pipelined: round r's
+    # inside round r+1's iteration, under ITS round number).
+    by_id = {s["id"]: s for s in rec.spans()}
+    for s in rec.spans():
+        if s["name"] in ("dispatch", "eval_dispatch"):
+            assert by_id[s["parent"]]["name"] == "round"
+            assert by_id[s["parent"]]["round"] == s["round"]
+        if s["name"] in ("host_sync", "post_round", "record"):
+            assert by_id[s["parent"]]["name"] == "finalize"
+            assert by_id[s["parent"]]["round"] == s["round"]
+    fin = [s for s in rec.spans() if s["name"] == "finalize"]
+    assert [by_id[s["parent"]].get("round") for s in fin] == [1, 2, 3, None]
+    counters = rec.counters()
+    assert counters["rounds"] == 4 and counters["host_syncs"] == 4
+    assert len(rec.round_stamps()) == 4
+    # Compiles before the first round completed: model init among them.
+    assert counters["compile_s"][0] > 0 and counters["trace_s"][0] > 0
+    in_init = [
+        t for key, t, _ in rec.duration_events()
+        if key == "compile_s" and any(
+            s["name"] == "setup/model_init"
+            and s["t0"] <= t <= s["t0"] + s["dur"] for s in top
+        )
+    ]
+    assert in_init, "no compile seen inside setup/model_init"
+    assert counters["compile_s"][1] == 0.0  # and none after warm-up
+    # phase_seconds come from the same spans, under the PHASE names.
+    assert set(result["history"][-1]["telemetry"]["phase_seconds"]) == {
+        "client_step", "eval", "host_sync", "post_round",
+    }
+    disp = [s for s in rec.spans()
+            if s["name"] == "dispatch" and s["round"] == 3][0]
+    assert result["history"][-1]["telemetry"]["phase_seconds"][
+        "client_step"] == round(disp["dur"], 6)
+
+
+def test_one_monitoring_listener_from_the_first_line(tiny_config, tmp_path,
+                                                     monkeypatch):
+    """The run has ONE ``jax.monitoring`` duration listener, the
+    RecompileMonitor's: switched on by the tracer at the first line of
+    run_simulation (the recorder's trace/lower/compile counters come
+    through it), counting for the records only from the round loop on,
+    and unregistered by the return."""
+    import jax
+    from jax._src import monitoring
+
+    from distributed_learning_simulator_tpu.simulator import run_simulation
+    from distributed_learning_simulator_tpu.telemetry import spans
+
+    registered = []
+    real = jax.monitoring.register_event_duration_secs_listener
+    monkeypatch.setattr(
+        jax.monitoring, "register_event_duration_secs_listener",
+        lambda fn: (registered.append(fn), real(fn))[1],
+    )
+    before = list(monitoring.get_event_duration_listeners())
+    cfg = dataclasses.replace(
+        tiny_config, round=2, telemetry_level="basic",
+        compilation_cache_dir=None, log_root=str(tmp_path / "log"),
+        model_name="mlp", model_args={"hidden": 20},  # set-up compiles
+    )
+    result = run_simulation(cfg)
+    rec = spans.last_run()
+    assert registered == [rec.monitor._on_duration]
+    assert list(monitoring.get_event_duration_listeners()) == before
+    # Heard by the recorder from the model init on ...
+    compiles = [t for key, t, _ in rec.duration_events()
+                if key == "compile_s"]
+    init_ends = max(s["t0"] + s["dur"] for s in rec.spans()
+                    if s["name"] == "setup/model_init")
+    assert [t for t in compiles if t <= init_ends]
+    # ... and not counted into the records: round 0 holds the loop's
+    # warm-up compiles alone.
+    warm = result["history"][0]["telemetry"]["compiles"]
+    assert 0 < warm < len(compiles)
+    assert result["post_warmup_compiles"] == 0
+
+
+def test_records_do_not_change_with_the_recorder(tiny_config, tmp_path):
+    """'off' and 'basic' train the same model and write the same record
+    apart from the v2 telemetry sub-object; the recorder adds no key
+    ('spans' is span_trace's), and at 'off' there is no recorder."""
+    from distributed_learning_simulator_tpu.telemetry import spans
+
+    runs = {}
+    for level in ("off", "basic"):
+        cfg = dataclasses.replace(
+            tiny_config, round=3, telemetry_level=level,
+            compilation_cache_dir=None,
+            log_root=str(tmp_path / level),
+        )
+        result, records, _ = _run_with_artifacts(cfg)
+        assert [json.dumps(r) for r in records] == [
+            json.dumps(r) for r in result["history"]
+        ]
+        runs[level] = records
+        assert (spans.last_run() is None) == (level == "off")
+    for off, basic in zip(runs["off"], runs["basic"]):
+        assert list(off) == ["round", "test_accuracy", "test_loss",
+                             "mean_client_loss", "round_seconds"]
+        assert list(basic) == list(off) + ["schema_version", "telemetry"]
+        assert basic["schema_version"] == 2
+        for key in ("round", "test_accuracy", "test_loss",
+                    "mean_client_loss"):
+            assert off[key] == basic[key]
+        assert set(basic["telemetry"]) <= {
+            "phase_seconds", "compiles", "compiled", "peak_hbm_bytes",
+        }
+
+
+def test_span_trace_journals_the_same_spans(tiny_config, tmp_path):
+    """span_trace='on' adds the journal (and the v12 `spans` sub-object)
+    to the SAME recorder: set-up sections, parents and rounds are in the
+    file; the root span `run` is its last span line."""
+    cfg = dataclasses.replace(
+        tiny_config, round=2, telemetry_level="basic", span_trace="on",
+        compilation_cache_dir=None, log_root=str(tmp_path / "log"),
+        checkpoint_dir=str(tmp_path / "ckpt"), checkpoint_every=1,
+    )
+    result, records, artifacts = _run_with_artifacts(cfg)
+    assert all(r["schema_version"] == 12 and "spans" in r for r in records)
+    # The v12 sub-object sums the leaf categories alone: the in-memory
+    # envelopes (the iteration's `round`, `record`, `checkpoint`, set-up)
+    # are journaled but not summed, so span_trace's numbers keep their
+    # meaning.
+    for r in records:
+        assert set(r["spans"]["seconds_by_cat"]) <= {"phase", "round"}
+    assert set(result["span_summary"]["seconds_by_cat"]) <= {
+        "phase", "round", "compile",
+    }
+    path = result["span_summary"]["journal_path"]
+    assert os.path.dirname(path) == artifacts
+    lines = [json.loads(line) for line in open(path)]
+    spans_ = [l for l in lines if l["kind"] == "span"]
+    names = [l["name"] for l in spans_]
+    assert names[-1] == "run" and spans_[-1]["parent"] is None
+    assert "setup/model_init" in names and "teardown" in names
+    assert names.count("round") == 2 and names.count("host_sync") == 2
+    assert names.count("checkpoint") == 2
+    cats = {l["name"]: l["cat"] for l in spans_}
+    assert (cats["round"], cats["checkpoint"], cats["record"],
+            cats["finalize"]) == ("iter", "host", "host", "round")
+    finalize = sum(l["dur"] for l in spans_ if l["name"] == "finalize")
+    assert result["span_summary"]["seconds_by_cat"]["round"] == (
+        pytest.approx(finalize, abs=1e-5)
+    )
+    opens = [l["name"] for l in lines if l["kind"] == "open"]
+    assert opens == ["finalize", "finalize"]  # the eager envelope
+    assert not [l for l in lines if l.get("name") == "dispatch"
+                and l["kind"] == "event"]  # the old instant mark is gone
+
+
 def test_simulator_telemetry_off_keeps_v1_records(tiny_config, tmp_path):
     """telemetry_level='off' (the default) emits the legacy v1 record —
     exactly the pre-telemetry key set, no schema_version, no telemetry

@@ -17,6 +17,7 @@ import pytest
 from distributed_learning_simulator_tpu.utils.tracing import (
     OP_CLASSES,
     STAGE_RULES,
+    attribute_idle_gaps,
     categorize_long_name,
     categorize_ops,
     classify_op,
@@ -237,3 +238,121 @@ def test_top_device_ops_ranks_by_bytes(tmp_path):
     assert report["totals"]["device_ms"] == pytest.approx(
         single["device_ms"]
     )
+
+
+# ----------------------------------------------------- idle-gap attribution
+
+
+def _meta(pid, name, tid=None, thread=None):
+    if tid is None:
+        return {"ph": "M", "pid": pid, "name": "process_name",
+                "args": {"name": name}}
+    return {"ph": "M", "pid": pid, "tid": tid, "name": "thread_name",
+            "args": {"name": thread}}
+
+
+def _x(pid, tid, name, ts, dur, **args):
+    return {"ph": "X", "pid": pid, "tid": tid, "name": name, "ts": ts,
+            "dur": dur, "args": args}
+
+
+def test_attribute_idle_gaps_names_gaps_by_host_spans(tmp_path):
+    """A hand-made capture with a host plane: two chips run round_fn then
+    server_eval twice; the host's spans (TraceAnnotations carrying
+    ``cat``) lie on the same clock. Each gap between programs is named by
+    the INNERMOST span over its midpoint; runtime events without ``cat``
+    never name one; a gap no span covers reads ``<none>``."""
+    events = [
+        _meta(1, "/device:TPU:0"), _meta(1, None, 2, "XLA Modules"),
+        _meta(1, None, 3, "XLA Ops"),
+        _meta(2, "/device:TPU:1"), _meta(2, None, 2, "XLA Modules"),
+        _meta(9, "/host:CPU"), _meta(9, None, 7, "python"),
+    ]
+    for pid in (1, 2):
+        events += [
+            _x(pid, 2, "jit_round_fn(123)", 1000.0, 600.0),
+            # gap 1600-1610 (10 us): inside `eval_dispatch`
+            _x(pid, 2, "jit_server_eval(456)", 1610.0, 90.0),
+            # gap 1700-2000 (300 us): inside `dispatch` of the next round
+            _x(pid, 2, "jit_round_fn(123)", 2000.0, 600.0),
+            # gap 2600-2640 (40 us): no span of the program covers it
+            _x(pid, 2, "jit_server_eval(456)", 2640.0, 90.0),
+        ]
+    # An op lane event between the programs must not split the gap.
+    events.append(_x(1, 3, "fusion.1", 1601.0, 2.0, long_name="fusion"))
+    events += [
+        _x(9, 7, "round", 900.0, 850.0, cat="round", round="0"),
+        _x(9, 7, "eval_dispatch", 1590.0, 30.0, cat="phase", round="0"),
+        _x(9, 7, "round", 1750.0, 800.0, cat="round", round="1"),
+        _x(9, 7, "dispatch", 1800.0, 150.0, cat="phase", round="1"),
+        # A runtime TraceMe without `cat` covering everything: ignored.
+        _x(9, 7, "PjitFunction(round_fn)", 0.0, 5000.0),
+    ]
+    _write_trace(str(tmp_path), events)
+    rows = attribute_idle_gaps(str(tmp_path))
+    assert rows == [
+        {"span": "dispatch", "count": 2, "seconds": pytest.approx(600e-6),
+         "between": "jit_server_eval->jit_round_fn"},
+        {"span": "<none>", "count": 2, "seconds": pytest.approx(80e-6),
+         "between": "jit_round_fn->jit_server_eval"},
+        {"span": "eval_dispatch", "count": 2,
+         "seconds": pytest.approx(20e-6),
+         "between": "jit_round_fn->jit_server_eval"},
+    ]
+    assert attribute_idle_gaps(str(tmp_path / "missing")) == []
+
+
+def test_report_run_renders_the_gap_attribution():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "report_run",
+        os.path.join(os.path.dirname(__file__), "..", "scripts",
+                     "report_run.py"),
+    )
+    report_run = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(report_run)
+    summary = report_run.summarize_run(
+        [{"round": 0, "test_accuracy": 0.5, "round_seconds": 1.0}],
+        idle_gaps=[{"span": "dispatch", "count": 2, "seconds": 0.0037,
+                    "between": "jit_server_eval->jit_round_fn"}],
+    )
+    assert summary["device_idle_gaps"][0]["span"] == "dispatch"
+    text = "\n".join(report_run.render_summary(summary))
+    assert "device idle gaps by host span:" in text
+    assert "3.700 ms" in text and "jit_server_eval->jit_round_fn" in text
+
+
+def test_session_events_fall_back_to_the_xplane_at_the_event_cap(
+        tmp_path, monkeypatch):
+    """The trace-viewer JSON drops events beyond its cap in silence, so a
+    capture that reaches it is read from the ``.xplane.pb``: the host
+    plane's spans arrive with their ``cat``/``round`` metadata, runtime
+    events without ``cat`` are left out."""
+    import jax.numpy as jnp
+
+    from distributed_learning_simulator_tpu.telemetry.spans import NullTracer
+    from distributed_learning_simulator_tpu.utils import tracing
+
+    tracer = NullTracer()
+    with tracing.profile_session(str(tmp_path)):
+        with tracer.span("round", "round", round_idx=7):
+            with tracer.span("dispatch", "phase", round_idx=7):
+                jnp.ones(8).sum().block_until_ready()
+    from_json = [e for e in tracing._session_events(str(tmp_path))
+                 if "cat" in (e.get("args") or {})]
+    monkeypatch.setattr(tracing, "_EVENT_CAP", 1)
+    events = tracing._session_events(str(tmp_path))
+    spans = [e for e in events if e["ph"] == "X"]
+    assert sorted(e["name"] for e in spans) == ["dispatch", "round"]
+    assert {e["args"]["cat"] for e in spans} == {"phase", "round"}
+    assert {int(e["args"]["round"]) for e in spans} == {7}
+    planes = [e["args"]["name"] for e in events
+              if e["ph"] == "M" and e["name"] == "process_name"]
+    assert planes == ["/host:CPU"]
+    # Same spans, same clock, by either route.
+    assert sorted((e["name"], round(e["ts"], 1)) for e in from_json) == (
+        sorted((e["name"], round(e["ts"], 1)) for e in spans)
+    )
+    # No device plane in a CPU capture: nothing to attribute, no error.
+    assert tracing.attribute_idle_gaps(str(tmp_path)) == []

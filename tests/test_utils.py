@@ -2,15 +2,17 @@
 
 import numpy as np
 
+from distributed_learning_simulator_tpu.telemetry.spans import NullTracer
 from distributed_learning_simulator_tpu.utils.tracing import (
-    annotate,
     profile_session,
 )
 
 
-def test_profile_session_noop_and_annotate():
+def test_profile_session_noop_and_annotated_span():
+    """No profile dir, telemetry off: the session is a no-op and a
+    boundary is a bare (inert) profiler annotation."""
     with profile_session(None):
-        with annotate("test_region"):
+        with NullTracer().span("test_region", "phase", round_idx=3):
             x = np.arange(4).sum()
     assert x == 6
 
@@ -186,24 +188,31 @@ def test_profile_from_round_defers_trace(tmp_path, tiny_config):
     res = run_simulation(cfg, setup_logging=False)
     assert len(res["history"]) == 3
     assert os.path.isdir(traced)
-    # The deferral must be visible in the captured events: the per-round
-    # `annotate(f"fl_round_N")` regions for rounds >= from_round are in
-    # the trace, round 0's is NOT (a regression that starts the trace at
-    # round 0 would put fl_round_0 in here).
+    # The deferral must be visible in the captured events: the loop's
+    # `round` annotations (the tracer's spans; the round number is
+    # their metadata) for rounds >= from_round are in the trace, round
+    # 0's is NOT (a regression that starts the trace at round 0 would
+    # put it in here). Their children are there under their own names.
     import glob
     import gzip
     import json
 
-    names = set()
+    rounds, names = set(), set()
     for path in glob.glob(
         os.path.join(traced, "**", "*.trace.json.gz"), recursive=True
     ):
         with gzip.open(path, "rt") as f:
             for ev in json.load(f).get("traceEvents", []):
-                if str(ev.get("name", "")).startswith("fl_round_"):
-                    names.add(ev["name"])
-    assert "fl_round_1" in names and "fl_round_2" in names, names
-    assert "fl_round_0" not in names, names
+                args = ev.get("args") or {}
+                if "cat" not in args:
+                    continue
+                names.add(ev["name"])
+                if ev["name"] == "round":
+                    assert args["cat"] == "iter"
+                    rounds.add(int(args["round"]))
+    assert rounds == {1, 2}, rounds
+    assert {"dispatch", "eval_dispatch", "host_sync", "record"} <= names
+    assert not any(n.startswith("fl_round") for n in names), names
 
     never = str(tmp_path / "never")
     cfg2 = dataclasses.replace(
