@@ -167,6 +167,13 @@ class Algorithm:
         """
         raise NotImplementedError
 
+    def local_steps_unrolled(self, shard_size: int) -> int:
+        """How many local steps this algorithm's round program holds
+        unrolled for shards of ``shard_size`` samples; 0 where the local
+        steps stay a loop (parallel/engine.UNROLL_MAX_LOCAL_STEPS). The
+        run's span recorder keeps it as a counter of the same name."""
+        return 0
+
     def init_client_state(self, optimizer, global_params, n_clients):
         """Initial per-client persistent state (client-stacked pytree).
 

@@ -34,6 +34,7 @@ from distributed_learning_simulator_tpu.ops.sampling import (
 )
 from distributed_learning_simulator_tpu.parallel.engine import (
     chunked_accumulate,
+    local_steps_unrolled,
     make_local_train_fn,
 )
 from distributed_learning_simulator_tpu.robustness.arrivals import (
@@ -273,6 +274,11 @@ class FedAvg(Algorithm):
         part_key = round_key_splits(round_key, with_faults)[0]
         return draw_cohort_host(part_key, n_clients, n_participants,
                                 sampler, alive=alive)
+
+    def local_steps_unrolled(self, shard_size: int) -> int:
+        return local_steps_unrolled(
+            self.config.epoch, shard_size // self.config.batch_size
+        )
 
     def make_round_fn(self, apply_fn, optimizer, n_clients: int,
                       preprocess=None, client_sizes=None):

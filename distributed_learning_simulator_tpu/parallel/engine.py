@@ -124,6 +124,28 @@ def _sr_tree_to_bf16(tree, salt):
     return jax.tree_util.tree_unflatten(treedef, out), salt
 
 
+#: A local run of at most this many optimizer steps (epochs x steps per
+#: epoch, both read from shapes while tracing) is unrolled: each step is
+#: compiled as its own copy, so XLA sees that the first step's optimizer
+#: state is ``optimizer.init``'s zeros and that the last step's is dead
+#: (under ``reset_optimizer``), moves neither through HBM, and fuses the
+#: last step's update into the reduce over clients. That is 2 of the
+#: update's 4*S state transfers (momentum and parameters, in and out) and
+#: the last parameter store: a quarter of them at S = 2, an eighth at
+#: S = 4, while the compiled program and its cold compile grow S-fold. 2 is
+#: the step count the benchmark's cells run and the only one measured on
+#: the chip (+3.5 % client-rounds/s on the flagship; PERF.md § 6, PR 26).
+UNROLL_MAX_LOCAL_STEPS = 2
+
+
+def local_steps_unrolled(local_epochs: int, steps_per_epoch: int) -> int:
+    """How many local steps ``local_train`` compiles unrolled for a run of
+    ``local_epochs`` x ``steps_per_epoch`` steps: all of them up to
+    :data:`UNROLL_MAX_LOCAL_STEPS`, else 0 (both scans stay rolled)."""
+    total = local_epochs * steps_per_epoch
+    return total if total <= UNROLL_MAX_LOCAL_STEPS else 0
+
+
 def make_local_train_fn(
     apply_fn,
     optimizer,
@@ -143,6 +165,23 @@ def make_local_train_fn(
     data/partition.py guarantees it). Matches the reference hot loop
     ``for _ in range(E): epoch of SGD`` (external Trainer.train called at
     fed_worker.py:25-27) but as two nested ``lax.scan``s.
+
+    What one step moves through HBM per client, counted in the flagship's
+    device trace (PERF.md § 5): the parameters are read three times (the
+    forward, the input-gradient and the weight-gradient convolution, the
+    last fused with the momentum trace, the update and the stochastic
+    rounding) and written once, the momentum is read once and written
+    once; around the steps the broadcast's rounding writes the parameters
+    and the reduce reads them. The scans carry that state through a
+    ``while`` loop, so a run of S steps makes 6*S + 2 such transfers. A run
+    of at most :data:`UNROLL_MAX_LOCAL_STEPS` steps is unrolled instead
+    (``lax.scan``'s own ``unroll``: the body is still traced and lowered
+    once): XLA then drops the first step's momentum read (zeros from
+    ``optimizer.init``), the last step's momentum write (dead under
+    ``reset_optimizer``) and, fusing the last update into the reduce, the
+    last parameter write and the reduce's read — 10 transfers for 14 at
+    S = 2. The mathematics, the rounding salts and the permutation stream
+    are the scan's.
 
     vmap over the client axis: ``jax.vmap(local_train, in_axes=(None, 0, 0,
     0, 0, 0))`` — global params broadcast (the init-model broadcast of
@@ -191,6 +230,9 @@ def make_local_train_fn(
             )
         shard_size = xs.shape[0]
         steps_per_epoch = shard_size // batch_size
+        # One traced body either way; unrolled, XLA sees the iterations
+        # apart (UNROLL_MAX_LOCAL_STEPS).
+        unroll = local_steps_unrolled(local_epochs, steps_per_epoch) > 0
         aug_key = None
         if augment is not None:
             # Split only when augmenting so the un-augmented RNG stream
@@ -210,6 +252,14 @@ def make_local_train_fn(
 
             def step_body(carry, step):
                 params, opt_state, sr_state = carry
+                if unroll:
+                    # What a loop carry gives for free: the parameters
+                    # exist in HBM at the step's entry. Without it XLA
+                    # recomputes their stochastic rounding inside every
+                    # convolution that reads them — fewer bytes, but the
+                    # flagship round 1.7 % slower (PERF.md § 6, PR 26).
+                    # The optimizer state stays free to fold.
+                    params = jax.lax.optimization_barrier(params)
                 idx = jax.lax.dynamic_slice_in_dim(
                     perm, step * batch_size, batch_size
                 )
@@ -265,7 +315,7 @@ def make_local_train_fn(
 
             (params, opt_state, sr_state), step_outs = jax.lax.scan(
                 step_body, (params, opt_state, sr_state),
-                jnp.arange(steps_per_epoch),
+                jnp.arange(steps_per_epoch), unroll=unroll,
             )
             if collect_stats:
                 losses, accs, grad_sqs = step_outs
@@ -282,7 +332,7 @@ def make_local_train_fn(
         (params, opt_state, sr_state), epoch_outs = (
             jax.lax.scan(
                 epoch_body, (params, opt_state, sr_state),
-                (epoch_keys, jnp.arange(local_epochs)),
+                (epoch_keys, jnp.arange(local_epochs)), unroll=unroll,
             )
         )
         if collect_stats:

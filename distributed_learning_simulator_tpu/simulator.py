@@ -863,6 +863,10 @@ def run_simulation(
                 )
 
         round_jit = jax.jit(round_fn, donate_argnums=(1,))
+        tracer.set_counter(
+            "local_steps_unrolled",
+            algorithm.local_steps_unrolled(client_data.shard_size),
+        )
 
         # Optional server-side optimizer (FedOpt; exceeds the reference): the
         # aggregate is post-processed by a jitted pseudo-gradient step.

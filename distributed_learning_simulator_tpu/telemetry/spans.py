@@ -26,7 +26,8 @@ list of their own that is never evicted; later ones in a bounded ring
 journal counts into ``dropped``; ``evicted_until`` says up to when the
 ring has lost anything, so a reader knows whether its window is whole).
 Counters sit at the same boundaries: completed rounds with their stamps
-(:meth:`SpanRecorder.round_done`), completed ``host_sync`` spans, and
+(:meth:`SpanRecorder.round_done`), completed ``host_sync`` spans, counts
+fixed when the programs are built (:meth:`SpanRecorder.set_counter`), and
 the ``jax.monitoring`` durations of tracing, lowering and backend
 compiles (or cache loads), heard from the first line of the run by the
 run's ONE listener, the :class:`RecompileMonitor`'s (``trace_s``,
@@ -61,7 +62,8 @@ Journal line taxonomy (all JSONL, one object per line):
 ``open``     eager begin marker (flight recorder); matched by a later
              ``span`` line with the same ``id`` unless the host died.
 ``span``     completed span: ``t0`` (monotonic), ``dur`` seconds.
-``event``    instant event (recompiles).
+``event``    instant event (recompiles; cat ``counter``: a build-time
+             count, ``attrs.value``).
 ``flight``   force-flush marker with the triggering ``reason`` and, when
              an exception unwound through a span first, the ``in_span``
              it escaped from (name/cat/round + exception type).
@@ -257,6 +259,8 @@ class SpanRecorder(_Sections):
             maxlen=capacity
         )
         self._host_syncs = 0
+        # Counts fixed when the run's programs are built (set_counter).
+        self._build_counts: dict[str, int] = {}
         self._jax_setup: list[tuple] = []
         self._jax_ring: collections.deque = collections.deque(
             maxlen=capacity
@@ -441,6 +445,18 @@ class SpanRecorder(_Sections):
                 self._in_setup = False
                 self._first_round_t = t
 
+    def set_counter(self, name: str, value: int) -> None:
+        """Record a count that is fixed once, when the run's programs are
+        built (``local_steps_unrolled``: how many local steps the round
+        program holds unrolled, 0 on the scan path). It joins
+        :meth:`counters` and goes to the journal as an ``event`` line of
+        cat ``counter`` with the count under ``attrs.value``."""
+        ev = {"kind": "event", "name": name, "cat": "counter",
+              "t": clock.monotonic(), "attrs": {"value": int(value)}}
+        with self._lock:
+            self._build_counts[name] = int(value)
+            self._append_locked(ev)
+
     def _on_duration(self, counter: str, t_end: float,
                      seconds: float) -> None:
         """The monitor's ``on_event``: one tracing, lowering or backend
@@ -587,13 +603,15 @@ class SpanRecorder(_Sections):
     def counters(self) -> dict:
         """``trace_s`` / ``lower_s`` / ``compile_s`` as ``[before,
         after]`` the first round completed (unions of the events'
-        intervals), ``host_syncs`` (completed ``host_sync`` spans) and
-        ``rounds`` (completed rounds)."""
+        intervals), ``host_syncs`` (completed ``host_sync`` spans),
+        ``rounds`` (completed rounds) and what :meth:`set_counter` was
+        given (``local_steps_unrolled``)."""
         events = self.duration_events()
         with self._lock:
             cut = self._first_round_t
             host_syncs = self._host_syncs
             rounds = self._rounds
+            build_counts = dict(self._build_counts)
         cut = float("inf") if cut is None else cut
         out: dict = {}
         for key in DURATION_EVENTS.values():
@@ -604,6 +622,7 @@ class SpanRecorder(_Sections):
             ]
         out["host_syncs"] = host_syncs
         out["rounds"] = rounds
+        out.update(build_counts)
         return out
 
     # ------------------------------------------------------------------
@@ -764,6 +783,9 @@ class NullTracer(_Sections):
             yield _SpanBox()
 
     def round_done(self, round_idx: int, t: float) -> None:
+        return None
+
+    def set_counter(self, name: str, value: int) -> None:
         return None
 
 

@@ -56,9 +56,12 @@ transfer split, and the barrier-skew timeline; telemetry/spans.py).
 When ``spans_*.jsonl`` journals sit next to ``metrics.jsonl`` (or a
 shared ``span_dir`` is passed via ``--spans``), the cross-host
 timeline section is stitched live through ``scripts/trace_timeline.py``
-— per-host busy/wait totals, per-round barrier skew with the slowest
-host named, and the flight-recorder postmortem (what each host was
-doing when it died); ``--host`` restricts it to one host. The only
+— per-host span and event counts with the recorder's build-time
+counters under them (``local_steps_unrolled``: how many local steps the
+round program holds unrolled, 0 = a loop), busy/wait totals, per-round
+barrier skew with the slowest host named, and the flight-recorder
+postmortem (what each host was doing when it died); ``--host``
+restricts it to one host. The only
 heavy import (jax, via utils.tracing) is deferred behind ``--trace``,
 so metrics-only reporting is instant.
 """

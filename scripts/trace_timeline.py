@@ -260,6 +260,12 @@ def summarize(journals: list[dict], host: int | None = None) -> dict:
             "clock_uncertainty_s": h.get("clock_uncertainty_s", 0.0),
             "spans": len(j["spans"]),
             "events": len(j["events"]),
+            # Build-time counts the recorder journaled (set_counter):
+            # ``local_steps_unrolled``.
+            "counters": {
+                e["name"]: e.get("attrs", {}).get("value")
+                for e in j["events"] if e.get("cat") == "counter"
+            },
         })
     busy_sum = sum(t["busy_s"] for t in totals.values())
     for hid, t in totals.items():
@@ -347,6 +353,8 @@ def render_text(summary: dict) -> str:
             f"(+/- {h['clock_uncertainty_s'] * 1e3:.3f} ms) "
             f"[{os.path.basename(h['journal'])}]"
         )
+        for name, value in sorted(h["counters"].items()):
+            lines.append(f"    {name}: {value}")
     lines.append("== totals ==")
     for hid, t in summary["totals"].items():
         lines.append(

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from distributed_learning_simulator_tpu.models.registry import get_model, init_params
+from distributed_learning_simulator_tpu.parallel import engine
 from distributed_learning_simulator_tpu.parallel.engine import (
     make_eval_fn,
     make_local_train_fn,
@@ -149,3 +150,143 @@ def test_chunked_accumulate_shard_local_chunks(n, chunk, shards):
     np.testing.assert_allclose(acc, np.asarray(w) @ np.asarray(x), rtol=1e-5)
     np.testing.assert_array_equal(per, per1)
     np.testing.assert_array_equal(per, np.asarray(x) * 2.0)
+
+
+# --- short local runs are compiled unrolled (UNROLL_MAX_LOCAL_STEPS) --------
+
+_UNROLL_MAX = engine.UNROLL_MAX_LOCAL_STEPS
+_BATCH = 4
+
+
+def _short_run(steps, epochs=1, momentum=0.9, reset=True, bf16=False,
+               stats=False):
+    """A jitted ``local_train`` on a 6-feature MLP and its arguments:
+    ``steps`` minibatches of ``_BATCH`` an epoch. Whether the scans unroll
+    is decided when the function is first traced."""
+    model = get_model("mlp", num_classes=4)
+    params = init_params(model, np.zeros((1, 6), np.float32))
+    opt = make_optimizer("SGD", 0.05, momentum=momentum)
+    local_train = make_local_train_fn(
+        model.apply, opt, local_epochs=epochs, batch_size=_BATCH,
+        reset_optimizer=reset,
+        compute_dtype=jnp.bfloat16 if bf16 else None, collect_stats=stats,
+    )
+    rng = np.random.default_rng(steps)
+    n = steps * _BATCH
+    state_params = params
+    if bf16:
+        state_params = jax.tree_util.tree_map(
+            lambda p: p.astype(jnp.bfloat16), params
+        )
+    # A persistent optimizer state that is not zeros: with
+    # reset_optimizer=False the first step's momentum is an input.
+    opt_state = jax.tree_util.tree_map(
+        lambda s: s + jnp.asarray(0.25, s.dtype), opt.init(state_params)
+    )
+    args = (
+        params, opt_state,
+        jnp.asarray(rng.normal(size=(n, 6)).astype(np.float32)),
+        jnp.asarray(rng.integers(0, 4, size=n).astype(np.int32)),
+        jnp.ones(n), jax.random.key(3),
+    )
+    return jax.jit(local_train), args
+
+
+@pytest.mark.parametrize("stats", [False, True], ids=["nostats", "stats"])
+@pytest.mark.parametrize("bf16", [False, True], ids=["f32", "bf16sr"])
+@pytest.mark.parametrize("reset", [True, False], ids=["reset", "keep"])
+@pytest.mark.parametrize("momentum", [0.0, 0.9])
+@pytest.mark.parametrize("epochs", [1, 2])
+@pytest.mark.parametrize("steps", sorted({1, 2, _UNROLL_MAX, _UNROLL_MAX + 1}))
+def test_unrolled_local_train_matches_scanned(
+    monkeypatch, steps, epochs, momentum, reset, bf16, stats
+):
+    """The unrolled and the scanned form of ``local_train`` are one
+    computation: parameters, returned optimizer state and metrics agree
+    on the same inputs. f32 state: exactly, the same operations run in
+    the same order. bf16 state with stochastic rounding: to two bf16 ulps
+    (2**-6 relative). The dither is a hash of the f32 sum's bits, so a
+    last-bit difference in that sum (XLA may keep an f32 intermediate
+    where the scan's carry rounds it to bf16) flips a rounding decision
+    and moves that weight one ulp in each of the two steps; on today's
+    CPU backend the difference is 0. A run above the constant is the
+    bypass: it lowers to the same program text whatever the constant."""
+    kw = dict(epochs=epochs, momentum=momentum, reset=reset, bf16=bf16,
+              stats=stats)
+    unrolled = engine.local_steps_unrolled(epochs, steps)
+    assert unrolled == (epochs * steps if epochs * steps <= _UNROLL_MAX else 0)
+    fn, args = _short_run(steps, **kw)
+    got = fn(*args) if unrolled else fn.lower(*args).as_text()
+    monkeypatch.setattr(engine, "UNROLL_MAX_LOCAL_STEPS", 0)
+    twin, _ = _short_run(steps, **kw)
+    if not unrolled:
+        assert got == twin.lower(*args).as_text()
+        return
+    want = twin(*args)
+    assert (jax.tree_util.tree_structure(got)
+            == jax.tree_util.tree_structure(want))
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.dtype == b.dtype
+        a, b = (np.asarray(v, dtype=np.float32) for v in (a, b))
+        if bf16:
+            np.testing.assert_allclose(a, b, rtol=2.0 ** -6, atol=1e-6)
+        else:
+            np.testing.assert_array_equal(a, b)
+
+
+def test_unrolled_lowering_traces_the_step_once():
+    """What guards the first round's tracing and lowering: unrolled, the
+    step body is still lowered once (a ``closed_call`` called twice), so
+    the module holds as many matmuls as the scanned form; only the step
+    loop's ``stablehlo.while`` is gone (the length-1 epoch scan never had
+    one) and the parameters pass a barrier at the step's entry, where
+    the loop carried them. One step above the constant the loop is
+    there and the barrier is not."""
+    def counts(steps, unroll_max):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(engine, "UNROLL_MAX_LOCAL_STEPS", unroll_max)
+            fn, args = _short_run(steps)
+            text = fn.lower(*args).as_text()
+        return (text.count("stablehlo.while"), text.count("dot_general"),
+                text.count("stablehlo.optimization_barrier"))
+
+    whiles, dots, barriers = counts(2, _UNROLL_MAX)
+    scanned_whiles, scanned_dots, scanned_barriers = counts(2, 0)
+    assert (whiles, dots) == (scanned_whiles - 1, scanned_dots)
+    assert (barriers, scanned_barriers) == (1, 0)  # in the one step body
+    assert counts(_UNROLL_MAX + 1, _UNROLL_MAX) == counts(_UNROLL_MAX + 1, 0)
+    assert counts(_UNROLL_MAX + 1, 0) == (scanned_whiles, scanned_dots, 0)
+
+
+def _compiled_momentum_program(reset, unroll_max):
+    """Compiled (CPU) HLO text of a 2-step momentum run, and how many of
+    its instructions are ``optimizer.init``'s zeros: a scalar broadcast to
+    a parameter's shape traced directly under ``local_train``."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(engine, "UNROLL_MAX_LOCAL_STEPS", unroll_max)
+        fn, args = _short_run(2, momentum=0.9, reset=reset)
+        text = fn.lower(*args).compile().as_text()
+    init_zeros = [
+        line for line in text.splitlines()
+        if 'op_name="jit(local_train)/broadcast_in_dim"' in line
+        and " broadcast(" in line
+    ]
+    return text, len(init_zeros)
+
+
+def test_unrolled_momentum_folds_the_zero_state():
+    """The mechanism itself, on the compiled program: with a fresh
+    optimizer and two unrolled steps XLA sees the first step's momentum
+    is zeros, so no zeros of a parameter's shape are made and the decay
+    multiplies once a leaf (step 2) — not twice, as it does when the
+    first state is an input, and not through a loop-carried zero state,
+    as in the scanned form."""
+    n_leaves = 4  # two Dense layers: kernel and bias each
+    text, zeros = _compiled_momentum_program(True, _UNROLL_MAX)
+    assert zeros == 0
+    assert text.count("constant(0.9)") == n_leaves
+    kept, _ = _compiled_momentum_program(False, _UNROLL_MAX)
+    assert kept.count("constant(0.9)") == 2 * n_leaves
+    _, scanned_zeros = _compiled_momentum_program(True, 0)
+    assert scanned_zeros > 0  # the check sees them where they exist

@@ -497,6 +497,42 @@ def test_span_trace_journals_the_same_spans(tiny_config, tmp_path):
                 and l["kind"] == "event"]  # the old instant mark is gone
 
 
+@pytest.mark.parametrize("batch_size,unrolled", [(64, 2), (32, 0)])
+def test_local_steps_unrolled_counter(tiny_config, tmp_path, batch_size,
+                                      unrolled):
+    """The recorder says whether the round program holds the local steps
+    unrolled (parallel/engine.UNROLL_MAX_LOCAL_STEPS): 128-sample shards
+    at batch 64 are 2 steps, unrolled; at batch 32 they are 4 and stay a
+    loop (0). The count is in ``counters()``, in the journal as a
+    ``counter`` event, and on the report's host line."""
+    import importlib.util
+
+    from distributed_learning_simulator_tpu.telemetry import spans
+
+    cfg = dataclasses.replace(
+        tiny_config, round=1, batch_size=batch_size,
+        telemetry_level="basic", span_trace="on",
+        compilation_cache_dir=None, log_root=str(tmp_path / "log"),
+    )
+    result, _records, _artifacts = _run_with_artifacts(cfg)
+    assert spans.last_run().counters()["local_steps_unrolled"] == unrolled
+    path = result["span_summary"]["journal_path"]
+    events = [json.loads(line) for line in open(path)]
+    events = [e for e in events if e.get("cat") == "counter"]
+    assert [(e["kind"], e["name"], e["attrs"]["value"]) for e in events] == [
+        ("event", "local_steps_unrolled", unrolled)
+    ]
+    spec = importlib.util.spec_from_file_location(
+        "trace_timeline",
+        os.path.join(os.path.dirname(__file__), "..", "scripts",
+                     "trace_timeline.py"),
+    )
+    tt = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tt)
+    rendered = tt.render_text(tt.summarize([tt.load_journal(path)]))
+    assert f"local_steps_unrolled: {unrolled}" in rendered
+
+
 def test_simulator_telemetry_off_keeps_v1_records(tiny_config, tmp_path):
     """telemetry_level='off' (the default) emits the legacy v1 record —
     exactly the pre-telemetry key set, no schema_version, no telemetry
