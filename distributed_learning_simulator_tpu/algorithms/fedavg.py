@@ -314,9 +314,7 @@ class FedAvg(Algorithm):
         # per-cohort score vector derived from the stats matrix the round
         # already computes.
         cv = ClientValuation.from_config(cfg)
-        local_train = make_local_train_fn(
-            apply_fn,
-            optimizer,
+        train_args = dict(
             local_epochs=cfg.epoch,
             batch_size=cfg.batch_size,
             param_transform=self.client_param_transform(),
@@ -326,6 +324,7 @@ class FedAvg(Algorithm):
             compute_dtype=compute_dtype,
             collect_stats=cs is not None,
         )
+        local_train = make_local_train_fn(apply_fn, optimizer, **train_args)
         vtrain = jax.vmap(local_train, in_axes=(None, 0, 0, 0, 0, 0, None))
         # keep_client_params (class OR instance level) = the documented
         # contract: post_round receives the payload-processed stack as
@@ -362,6 +361,26 @@ class FedAvg(Algorithm):
         arrival_speeds = (
             af.speed_table(n_clients) if af is not None else None
         )
+
+        # One client at a time (--client_chunk_size 1 on one device): the
+        # cohort is a scan over single clients, each trained by
+        # local_train's own body with no batch axis (a model's lax.cond
+        # stays a branch, no copy is stacked), its steps adding their
+        # updates into the round's f32 aggregate as they are computed
+        # (parallel/engine.make_local_train_fn, accumulate_updates). How a
+        # model of hundreds of millions of parameters trains. What needs a
+        # client's parameters whole (payload transforms, corrupted or late
+        # uploads, per-client stats) keeps the batched path.
+        add_client_updates = None
+        if (
+            chunk == 1 and shards == 1 and not materialize
+            and fm is None and af is None and cs is None
+            and type(self).process_client_payload
+            is FedAvg.process_client_payload
+        ):
+            add_client_updates = make_local_train_fn(
+                apply_fn, optimizer, accumulate_updates=True, **train_args
+            )
 
         # --- size-aware work scheduling (config.bucket_client_work) --------
         # The packed-shard discipline makes every client scan
@@ -518,6 +537,24 @@ class FedAvg(Algorithm):
             (aggregate[, late_sum], new_state, train_metrics)."""
             k = keys.shape[0]
 
+            if add_client_updates is not None:
+                def one_client(update_sum, client):
+                    s, xi, yi, mi, key_i, w = client
+                    update_sum, ns, tm = add_client_updates(
+                        update_sum, w, global_params, s, xi, yi, mi, key_i,
+                        lr_scale,
+                    )
+                    return update_sum, (ns, tm)
+
+                update_sum, (ns, tm) = jax.lax.scan(
+                    one_client, zero_acc(global_params),
+                    (state, x, y, m, keys, norm_w),
+                )
+                # The weights sum to 1: the weighted mean of the clients'
+                # parameters is the global model plus this.
+                return jax.tree_util.tree_map(
+                    jnp.add, global_params, update_sum
+                ), ns, tm
             if chunk is None or chunk >= k:
                 cp, ns, tm = train_clients(
                     global_params, state, x, y, m, keys, lr_scale
@@ -951,6 +988,14 @@ class FedAvg(Algorithm):
                 **payload_aux,
                 **agg_aux,
             })
+            if "model_counts" in train_metrics:
+                # A model's own counters (an expert layer's routing
+                # counts), summed over the cohort: they ride the round's
+                # one metric fetch into the span recorder's counters.
+                aux["model_counts"] = jax.tree_util.tree_map(
+                    lambda c: jnp.sum(c, axis=0),
+                    train_metrics["model_counts"],
+                )
             return new_global, new_state_k, aux
 
         def round_fn(global_params, client_state, cx, cy, cmask, sizes, key,

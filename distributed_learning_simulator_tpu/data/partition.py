@@ -159,6 +159,9 @@ def pack_client_shards(
     carry mask 0 and contribute nothing to the loss). ``compact`` stores
     uint8-flattened samples (see :class:`ClientData`).
     """
+    if compact and np.issubdtype(x.dtype, np.integer):
+        # Token ids, not pixels: stored as they are.
+        compact = False
     if compact:
         ok, xmin, xmax = _unit_range(x)
         if not ok:
@@ -184,7 +187,8 @@ def pack_client_shards(
         cx = np.zeros((n_clients, size, dim), dtype=np.uint8)
     else:
         cx = np.zeros((n_clients, size) + sample_shape, dtype=x.dtype)
-    cy = np.zeros((n_clients, size), dtype=np.int32)
+    # One label a sample, or one target a position (``y`` ``[n, T]``).
+    cy = np.zeros((n_clients, size) + np.shape(y)[1:], dtype=np.int32)
     mask = np.zeros((n_clients, size), dtype=np.float32)
     for i, ix in enumerate(indices):
         n = min(len(ix), size)

@@ -18,6 +18,7 @@ from distributed_learning_simulator_tpu.models.cnn import (
 )
 from distributed_learning_simulator_tpu.models.lenet import LeNet5
 from distributed_learning_simulator_tpu.models.resnet import ResNet18, ResNet34
+from distributed_learning_simulator_tpu.models.solar_open2 import solar_open2
 
 _MODELS = {
     "lenet5": LeNet5,
@@ -28,6 +29,9 @@ _MODELS = {
     "resnet18": ResNet18,
     "resnet34": ResNet34,
     "mlp": MLP,
+    # Token sequences; ``num_classes`` is the vocabulary held, and
+    # ``--model_args`` the share (heads_held, experts_held, vocab_rows).
+    "solaropen2": solar_open2,
 }
 
 
@@ -47,7 +51,16 @@ def get_model(name: str, num_classes: int = 10, **kwargs):
 
 def init_params(model, sample_input, seed: int = 0):
     """Initialize model params from a sample batch (pure-params models only)."""
-    variables = model.init(jax.random.key(seed), jnp.asarray(sample_input))
+    init = model.init
+    sample_input = jnp.asarray(sample_input)
+    if getattr(model, "jit_init", False):
+        # A model too large to run op by op: one program that draws the
+        # weights. The forward pass it traces is dead code to XLA but not
+        # to its compile time, so it is traced over the fewest positions
+        # the model takes (no parameter's shape depends on them).
+        init = jax.jit(init)
+        sample_input = sample_input[:, :model.init_positions]
+    variables = init(jax.random.key(seed), sample_input)
     if set(variables.keys()) != {"params"}:
         raise ValueError(
             "models must be pure functions of params (no mutable collections); "

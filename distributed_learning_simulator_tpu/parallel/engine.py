@@ -49,23 +49,47 @@ def make_optimizer(name: str, learning_rate: float, momentum: float = 0.0,
     return tx
 
 
+def split_model_output(out):
+    """``(logits, counts)``: a model returns its outputs alone or with a
+    dict of counters of the batch (an expert layer's routing counts,
+    models/solar_open2.py); ``counts`` is ``{}`` for a model that has
+    none, which adds nothing to the traced program."""
+    if isinstance(out, tuple):
+        return out
+    return out, {}
+
+
+def target_nll(logits, y, mask):
+    """``(nll, mask)`` of softmax cross-entropy, one value a target:
+    ``y`` ``[B]`` over ``logits`` ``[B, V]``, or one target a position,
+    ``[B, T]`` over ``[B, T, V]``; ``mask`` ``[B]`` marks the real
+    samples and comes back in the targets' shape."""
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+    nll = -jnp.take_along_axis(logp, y[..., None], axis=-1)[..., 0]
+    if y.ndim == 2:
+        mask = jnp.broadcast_to(mask[:, None], y.shape)
+    return nll, mask
+
+
 def make_loss_fn(apply_fn, param_transform: Callable | None = None):
     """Masked softmax cross-entropy + accuracy.
 
     ``param_transform`` hooks QAT: e.g. ``fake_quant_tree`` applied to params
     inside the loss gives straight-through-estimator quantization-aware
     training (replaces reference workers/fed_quant_worker.py:19-20).
+
+    Returns ``(loss, (acc, counts))``, ``counts`` the model's counters of
+    the batch (:func:`split_model_output`).
     """
 
     def loss_fn(params, x, y, mask):
         p = param_transform(params) if param_transform is not None else params
-        logits = apply_fn({"params": p}, x)
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-        nll = -jnp.take_along_axis(logp, y[:, None], axis=1)[:, 0]
+        logits, counts = split_model_output(apply_fn({"params": p}, x))
+        nll, mask = target_nll(logits, y, mask)
         denom = jnp.maximum(jnp.sum(mask), 1.0)
         loss = jnp.sum(nll * mask) / denom
-        acc = jnp.sum((jnp.argmax(logits, axis=1) == y) * mask) / denom
-        return loss, acc
+        acc = jnp.sum((jnp.argmax(logits, axis=-1) == y) * mask) / denom
+        return loss, (acc, counts)
 
     return loss_fn
 
@@ -157,6 +181,7 @@ def make_local_train_fn(
     augment: Callable | None = None,
     compute_dtype=None,
     collect_stats: bool = False,
+    accumulate_updates: bool = False,
 ):
     """Build ``local_train(params, opt_state, xs, ys, mask, key)``.
 
@@ -202,13 +227,29 @@ def make_local_train_fn(
     per-step squared gradient L2 norm) in the metrics dict. A trace-time
     flag: False (the default) compiles the exact pre-feature program and
     consumes no extra RNG either way.
+
+    ``accumulate_updates`` builds, from the same body, ``add_updates(
+    update_sum, weight, params, opt_state, xs, ys, mask, key)`` ->
+    ``(update_sum, opt_state, metrics)``: the client trains as above and
+    every step's optimizer update, times ``weight``, is added into the
+    f32 tree ``update_sum`` as it is computed; no parameters come back
+    (the last store is dead). The round then moves the global model by
+    the weighted mean UPDATE. Where the local state is bf16 that keeps
+    the stochastic rounding of the broadcast copy and of each store out
+    of the global model: summed as parameters, 8 clients' roundings leave
+    about 20 times the norm of a two-round update at 841 M parameters
+    (PERF.md § 6, PR 29); the rounding still decides where each gradient
+    is taken. The engine's path for one client at a time
+    (``algorithms/fedavg.py``), which has no room for a third
+    parameter-sized tensor.
     """
     loss_fn = make_loss_fn(apply_fn, param_transform)
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
     sr_enabled = compute_dtype == jnp.bfloat16
 
-    def local_train(params, opt_state, xs, ys, mask, key, lr_scale=1.0):
+    def run(params, opt_state, xs, ys, mask, key, lr_scale, update_sum,
+            weight):
         sr_state = jnp.uint32(0)
         if sr_enabled:
             # Per-client dither salt from the client's key: independent
@@ -247,11 +288,11 @@ def make_local_train_fn(
 
         def epoch_body(carry, scan_in):
             epoch_key, epoch_idx = scan_in
-            params, opt_state, sr_state = carry
+            params, opt_state, sr_state, update_sum = carry
             perm = jax.random.permutation(epoch_key, shard_size)
 
             def step_body(carry, step):
-                params, opt_state, sr_state = carry
+                params, opt_state, sr_state, update_sum = carry
                 if unroll:
                     # What a loop carry gives for free: the parameters
                     # exist in HBM at the step's entry. Without it XLA
@@ -260,6 +301,12 @@ def make_local_train_fn(
                     # flagship round 1.7 % slower (PERF.md § 6, PR 26).
                     # The optimizer state stays free to fold.
                     params = jax.lax.optimization_barrier(params)
+                    if update_sum is not None:
+                        # Likewise the sum so far: left free, the adds of
+                        # one step wait for the next and keep that step's
+                        # gradients alive beside it (1.4 GB at 841 M
+                        # parameters, by the compiler's count).
+                        update_sum = jax.lax.optimization_barrier(update_sum)
                 idx = jax.lax.dynamic_slice_in_dim(
                     perm, step * batch_size, batch_size
                 )
@@ -275,7 +322,7 @@ def make_local_train_fn(
                         bx, jax.random.fold_in(jax.random.fold_in(
                             aug_key, epoch_idx), step),
                     )
-                (loss, acc), grads = grad_fn(params, bx, by, bm)
+                (loss, (acc, counts)), grads = grad_fn(params, bx, by, bm)
                 updates, opt_state = optimizer.update(grads, opt_state, params)
                 # Round-level lr schedule (config.lr_schedule): the per-round
                 # factor multiplies the final update, which is EXACT for
@@ -289,6 +336,13 @@ def make_local_train_fn(
                     ).astype(u.dtype),
                     updates,
                 )
+                if update_sum is not None:
+                    # The step's own update, before any rounding of the
+                    # stored parameters, goes into the round's aggregate.
+                    update_sum = jax.tree_util.tree_map(
+                        lambda a, u: a + weight * u.astype(jnp.float32),
+                        update_sum, updates,
+                    )
                 if sr_enabled:
                     # f32 update math, stochastically-rounded bf16 storage:
                     # plain bf16 apply_updates swallows updates below the
@@ -311,11 +365,11 @@ def make_local_train_fn(
                         for g in jax.tree_util.tree_leaves(grads)
                     )
                     step_out = (loss, acc, grad_sq)
-                return (params, opt_state, sr_state), step_out
+                carry = (params, opt_state, sr_state, update_sum)
+                return carry, (step_out, counts)
 
-            (params, opt_state, sr_state), step_outs = jax.lax.scan(
-                step_body, (params, opt_state, sr_state),
-                jnp.arange(steps_per_epoch), unroll=unroll,
+            carry, (step_outs, counts) = jax.lax.scan(
+                step_body, carry, jnp.arange(steps_per_epoch), unroll=unroll,
             )
             if collect_stats:
                 losses, accs, grad_sqs = step_outs
@@ -326,12 +380,12 @@ def make_local_train_fn(
             else:
                 losses, accs = step_outs
                 epoch_out = (jnp.mean(losses), jnp.mean(accs))
-            return (params, opt_state, sr_state), epoch_out
+            return carry, (epoch_out, _sum_leading(counts))
 
         epoch_keys = jax.random.split(key, local_epochs)
-        (params, opt_state, sr_state), epoch_outs = (
+        (params, opt_state, sr_state, update_sum), (epoch_outs, counts) = (
             jax.lax.scan(
-                epoch_body, (params, opt_state, sr_state),
+                epoch_body, (params, opt_state, sr_state, update_sum),
                 (epoch_keys, jnp.arange(local_epochs)), unroll=unroll,
             )
         )
@@ -348,9 +402,31 @@ def make_local_train_fn(
         else:
             epoch_losses, epoch_accs = epoch_outs
             metrics = {"loss": epoch_losses[-1], "accuracy": epoch_accs[-1]}
-        return params, (None if reset_optimizer else opt_state), metrics
+        if counts:
+            # A model's counters, summed over the run's batches
+            # (telemetry: an expert layer's routing counts).
+            metrics["model_counts"] = _sum_leading(counts)
+        opt_state = None if reset_optimizer else opt_state
+        return params, opt_state, metrics, update_sum
 
-    return local_train
+    def local_train(params, opt_state, xs, ys, mask, key, lr_scale=1.0):
+        return run(
+            params, opt_state, xs, ys, mask, key, lr_scale, None, None
+        )[:3]
+
+    def add_updates(update_sum, weight, params, opt_state, xs, ys, mask,
+                    key, lr_scale=1.0):
+        _, opt_state, metrics, update_sum = run(
+            params, opt_state, xs, ys, mask, key, lr_scale, update_sum,
+            weight,
+        )
+        return update_sum, opt_state, metrics
+
+    return add_updates if accumulate_updates else local_train
+
+
+def _sum_leading(tree):
+    return jax.tree_util.tree_map(lambda c: jnp.sum(c, axis=0), tree)
 
 
 def chunked_accumulate(trees, chunk: int, compute_fn, acc0, per_chunk=None,
@@ -707,12 +783,12 @@ def pad_eval_set(x, y, batch_size: int, flatten: bool = False):
     n_batches = (n + batch_size - 1) // batch_size
     padded = n_batches * batch_size
     xp = np.zeros((padded,) + x.shape[1:], dtype=x.dtype)
-    yp = np.zeros((padded,), dtype=np.int32)
+    yp = np.zeros((padded,) + np.shape(y)[1:], dtype=np.int32)
     mp = np.zeros((padded,), dtype=np.float32)
     xp[:n], yp[:n], mp[:n] = x, y, 1.0
     return (
         xp.reshape((n_batches, batch_size) + x.shape[1:]),
-        yp.reshape((n_batches, batch_size)),
+        yp.reshape((n_batches, batch_size) + yp.shape[1:]),
         mp.reshape((n_batches, batch_size)),
     )
 
@@ -738,10 +814,9 @@ def make_eval_fn(apply_fn, preprocess: Callable | None = None,
             x, y, m = batch
             if preprocess is not None:
                 x = preprocess(x)
-            logits = apply_fn({"params": params}, x)
-            logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-            nll = -jnp.take_along_axis(logp, y[:, None], axis=1)[:, 0]
-            correct = (jnp.argmax(logits, axis=1) == y).astype(jnp.float32)
+            logits, _ = split_model_output(apply_fn({"params": params}, x))
+            nll, m = target_nll(logits, y, m)
+            correct = (jnp.argmax(logits, axis=-1) == y).astype(jnp.float32)
             loss_sum, correct_sum, count = carry
             return (
                 loss_sum + jnp.sum(nll * m),

@@ -86,6 +86,9 @@ from distributed_learning_simulator_tpu.telemetry import (
     start_run,
     valuation_record,
 )
+from distributed_learning_simulator_tpu.telemetry.client_stats import (
+    expert_load_record,
+)
 from distributed_learning_simulator_tpu.utils.reporting import (
     build_round_record,
 )
@@ -347,6 +350,8 @@ def build_base_round_record(config, round_idx: int, metrics: dict,
         record["survivor_count"] = int(fetched_tel["survivor_count"])
     if "round_rejected" in fetched_tel:
         record["round_rejected"] = bool(fetched_tel["round_rejected"])
+    if "model_counts" in fetched_tel:
+        record["expert_load"] = expert_load_record(fetched_tel["model_counts"])
     if "participants" in fetched_tel:
         # CRC of the sampled cohort: a compact per-round fingerprint
         # that lets the resume-determinism tests assert the cohort
@@ -866,6 +871,10 @@ def run_simulation(
         tracer.set_counter(
             "local_steps_unrolled",
             algorithm.local_steps_unrolled(client_data.shard_size),
+        )
+        tracer.set_counter(
+            "client_axis_width", min(config.client_chunk_size or n_clients,
+                                     config.cohort_size(n_clients)),
         )
 
         # Optional server-side optimizer (FedOpt; exceeds the reference): the
@@ -1668,7 +1677,9 @@ def run_simulation(
             with tracer.span("record", "host", round_idx=round_idx):
                 # Wall time between successive round completions: covers train +
                 # eval + metric fetch + host post_round (Shapley time included —
-                # it IS per-round server work). Sums to total wall time (within
+                # it IS per-round server work). Sums to total wall time, less
+                # the periodic checkpoints of a loop that is not pipelined
+                # (``_finalize`` restarts the clock after one) (within
                 # a batched dispatch the dispatch's wall lands on its first
                 # round; later rounds record only their host-side tail).
                 record = build_base_round_record(
@@ -1853,14 +1864,28 @@ def run_simulation(
             # (maybe_crash, last statement below) fires inside it, so a
             # SIGKILL'd host's journal names this span as its in-flight
             # postmortem without any cleanup code running.
+            nonlocal t_prev_done
             with tracer.span(
                 "finalize", "round", round_idx=p["round_idx"], eager=True,
             ):
-                return _finalize(p)
+                saved = _finalize(p)
+            if saved and not pipelined:
+                # The device idled while the host wrote (nothing is
+                # dispatched behind a finalize that is not deferred):
+                # those seconds are the save's, not the next round's,
+                # whose clock starts here. Gigabytes take seconds.
+                t_prev_done = time.perf_counter()
+            # The loop keeps the entry bound until the next round's: let go
+            # of the two models it names, or the round before last's stays
+            # on the device through the next dispatch (a third f32 copy).
+            p["prev_global"] = p["new_global"] = None
 
-        def _finalize(p: dict) -> None:
+        def _finalize(p: dict) -> bool:
+            """True where the round's periodic checkpoint was written."""
+            saved = False
             tel_keys = [
-                k for k in ("survivor_count", "round_rejected", "participants")
+                k for k in ("survivor_count", "round_rejected", "participants",
+                            "model_counts")
                 if k in p["aux"]
             ]
             # Client-stats fetch cadence (client_stats_every): the [N, S]
@@ -1893,6 +1918,8 @@ def run_simulation(
                       for k in tel_keys + cs_keys + val_keys + async_keys})
                 )
             metrics = {k: float(v) for k, v in fetched_metrics.items()}
+            if "model_counts" in fetched_tel:
+                tracer.add_counts(fetched_tel["model_counts"])
             if p.get("participants_host") is not None and (
                 "participants" in fetched_tel
             ):
@@ -2021,11 +2048,13 @@ def run_simulation(
                         )
                         gc_checkpoints(config.checkpoint_dir,
                                        config.checkpoint_keep_last)
+                saved = True
             # Chaos-harness hook (robustness/chaos.py): inert unless
             # DLS_CRASH_AT_ROUND is set. Placed after the checkpoint block so
             # an injected crash models "the process died right after round N
             # was persisted".
             maybe_crash(p["round_idx"])
+            return saved
 
         # Dispatch sizes already compiled this run (rounds_per_dispatch > 1):
         # a size seen for the first time (remainder/checkpoint-clipped
@@ -2046,7 +2075,8 @@ def run_simulation(
             aux_k = d["aux"]
             tel_keys = [
                 name for name in
-                ("survivor_count", "round_rejected", "participants")
+                ("survivor_count", "round_rejected", "participants",
+                 "model_counts")
                 if name in aux_k
             ]
             # Client-stats cadence at batch granularity: the stacked rows ride
@@ -2079,6 +2109,8 @@ def run_simulation(
                      {name: aux_k[name]
                       for name in tel_keys + cs_keys + val_keys + async_keys})
                 )
+            if "model_counts" in fetched_tel:
+                tracer.add_counts(fetched_tel["model_counts"])
 
             def tel_rec_fn():
                 if not tracer.phases.enabled:

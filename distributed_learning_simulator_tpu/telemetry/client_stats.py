@@ -366,3 +366,27 @@ def attribution_crosscheck(shapley_values: np.ndarray,
     if np.ptp(sv) == 0.0 or np.ptp(improve) == 0.0:
         return None
     return float(np.corrcoef(sv, improve)[0, 1])
+
+
+def expert_load_record(model_counts: dict) -> dict:
+    """The round's routing counters (the round program's ``model_counts``
+    aux, summed over the cohort's local steps; models/solar_open2.py) as a
+    record: per layer the assignments that fell to each expert held here,
+    their share of all the round's assignments' tokens, the fullest held
+    expert over the mean (1.0 = even), and the steps in which a held
+    expert outgrew its slots and the layer fell back to every token."""
+    load = np.asarray(model_counts["moe_expert_load"], dtype=np.int64)
+    load = load.reshape((-1,) + load.shape[-2:]).sum(axis=0)
+    tokens = np.asarray(model_counts["moe_routed_tokens"]).reshape(
+        -1, load.shape[0]).sum(axis=0)
+    mean = np.maximum(load.mean(axis=1), 1e-9)
+    return {
+        "load": load.tolist(),
+        "assignments_per_token": [
+            round(float(a), 6) for a in load.sum(axis=1) / np.maximum(tokens, 1)
+        ],
+        "max_over_mean": [round(float(r), 4) for r in load.max(axis=1) / mean],
+        "overflow_steps": np.asarray(
+            model_counts["moe_overflows"]
+        ).reshape(-1, load.shape[0]).sum(axis=0).tolist(),
+    }

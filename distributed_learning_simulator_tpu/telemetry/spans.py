@@ -97,6 +97,7 @@ import os
 import threading
 
 import jax
+import numpy as np
 
 from distributed_learning_simulator_tpu.telemetry import clock
 from distributed_learning_simulator_tpu.telemetry.phases import (
@@ -108,6 +109,12 @@ from distributed_learning_simulator_tpu.telemetry.recompile import (
     RecompileMonitor,
 )
 
+#: The round program's ``model_counts`` aux -> the counters they add to.
+_COUNT_NAMES = {
+    "moe_local_assignments": "local_expert_assignments",
+    "moe_routed_tokens": "routed_tokens",
+    "moe_overflows": "moe_overflows",
+}
 JOURNAL_VERSION = 1
 
 #: Journal filename for a host, next to metrics.jsonl in the artifacts
@@ -457,6 +464,20 @@ class SpanRecorder(_Sections):
             self._build_counts[name] = int(value)
             self._append_locked(ev)
 
+    def add_counts(self, counts: dict) -> None:
+        """Add a round's fetched counts to the running counters of
+        :meth:`counters` (the round program's ``model_counts`` aux: an
+        expert layer's ``moe_local_assignments``, ``moe_routed_tokens``,
+        ``moe_overflows`` become ``local_expert_assignments``,
+        ``routed_tokens``, ``moe_overflows``; arrays are summed)."""
+        with self._lock:
+            for name, value in counts.items():
+                name = _COUNT_NAMES.get(name)
+                if name is not None:
+                    self._build_counts[name] = self._build_counts.get(
+                        name, 0
+                    ) + int(np.sum(value))
+
     def _on_duration(self, counter: str, t_end: float,
                      seconds: float) -> None:
         """The monitor's ``on_event``: one tracing, lowering or backend
@@ -786,6 +807,9 @@ class NullTracer(_Sections):
         return None
 
     def set_counter(self, name: str, value: int) -> None:
+        return None
+
+    def add_counts(self, counts: dict) -> None:
         return None
 
 

@@ -52,14 +52,34 @@ def _write_framed(path: str, payload: dict) -> str:
     """CRC-framed atomic write — the one copy of the DLSC on-disk
     format, shared by whole checkpoints and per-host shards."""
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    blob = pickle.dumps(payload, protocol=pickle.HIGHEST_PROTOCOL)
     tmp = path + ".tmp"
     with open(tmp, "wb") as f:
         f.write(_MAGIC)
-        f.write(_HEADER.pack(zlib.crc32(blob), len(blob)))
-        f.write(blob)
+        f.write(_HEADER.pack(0, 0))
+        # Pickled straight into the file, the CRC kept as the bytes go
+        # by: a model of gigabytes is never held a second time as one
+        # blob, and the header is filled in once its numbers are known.
+        body = _CrcWriter(f)
+        pickle.dump(payload, body, protocol=pickle.HIGHEST_PROTOCOL)
+        f.seek(len(_MAGIC))
+        f.write(_HEADER.pack(body.crc, body.length))
     os.replace(tmp, path)  # atomic: never leaves a torn checkpoint
     return path
+
+
+class _CrcWriter:
+    """The ``write`` a pickler needs, over an open file: passes the bytes
+    on and keeps their running CRC-32 and count."""
+
+    def __init__(self, f):
+        self._f = f
+        self.crc = 0
+        self.length = 0
+
+    def write(self, data) -> int:
+        self.crc = zlib.crc32(data, self.crc)
+        self.length += memoryview(data).nbytes
+        return self._f.write(data)
 
 
 def save_checkpoint(path: str, round_idx: int, global_params, client_state,
@@ -87,7 +107,8 @@ def load_checkpoint(path: str) -> dict:
                 f"({len(raw)} bytes)"
             )
         crc, length = _HEADER.unpack(raw[len(_MAGIC):header_end])
-        blob = raw[header_end:]
+        # A view, not a copy: a checkpoint may be gigabytes.
+        blob = memoryview(raw)[header_end:]
         if len(blob) != length:
             raise CheckpointCorruptError(
                 f"{path}: payload truncated ({len(blob)} of {length} bytes)"

@@ -151,6 +151,36 @@ def test_checkpoint_keep_last_retention_end_to_end(tiny_config, tmp_path):
     assert [h["round"] for h in resumed["history"]] == [4, 5]
 
 
+def test_a_save_is_not_the_next_rounds_time(tiny_config, tmp_path,
+                                            monkeypatch):
+    """Where the loop is not pipelined the device idles while a periodic
+    checkpoint is written: the next round's ``round_seconds`` starts when
+    the save ends (a pipelined loop writes while the next round runs, and
+    its clock is left alone)."""
+    import time
+
+    from distributed_learning_simulator_tpu import simulator
+
+    def slow_save(*args, **kwargs):
+        time.sleep(0.5)
+        return save_checkpoint(*args, **kwargs)
+
+    monkeypatch.setattr(simulator, "save_checkpoint", slow_save)
+    seconds = {}
+    for pipelined in (False, True):
+        result = run_simulation(
+            dataclasses.replace(
+                tiny_config, round=4, pipeline_rounds=pipelined,
+                checkpoint_dir=str(tmp_path / f"ck_{pipelined}"),
+                checkpoint_every=2),
+            setup_logging=False,
+        )
+        seconds[pipelined] = [h["round_seconds"] for h in result["history"]]
+    # Round 1 saves; round 2 is the round after.
+    assert seconds[False][2] < 0.4
+    assert max(seconds[True][2:]) > 0.45
+
+
 def test_server_opt_resume_matches_straight_run(tiny_config, tmp_path):
     """FedAvgM momentum state survives checkpoint/resume bit-exactly."""
     fedavgm = dict(server_optimizer_name="sgd", server_learning_rate=1.0,

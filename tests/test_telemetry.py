@@ -520,7 +520,8 @@ def test_local_steps_unrolled_counter(tiny_config, tmp_path, batch_size,
     events = [json.loads(line) for line in open(path)]
     events = [e for e in events if e.get("cat") == "counter"]
     assert [(e["kind"], e["name"], e["attrs"]["value"]) for e in events] == [
-        ("event", "local_steps_unrolled", unrolled)
+        ("event", "local_steps_unrolled", unrolled),
+        ("event", "client_axis_width", 4),  # the 4 clients in one chunk
     ]
     spec = importlib.util.spec_from_file_location(
         "trace_timeline",
