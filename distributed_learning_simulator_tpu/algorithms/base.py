@@ -60,6 +60,15 @@ class Algorithm:
     # Shapley algorithms set False — their post_round drives data-dependent
     # subset evaluation that must see the round's metrics synchronously.
     supports_round_pipelining: bool = True
+    # Whether the host loop may donate the global model to the round
+    # program, which then writes the new global into the old one's buffer
+    # (one f32 copy of the model fewer on the device). The loop does so
+    # only where it is not pipelined and nothing else it runs takes the
+    # previous global; then ``RoundContext.prev_global_params`` is None.
+    # True says post_round does not read it. Conservative default False;
+    # FedAvg opts in, the Shapley servers (subset means are taken around
+    # the previous global) opt out again.
+    supports_global_donation: bool = False
     # Whether round_fn accepts the optional trailing ``lr_scale`` operand
     # (config.lr_schedule): the simulator passes it only when a schedule
     # is active AND the algorithm declares support — an algorithm without

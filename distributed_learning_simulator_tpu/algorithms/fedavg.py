@@ -109,6 +109,9 @@ class FedAvg(Algorithm):
     # scatter drops out, and the shared cohort_round body keeps the two
     # programs bit-identical. fed_quant inherits.
     supports_streamed_residency = True
+    # post_round (client_eval) reads the round's raw client stack and the
+    # test batches, never the global the round started from.
+    supports_global_donation = True
 
     def __init__(self, config):
         super().__init__(config)

@@ -869,6 +869,8 @@ class MultiRoundShapley(FedAvg):
     # K-stacked aux['client_params']; SV attribution needs each round's
     # stack + metrics synchronously (same reason pipelining is off).
     supports_round_batching = False
+    # post_round takes every subset's mean around ctx.prev_global_params.
+    supports_global_donation = False
     # Streamed residency (config.client_residency='streamed'): subset
     # re-evaluation consumes the RESIDENT aux['client_params'] stack —
     # overrides the FedAvg-family opt-in; the simulator refuses with
@@ -991,6 +993,7 @@ class GTGShapley(FedAvg):
     keep_client_params = True
     supports_round_pipelining = False  # post_round consumes round metrics
     supports_round_batching = False  # same: per-round stacks + metrics
+    supports_global_donation = False  # the walk reads ctx.prev_global_params
     # Same as MultiRoundShapley: the permutation walk's subset utilities
     # assume a resident per-client stack; streamed residency is refused.
     supports_streamed_residency = False

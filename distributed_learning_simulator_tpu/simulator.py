@@ -836,12 +836,6 @@ def run_simulation(
         preprocess = (
             make_decoder(client_data.sample_shape) if client_data.compact else None
         )
-        # Static per-client sample counts feed the size-aware work scheduler
-        # (FedAvg fused path); withheld under mesh/multihost sharding, where the
-        # client axis layout is owned by the PartitionSpec.
-        _sharded = config.multihost or (
-            config.mesh_devices is not None and config.mesh_devices > 1
-        )
         # Count-dependent feasibility (exact Shapley's 2^N bound, GTG's
         # permutation cap) against the TRUE client count, for every algorithm
         # regardless of its make_round_fn inheritance (the threaded runner
@@ -849,7 +843,14 @@ def run_simulation(
         algorithm.check_cohort(n_clients)
         round_fn = algorithm.make_round_fn(
             model.apply, optimizer, n_clients, preprocess=preprocess,
-            client_sizes=None if _sharded else client_data.sizes,
+            # Static per-client sample counts feed the size-aware work
+            # scheduler (FedAvg fused path); withheld under mesh/multihost
+            # sharding, where the client axis layout is owned by the
+            # PartitionSpec.
+            client_sizes=(
+                None if config.multihost or (config.mesh_devices or 0) > 1
+                else client_data.sizes
+            ),
         )
         if stream_full:
             # Full-cohort streamed convention differs from the resident one
@@ -867,7 +868,6 @@ def run_simulation(
                     key, lr_scale, **kw,
                 )
 
-        round_jit = jax.jit(round_fn, donate_argnums=(1,))
         tracer.set_counter(
             "local_steps_unrolled",
             algorithm.local_steps_unrolled(client_data.shard_size),
@@ -1014,9 +1014,12 @@ def run_simulation(
                         "model version — resume with the configuration it was "
                         "written with"
                     )
-                global_params = jax.tree_util.tree_map(
-                    jnp.asarray, ckpt["global_params"]
-                )
+                # init_params' tree goes before the checkpoint's is placed
+                # (two f32 copies of the model live at once are more than
+                # any round holds); owned buffers, since the loop may
+                # donate the global model.
+                global_params = None
+                global_params = _owned_device_tree(ckpt["global_params"])
                 want_cs = jax.tree_util.tree_structure(client_state)
                 got_cs = jax.tree_util.tree_structure(ckpt["client_state"])
                 if want_cs != got_cs:
@@ -1532,6 +1535,26 @@ def run_simulation(
                     make_eval_fn(model.apply, preprocess=eval_preprocess),
                     client_data, eval_batches, n_clients,
                 )
+        # Where nothing reads the model a round started from once the round
+        # is dispatched, the global model is donated with the client state:
+        # the program writes the new global into the old one's buffer, and
+        # two f32 copies of the model are alive on the device for three. A
+        # pipelined loop keeps round r's global for its deferred evaluation
+        # and finalize, a batched dispatch has a jit of its own, and the
+        # Shapley servers' post_round, the valuation auditor and the server
+        # optimizer each take the previous global: those keep (1,).
+        donate_global = (
+            not pipelined and not batched and auditor is None
+            and server_update_jit is None
+            and algorithm.supports_global_donation
+        )
+        if donate_global and start_round == 0:
+            # init_params' tree (a resumed one is owned already).
+            global_params = _owned_device_tree(global_params)
+        round_jit = jax.jit(
+            round_fn, donate_argnums=(0, 1) if donate_global else (1,)
+        )
+        tracer.set_counter("global_donated", int(donate_global))
         # Predictive cost model (telemetry/costmodel.py): parse the reference
         # trace ONCE at startup (pure host-side gzip read); the roofline
         # prediction attaches to the run's LAST metrics record (schema v6)
@@ -2761,7 +2784,7 @@ def run_simulation(
                                 "eval_dispatch", "phase", round_idx=round_idx,
                                 phase="eval",
                             ) as _ph, _oom_hint(
-                                config, global_params, n_clients, site="eval"
+                                config, new_global, n_clients, site="eval"
                             ):
                                 metrics_dev = evaluate(new_global, *eval_batches)
                                 _ph.fence(metrics_dev)
@@ -2775,7 +2798,11 @@ def run_simulation(
                                 "round_idx": round_idx,
                                 "round_key": round_key,
                                 "new_global": new_global,
-                                "prev_global": global_params,
+                                # A donated global is gone: its buffer
+                                # holds new_global.
+                                "prev_global": (
+                                    None if donate_global else global_params
+                                ),
                                 # Sampled streamed: the (post-writeback) host
                                 # store is what a checkpoint must persist.
                                 "client_state": (

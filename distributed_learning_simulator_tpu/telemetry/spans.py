@@ -626,7 +626,8 @@ class SpanRecorder(_Sections):
         after]`` the first round completed (unions of the events'
         intervals), ``host_syncs`` (completed ``host_sync`` spans),
         ``rounds`` (completed rounds) and what :meth:`set_counter` was
-        given (``local_steps_unrolled``)."""
+        given (``local_steps_unrolled``, ``client_axis_width``,
+        ``global_donated``)."""
         events = self.duration_events()
         with self._lock:
             cut = self._first_round_t

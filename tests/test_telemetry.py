@@ -522,6 +522,7 @@ def test_local_steps_unrolled_counter(tiny_config, tmp_path, batch_size,
     assert [(e["kind"], e["name"], e["attrs"]["value"]) for e in events] == [
         ("event", "local_steps_unrolled", unrolled),
         ("event", "client_axis_width", 4),  # the 4 clients in one chunk
+        ("event", "global_donated", 0),  # a pipelined loop keeps the global
     ]
     spec = importlib.util.spec_from_file_location(
         "trace_timeline",
