@@ -63,11 +63,6 @@ runs ONE spans-on 2-process pair at its largest population (the timed
 sweep stays span-off) and records ``barrier_skew_ms`` — the worst
 spill-exchange arrival skew either host saw — plus per-host DCN
 wait/transfer splits; BENCH_MHOST_SPANS=0 skips.
-The ``round_batch`` sub-object sweeps
-``rounds_per_dispatch`` K in {1, BENCH_ROUND_BATCH_K} on the headline
-program and records the wall-based K-vs-1 ``amortization_ratio``
-(docs/PERFORMANCE.md § Round batching) — compare_bench.py gates it
-absolutely (--batch-amortization-threshold); BENCH_ROUND_BATCH=0 skips.
 The ``async`` sub-object runs the headline program under the 80/20
 fast/slow arrival population (async_mode='on', docs/ROBUSTNESS.md §
 Asynchronous federation) and records the simulated-clock
@@ -115,7 +110,7 @@ heterogeneous scheduler's ``compile_reuse_fraction`` on a 2-hash
 8-point sweep; BENCH_SWEEP=0 skips, BENCH_SWEEP_POINTS/_ROUNDS/_CLIENTS
 set the shape. Lean-compatible legs route through ONE
 sweep.SweepScheduler (``warm_programs`` in the record), so same-program
-legs (headline / round_batch K=1) pay trace+compile once.
+legs pay trace+compile once.
 """
 
 from __future__ import annotations
@@ -128,12 +123,11 @@ import time
 
 # Warm-program scheduler shared by every lean-compatible leg (ISSUE 11
 # small fix): bench used to re-pay trace+compile for every leg even when
-# two legs ran the SAME program (the headline and the round_batch K=1
-# leg differ only in round count — identical config_hash). Routing
+# two legs ran the SAME program (identical config_hash). Routing
 # repeated same-program runs through one sweep.SweepScheduler pays the
 # warmup once and records the reuse explicitly (``warm_programs`` in
 # the bench JSON). Legs outside the lean envelope (telemetry, async,
-# streamed, Shapley, K>1, profiling) fall back to run_simulation inside
+# streamed, Shapley, profiling) fall back to run_simulation inside
 # the scheduler — recorded as fallback_points, never silent.
 _SCHEDULER = None
 
@@ -1018,56 +1012,6 @@ def main() -> int:
         }
         shutil.rmtree(sp_dir, ignore_errors=True)
 
-    # Round batching (ISSUE 5, config.rounds_per_dispatch): the SAME
-    # headline program dispatched K rounds at a time, so the
-    # amortization_ratio is an apples-to-apples K-vs-1 rate ratio measured
-    # in one bench run on one machine. Rates are WALL-based over the
-    # steady rounds (clients * rounds / elapsed): within a dispatch the
-    # per-round wall lands on the dispatch's first record, so the K=1
-    # median would be meaningless against K>1 — the elapsed-time rate is
-    # the honest common unit. The first K rounds are dropped on both legs
-    # (the first dispatch carries the scan program's compile). Gated by
-    # scripts/compare_bench.py --batch-amortization-threshold as an
-    # in-record ABSOLUTE floor, same pattern as the client_stats overhead
-    # gate. rounds_per_dispatch lands in config_hash like every other
-    # program-defining knob, so K-batched and unbatched headline runs
-    # can never be silently diffed. BENCH_ROUND_BATCH=0 skips;
-    # BENCH_ROUND_BATCH_K / BENCH_ROUND_BATCH_ROUNDS set the sweep.
-    run_rbatch = (
-        os.environ.get("BENCH_ROUND_BATCH", "1") != "0"
-        and model == "cnn_tpu"
-        and n_clients == 1000
-    )
-    if run_rbatch:
-        rb_k = int(os.environ.get("BENCH_ROUND_BATCH_K", "8"))
-        rb_rounds = int(os.environ.get("BENCH_ROUND_BATCH_ROUNDS", "16"))
-        # Round UP to a multiple of K: a trailing remainder dispatch is a
-        # different scan program whose compile would land inside the
-        # measured window and deflate the ratio with pure compile time.
-        rb_rounds = -(-rb_rounds // rb_k) * rb_k
-        rb_rates = {}
-        for k_ in (1, rb_k):
-            rb_config = ExperimentConfig(
-                model_name=model, round=rb_rounds + k_,
-                client_chunk_size=chunk, local_compute_dtype=dtype,
-                rounds_per_dispatch=k_,
-                **failure_knobs, **common,
-            )
-            rb_times, _ = _run(
-                rb_config, dataset=dataset, client_data=client_data
-            )
-            steady = rb_times[k_:]
-            rb_rates[k_] = n_clients * len(steady) / sum(steady)
-        record["round_batch"] = {
-            "k": rb_k,
-            "rounds": rb_rounds,
-            "k1_rate": round(rb_rates[1], 2),
-            "k_rate": round(rb_rates[rb_k], 2),
-            # >= 1.0 means batching pays: K rounds per dispatch move at
-            # least as fast as one-round dispatches.
-            "amortization_ratio": round(rb_rates[rb_k] / rb_rates[1], 4),
-        }
-
     # Asynchronous federation (ISSUE 6, config.async_mode): the headline
     # program under the documented 80/20 fast/slow population with
     # deadline rounds + the staleness buffer (docs/ROBUSTNESS.md §
@@ -1076,8 +1020,8 @@ def main() -> int:
     # counterfactual, computed from the SAME arrival draws — a
     # deterministic program property, not wall-clock), gated by
     # scripts/compare_bench.py --async-speedup-threshold as an in-record
-    # ABSOLUTE floor, same pattern as the round_batch gate. The async
-    # knobs land in config_hash like every other program-defining field,
+    # ABSOLUTE floor, same pattern as the client_stats overhead gate. The
+    # async knobs land in config_hash like every other program-defining field,
     # so async and sync headline runs can never be silently diffed.
     # BENCH_ASYNC=0 skips; BENCH_ASYNC_ROUNDS sets the length.
     run_async = (
@@ -1284,8 +1228,8 @@ def main() -> int:
     # seconds — how much of the host->HBM upload the double-buffered
     # prefetch hid behind compute). compare_bench.py gates the LARGEST
     # N's overlap ratio absolutely (--stream-overlap-threshold), the
-    # same in-record pattern as the round_batch/async gates: the ratio
-    # sits near a fixed operating point, where a relative gate would
+    # same in-record pattern as the async gate: the ratio sits near a
+    # fixed operating point, where a relative gate would
     # flap. The residency/sampling knobs are program-defining config
     # fields, so they land in each entry's config_hash automatically.
     # BENCH_STREAM=0 skips; BENCH_STREAM_SWEEP (comma-separated N list),
@@ -1488,8 +1432,8 @@ def main() -> int:
 
     # Warm-program accounting for the legs that ran through the shared
     # scheduler (see _run): programs_compiled < points means at least
-    # one leg rode another leg's warm program (the headline's serves
-    # the round_batch K=1 leg — same config_hash, different horizon).
+    # one leg rode another leg's warm program (same config_hash,
+    # different horizon).
     if _SCHEDULER is not None:
         record["warm_programs"] = {
             "points": _SCHEDULER.points_run,

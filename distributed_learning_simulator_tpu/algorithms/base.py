@@ -74,17 +74,6 @@ class Algorithm:
     # is active AND the algorithm declares support — an algorithm without
     # the operand still works with the constant default.
     supports_lr_schedule: bool = False
-    # Whether the host loop may fuse K rounds into one dispatched program
-    # (config.rounds_per_dispatch > 1; parallel/engine.py
-    # make_batched_round_fn). The batched dispatch scan-stacks every aux
-    # leaf ``[K, ...]`` and hands post_round dispatch-granular params
-    # (RoundContext.global_params is the dispatch-FINAL model,
-    # prev_global_params the dispatch-initial one), so algorithms whose
-    # aux carries per-round parameter stacks or whose post_round consumes
-    # per-round parameter state must say False. Conservative default
-    # False — a third-party post_round reading ctx.global_params would
-    # silently get wrong values; FedAvg/SignSGD opt in.
-    supports_round_batching: bool = False
     # Whether the algorithm's round program can run under
     # ``config.client_residency='streamed'`` (data/residency.py +
     # parallel/streaming.py): per-client arrays live in a host shard

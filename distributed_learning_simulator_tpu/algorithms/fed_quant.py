@@ -55,12 +55,7 @@ class FedQuant(FedAvg):
 
     # Per-client eval telemetry (reference fed_quant_worker.py:55-69) is
     # FedAvg-family machinery now — FedAvg.__init__ auto-enables it for
-    # this algorithm at reference-like cohort sizes. Round batching
-    # (config.rounds_per_dispatch) rides FedAvg.supports_round_batching:
-    # available whenever client_eval is off, so batching fed_quant at
-    # reference-like cohorts (<= 32, where client_eval auto-enables)
-    # needs an explicit client_eval=False. The quant_mse round scalar
-    # scan-stacks like any other aux leaf.
+    # this algorithm at reference-like cohort sizes.
 
     @property
     def levels(self) -> int:

@@ -158,16 +158,6 @@ class FedAvg(Algorithm):
             or self.config.aggregation.lower() != "mean"
         )
 
-    @property
-    def supports_round_batching(self) -> bool:
-        # Round batching (config.rounds_per_dispatch) scan-stacks every
-        # aux output over K rounds: keep_client_params would materialize
-        # K cohort-sized parameter stacks, and client_eval's post_round
-        # must evaluate each round's raw stack — per-round data a
-        # batched dispatch cannot provide. Robust aggregation rules are
-        # fine: their stack is transient inside each scan iteration.
-        return not (self.keep_client_params or self._client_eval_enabled)
-
     # jax-level template hooks, parity with fed_server.py:38-42 -------------
     def process_client_payload(self, client_params, key):
         """Per-client payload transform before aggregation (identity here;
@@ -373,7 +363,7 @@ class FedAvg(Algorithm):
         # (parallel/engine.make_local_train_fn, accumulate_updates). How a
         # model of hundreds of millions of parameters trains. What needs a
         # client's parameters whole (payload transforms, corrupted or late
-        # uploads, per-client stats) keeps the batched path.
+        # uploads, per-client stats) keeps the stacked path.
         add_client_updates = None
         if (
             chunk == 1 and shards == 1 and not materialize
@@ -970,9 +960,8 @@ class FedAvg(Algorithm):
                 else:
                     new_async_state = astate_next
                     applied_eff = buffer_applied
-                # The buffer carry rides aux: the host loop (and the
-                # batched scan) pops it and feeds it back as the next
-                # round's async_state operand.
+                # The buffer carry rides aux: the host loop pops it and
+                # feeds it back as the next round's async_state operand.
                 aux["async_state"] = new_async_state
                 aux.update({
                     "on_time_count": on_time_count,

@@ -865,10 +865,6 @@ class MultiRoundShapley(FedAvg):
     name = "multiround_shapley_value"
     keep_client_params = True
     supports_round_pipelining = False  # post_round consumes round metrics
-    # Round batching would hand post_round dispatch-final params and a
-    # K-stacked aux['client_params']; SV attribution needs each round's
-    # stack + metrics synchronously (same reason pipelining is off).
-    supports_round_batching = False
     # post_round takes every subset's mean around ctx.prev_global_params.
     supports_global_donation = False
     # Streamed residency (config.client_residency='streamed'): subset
@@ -992,7 +988,6 @@ class GTGShapley(FedAvg):
     name = "GTG_shapley_value"
     keep_client_params = True
     supports_round_pipelining = False  # post_round consumes round metrics
-    supports_round_batching = False  # same: per-round stacks + metrics
     supports_global_donation = False  # the walk reads ctx.prev_global_params
     # Same as MultiRoundShapley: the permutation walk's subset utilities
     # assume a resident per-client stack; streamed residency is refused.

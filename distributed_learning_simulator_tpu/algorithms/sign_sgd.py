@@ -53,11 +53,6 @@ from distributed_learning_simulator_tpu.telemetry.client_stats import (
 
 class SignSGD(Algorithm):
     name = "sign_SGD"
-    # Round batching (config.rounds_per_dispatch): the round keeps ONE
-    # shared params tree and scalar aux, and post_round's payload-byte
-    # accounting is a pure shape function — nothing needs per-round
-    # parameter state, so K rounds scan cleanly into one dispatch.
-    supports_round_batching = True
     # Streamed residency (config.client_residency='streamed'): the
     # per-step vote synchronizes EVERY client (the constructor rejects
     # participation_fraction < 1), so the "cohort" is always the whole

@@ -141,7 +141,7 @@ class ExperimentConfig:
     # composes with faults/quorum (a round whose survivors fall below
     # min_survivors after mid-round departures is rejected in-program,
     # previous global retained) and single-host mesh; refuses async
-    # mode, round batching, valuation audits, the threaded oracle, and
+    # mode, valuation audits, the threaded oracle, and
     # the vmapped sweep strategy — each with the blocking cause named
     # (docs/ROBUSTNESS.md § Dynamic populations).
     population: str = "static"
@@ -419,24 +419,6 @@ class ExperimentConfig:
     # disabled for algorithms whose post_round needs same-round metrics
     # (Shapley) and when per-client state must be checkpointed.
     pipeline_rounds: bool = True
-    # Fuse this many federated rounds — train + server-optimizer step +
-    # server eval + the per-round RNG split chain — into ONE jitted
-    # dispatch (parallel/engine.py make_batched_round_fn), with per-round
-    # metrics stacked on device and fetched in a single transfer per
-    # dispatch. Amortizes the per-round host dispatch/eval-launch/sync
-    # overhead the Python round loop cannot hide (~28% of the headline
-    # round; docs/PERFORMANCE.md § Round batching). 1 (default) keeps the
-    # exact pre-feature per-round dispatch path — trace-time gated like
-    # failure_mode/client_stats — and K>1 history is bit-identical to
-    # K=1 (the in-program RNG chain replays the host loop's split
-    # sequence). Dispatch size is clipped to the next checkpoint
-    # boundary, so checkpoint_every and SIGTERM finish-in-flight
-    # semantics keep working at batch granularity. Algorithms opt in via
-    # Algorithm.supports_round_batching (FedAvg family incl. fed_quant,
-    # sign_SGD; the Shapley algorithms refuse — their post_round must see
-    # every round). Phase timings/recompile attribution become
-    # per-dispatch when K>1 (docs/OBSERVABILITY.md).
-    rounds_per_dispatch: int = 1
     # --- telemetry (telemetry/; docs/OBSERVABILITY.md) ----------------------
     # "off" (default): zero instrumentation — metrics.jsonl keeps the
     # legacy v1 record layout byte-for-byte and the measured program is
@@ -525,8 +507,8 @@ class ExperimentConfig:
     # measured fidelity bound on the cheap estimator. Audits are pure
     # reads (training is untouched) and cost roughly one extra cohort
     # training pass + the walk; they refuse failure models, async mode,
-    # non-mean aggregation, persistent client optimizers, multihost,
-    # and rounds_per_dispatch > 1 (the replay's exactness contract).
+    # non-mean aggregation, persistent client optimizers and multihost
+    # (the replay's exactness contract).
     # Single-host mesh_devices > 1 COMPOSES: the audit walk's subset
     # evaluation shards over the mesh, bit-identical to the serial walk
     # (algorithms/shapley.eval_mesh_devices). Caveat, documented not
@@ -687,17 +669,6 @@ class ExperimentConfig:
                     "support sweeps: its post_round drives data-dependent "
                     "subset evaluation that must observe every round "
                     "synchronously; run Shapley configs as solo runs"
-                )
-            if (
-                self.client_residency.lower() == "streamed"
-                and self.rounds_per_dispatch > 1
-            ):
-                raise ValueError(
-                    "client_residency='streamed' with rounds_per_dispatch"
-                    " > 1 does not compose with sweeps: the scheduler "
-                    "cannot host-replay K stacked cohort plans across "
-                    "points sharing one streamer; set "
-                    "rounds_per_dispatch=1 or client_residency='resident'"
                 )
             if self.multihost:
                 raise ValueError(
@@ -911,15 +882,6 @@ class ExperimentConfig:
                         "sampler pays an O(N log N) permutation PER "
                         "HOST per round)"
                     )
-                if self.rounds_per_dispatch > 1:
-                    raise ValueError(
-                        "client_residency='streamed' under multihost "
-                        "requires rounds_per_dispatch=1: a fused "
-                        "K-round dispatch would need K owner-sharded "
-                        "assemblies and spill exchanges inside one "
-                        "program, which the host-side exchange cannot "
-                        "serve mid-dispatch"
-                    )
                 if (
                     self.distributed_algorithm == "fed_quant"
                     and self.participation_fraction < 1.0
@@ -1046,13 +1008,6 @@ class ExperimentConfig:
                     "full-participation cohort would have to grow with "
                     "the population"
                 )
-            if self.rounds_per_dispatch > 1:
-                raise ValueError(
-                    "population='dynamic' requires rounds_per_dispatch=1:"
-                    " registration events (joins/departures/drift) apply "
-                    "at host round boundaries, which a fused K-round "
-                    "scan dispatch does not expose"
-                )
             if self.multihost:
                 raise ValueError(
                     "population='dynamic' does not compose with "
@@ -1079,18 +1034,6 @@ class ExperimentConfig:
                     "itself composes — its vector grows with the "
                     "population)"
                 )
-        if self.rounds_per_dispatch < 1:
-            raise ValueError("rounds_per_dispatch must be >= 1")
-        if (
-            self.rounds_per_dispatch > 1
-            and self.execution_mode.lower() == "threaded"
-        ):
-            # The thread-per-client oracle sequences rounds on the host by
-            # construction; there is no program to batch.
-            raise ValueError(
-                "rounds_per_dispatch > 1 requires the vmap execution mode "
-                "(the threaded oracle dispatches per round)"
-            )
         if (
             self.shapley_eval_samples is not None
             and self.shapley_eval_samples < 1
@@ -1216,12 +1159,6 @@ class ExperimentConfig:
                     "valuation audits require reset_client_optimizer="
                     "True (the replay cannot reconstruct pre-round "
                     "persistent optimizer state)"
-                )
-            if self.rounds_per_dispatch > 1:
-                raise ValueError(
-                    "valuation audits require rounds_per_dispatch=1 "
-                    "(the audit replays one round's key chain against "
-                    "that round's pre-round global params)"
                 )
             if self.multihost:
                 # Single-host mesh sharding COMPOSES (the audit walk's

@@ -527,174 +527,6 @@ def chunked_accumulate(trees, chunk: int, compute_fn, acc0, per_chunk=None,
     return acc, per_client
 
 
-def make_batched_round_fn(round_fn, server_update_fn, eval_fn, length: int,
-                          lr_schedule: bool, async_mode: bool = False):
-    """Fuse ``length`` federated rounds into ONE dispatchable program
-    (config.rounds_per_dispatch; docs/PERFORMANCE.md § Round batching).
-
-    The host round loop pays per-round dispatch, eval launch, and sync
-    costs that a ~100 ms round cannot amortize (measured ~28% of the
-    headline round is host-side). This builds a ``lax.scan`` whose body
-    replays the host loop's per-round sequence EXACTLY — the
-    ``key, round_key = jax.random.split(key)`` chain, the round program,
-    the optional server-optimizer step (fed the round's quorum verdict,
-    like the host path), and the server eval — so K>1 history is
-    bit-identical to K=1; only where the sequencing runs moves. Per-round
-    metrics and aux diagnostics come back stacked ``[length, ...]`` for
-    one host fetch per dispatch.
-
-    ``lr_schedule`` (trace-time): when True the returned function takes a
-    ``[length]`` f32 vector of per-round schedule factors (simulator
-    ``lr_factors``) and the scan consumes one per round; when False the
-    round fn is called WITHOUT the operand so the constant default
-    constant-folds exactly as in the unbatched program.
-
-    ``async_mode`` (trace-time; config.async_mode='on'): the round fn's
-    staleness-buffer state (robustness/arrivals.py) joins the scan carry
-    — each iteration feeds the previous round's ``aux['async_state']``
-    back as the ``async_state`` operand, exactly replaying the host
-    loop's pop-and-refeed sequence, and the dispatch returns the final
-    buffer state as a trailing output. The carried state is popped from
-    aux BEFORE stacking (a param-sized buffer stacked K times would
-    defeat the point of one accumulator).
-
-    Returns ``batched(global_params, client_state, server_state, key,
-    cx, cy, cmask, sizes, eval_batches[, lr_vec][, async_state]) ->
-    (new_global, new_client_state, new_server_state, new_key, metrics_k,
-    aux_k[, async_state])``. ``client_state``/``server_state`` may be
-    None (absent state carries through the scan as an empty subtree).
-    Algorithms opt in via ``Algorithm.supports_round_batching`` — the
-    scan stacks every aux leaf, so aux must not carry per-round
-    parameter STACKS, and post_round hooks only see dispatch-granular
-    params.
-    """
-
-    def batched(global_params, client_state, server_state, key,
-                cx, cy, cmask, sizes, eval_batches, lr_vec=None,
-                async_state=None):
-        def body(carry, lr_k):
-            if async_mode:
-                gp, cstate, sstate, k, astate = carry
-                kw = {"async_state": astate}
-            else:
-                gp, cstate, sstate, k = carry
-                kw = {}
-            k, round_key = jax.random.split(k)
-            if lr_schedule:
-                new_gp, cstate, aux = round_fn(
-                    gp, cstate, cx, cy, cmask, sizes, round_key, lr_k, **kw
-                )
-            else:
-                new_gp, cstate, aux = round_fn(
-                    gp, cstate, cx, cy, cmask, sizes, round_key, **kw
-                )
-            if async_mode:
-                aux = dict(aux)
-                astate = aux.pop("async_state")
-            if server_update_fn is not None:
-                srv_args = (gp, new_gp, sstate)
-                if "round_rejected" in aux:
-                    srv_args += (aux["round_rejected"],)
-                new_gp, sstate = server_update_fn(*srv_args)
-            metrics = eval_fn(new_gp, *eval_batches)
-            carry = (
-                (new_gp, cstate, sstate, k, astate) if async_mode
-                else (new_gp, cstate, sstate, k)
-            )
-            return carry, (metrics, aux)
-
-        carry0 = (global_params, client_state, server_state, key)
-        if async_mode:
-            carry0 = carry0 + (async_state,)
-        carry_out, (metrics_k, aux_k) = jax.lax.scan(
-            body, carry0,
-            lr_vec if lr_schedule else None,
-            length=None if lr_schedule else length,
-        )
-        if async_mode:
-            gp, cstate, sstate, key, astate = carry_out
-            return gp, cstate, sstate, key, metrics_k, aux_k, astate
-        gp, cstate, sstate, key = carry_out
-        return gp, cstate, sstate, key, metrics_k, aux_k
-
-    return batched
-
-
-def make_streamed_batched_round_fn(round_fn, server_update_fn, eval_fn,
-                                   length: int, lr_schedule: bool,
-                                   async_mode: bool = False):
-    """Batched dispatch for the STREAMED calling convention with a
-    sampled cohort (config.client_residency='streamed' +
-    rounds_per_dispatch > 1; parallel/streaming.py).
-
-    Mirrors :func:`make_batched_round_fn`'s scan — the same
-    ``key, round_key = jax.random.split(key)`` chain, server-optimizer
-    step, and fused eval, so K>1 streamed history is bit-identical to
-    the K=1 loop — but the per-round client data arrives PRE-GATHERED:
-    the K cohorts' slices are stacked ``[K, cohort, ...]`` scan operands
-    (uploaded by the streamer, which host-replayed this scan's key chain
-    to know the cohorts ahead of time) and each iteration consumes one
-    slice. There is no client-state carry: the simulator refuses
-    streamed batching with persistent per-client state — cohorts inside
-    one dispatch may overlap, and a scan iteration cannot scatter into
-    the host store mid-dispatch.
-
-    Returns ``batched(global_params, server_state, key, xs_k, ys_k,
-    ms_k, sizes_k, idx_k, eval_batches[, lr_vec][, async_state]) ->
-    (new_global, new_server_state, new_key, metrics_k, aux_k
-    [, async_state])``.
-    """
-
-    def batched(global_params, server_state, key, xs_k, ys_k, ms_k,
-                sizes_k, idx_k, eval_batches, lr_vec=None,
-                async_state=None):
-        def body(carry, scan_in):
-            if async_mode:
-                gp, sstate, k, astate = carry
-                kw = {"async_state": astate}
-            else:
-                gp, sstate, k = carry
-                kw = {}
-            if lr_schedule:
-                x_r, y_r, m_r, s_r, i_r, lr_k = scan_in
-            else:
-                x_r, y_r, m_r, s_r, i_r = scan_in
-            k, round_key = jax.random.split(k)
-            args = (gp, None, x_r, y_r, m_r, s_r, i_r, round_key)
-            if lr_schedule:
-                args = args + (lr_k,)
-            new_gp, _state, aux = round_fn(*args, **kw)
-            if async_mode:
-                aux = dict(aux)
-                astate = aux.pop("async_state")
-            if server_update_fn is not None:
-                srv_args = (gp, new_gp, sstate)
-                if "round_rejected" in aux:
-                    srv_args += (aux["round_rejected"],)
-                new_gp, sstate = server_update_fn(*srv_args)
-            metrics = eval_fn(new_gp, *eval_batches)
-            carry = (
-                (new_gp, sstate, k, astate) if async_mode
-                else (new_gp, sstate, k)
-            )
-            return carry, (metrics, aux)
-
-        xs = (xs_k, ys_k, ms_k, sizes_k, idx_k)
-        if lr_schedule:
-            xs = xs + (lr_vec,)
-        carry0 = (global_params, server_state, key)
-        if async_mode:
-            carry0 = carry0 + (async_state,)
-        carry_out, (metrics_k, aux_k) = jax.lax.scan(body, carry0, xs)
-        if async_mode:
-            gp, sstate, key, astate = carry_out
-            return gp, sstate, key, metrics_k, aux_k, astate
-        gp, sstate, key = carry_out
-        return gp, sstate, key, metrics_k, aux_k
-
-    return batched
-
-
 def make_experiment_round_fn(round_fn, lr_schedule: bool):
     """vmap a resident-convention round fn over a leading EXPERIMENT axis
     (the sweep engine's vmapped fleet, sweep/engine.py).
@@ -711,7 +543,7 @@ def make_experiment_round_fn(round_fn, lr_schedule: bool):
     bit-for-bit; everything downstream is the same XLA ops with one more
     batch dimension.
 
-    ``lr_schedule`` (trace-time, the PR 5 operand discipline): when True
+    ``lr_schedule`` (trace-time): when True
     the returned function takes a ``[E]`` f32 vector — per-experiment lr
     factor x the round's schedule factor — consumed with ``in_axes=0``;
     when False the round fn is called WITHOUT the operand so the

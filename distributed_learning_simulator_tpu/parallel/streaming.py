@@ -35,9 +35,8 @@ replay is timed too (the ``sample`` phase + the stream record's
 **Mesh composition** (``mesh_devices > 1`` + streamed, single host):
 the streamer uploads each cohort slice directly into the client-axis
 ``PartitionSpec`` layout — one ``jax.device_put`` per array against a
-``NamedSharding`` whose client axis is the slice's cohort axis (axis 0
-per-round, axis 1 for a stacked ``[k, cohort, ...]`` batched
-dispatch), so the host->device transfer is split per shard by the
+``NamedSharding`` whose client axis is the slice's cohort axis (axis
+0), so the host->device transfer is split per shard by the
 mesh's client-axis ownership and the round program consumes the slice
 without a resharding copy. Double buffering is unchanged (the worker
 thread's device_put targets the sharded layout directly) and the
@@ -98,10 +97,8 @@ class CohortStreamer:
     """Owns the host shard store's device side: upload, prefetch, writeback.
 
     One dispatch's upload is a tuple ``(x, y, m, sizes, idx)`` of device
-    arrays — cohort-shaped for a single round (``[cohort, ...]``), or
-    stacked ``[k, cohort, ...]`` for a batched dispatch
-    (config.rounds_per_dispatch > 1). ``prefetch`` schedules the upload
-    on the ONE worker thread (uploads are sequential by construction —
+    arrays, cohort-shaped (``[cohort, ...]``). ``prefetch`` schedules the
+    upload on the ONE worker thread (uploads are sequential by construction —
     double buffering needs exactly one in flight); ``acquire`` collects
     it, falling back to a synchronous upload when nothing (or the wrong
     cohort — e.g. after a preemption break) is pending.
@@ -169,18 +166,14 @@ class CohortStreamer:
             "d2h_bytes": 0, "d2h_seconds": 0.0, "sample_seconds": 0.0,
         }
 
-    def _placed(self, a, client_axis: int):
+    def _placed(self, a):
         """device_put one upload array: uncommitted default device
         (single-device runs), the explicit device, or — under a mesh —
-        the client-axis NamedSharding with the cohort axis at
-        ``client_axis`` (0 for a per-round slice, 1 for a stacked
-        ``[k, cohort, ...]`` batched dispatch)."""
+        the client-axis NamedSharding (the cohort axis leads)."""
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
-            spec = PartitionSpec(
-                *([None] * client_axis), self._mesh.axis_names[0]
-            )
+            spec = PartitionSpec(self._mesh.axis_names[0])
             return jax.device_put(a, NamedSharding(self._mesh, spec))
         if self._device is not None:
             return jax.device_put(a, self._device)
@@ -214,47 +207,32 @@ class CohortStreamer:
         return None if idx is None else np.asarray(idx)
 
     # ---- upload / prefetch -------------------------------------------------
-    def _upload(self, idx_list, stack: bool):
+    def _upload(self, idx_list):
         """Worker-thread body: gather + device_put + block, timed.
 
-        ``idx_list`` is one index array per round in the dispatch; a
-        per-round dispatch (``stack=False``, one entry) uploads
-        cohort-shaped arrays, a batched scan dispatch (``stack=True``)
-        stacks them ``[k, cohort, ...]`` — even at k=1, where the
-        remainder scan still consumes a leading round axis.
+        ``idx_list`` holds the dispatch's one index array; the upload
+        is cohort-shaped.
         """
         with _maybe_span(
             self.span_recorder, "prefetch_upload", "stream",
             round_idx=self.span_round,
         ) as _sp:
-            return self._upload_body(idx_list, stack, _sp)
+            return self._upload_body(idx_list, _sp)
 
-    def _upload_body(self, idx_list, stack: bool, _sp):
+    def _upload_body(self, idx_list, _sp):
         t0 = clock.monotonic()
-        slices = [self.store.gather_data(idx) for idx in idx_list]
-        if not stack:
-            x, y, m, s = slices[0]
-            # idx None = the whole population (upload_full): the round
-            # program's idx operand stays None too.
-            idx_arr = (
-                None if idx_list[0] is None
-                else np.asarray(idx_list[0], dtype=np.int32)
-            )
-        else:
-            x, y, m, s = (
-                np.stack([sl[j] for sl in slices]) for j in range(4)
-            )
-            idx_arr = np.stack(
-                [np.asarray(idx, dtype=np.int32) for idx in idx_list]
-            )
+        x, y, m, s = self.store.gather_data(idx_list[0])
+        # idx None = the whole population (upload_full): the round
+        # program's idx operand stays None too.
+        idx_arr = (
+            None if idx_list[0] is None
+            else np.asarray(idx_list[0], dtype=np.int32)
+        )
         host_arrays = (x, y, m, s, idx_arr)
-        # Cohort axis: leading for a per-round slice, axis 1 behind the
-        # round axis for a stacked batched dispatch — the mesh placement
-        # shards exactly that axis (PartitionSpec layout).
-        client_axis = 1 if stack else 0
+        # The cohort axis leads: the mesh placement shards exactly that
+        # axis (PartitionSpec layout).
         arrays = tuple(
-            None if a is None else self._placed(a, client_axis)
-            for a in host_arrays
+            None if a is None else self._placed(a) for a in host_arrays
         )
         # device_put is asynchronous; the transfer is only DONE here —
         # which is the point: this block runs on the worker thread, so at
@@ -265,7 +243,7 @@ class CohortStreamer:
             _sp["bytes"] = nbytes
         return arrays, nbytes, clock.monotonic() - t0
 
-    def prefetch(self, idx_list, stack: bool = False) -> None:
+    def prefetch(self, idx_list) -> None:
         """Schedule the upload for the NEXT dispatch's cohorts; returns
         immediately. At most one prefetch is in flight (a second call
         before acquire drains the first — the pipeline is strictly
@@ -273,23 +251,22 @@ class CohortStreamer:
         if self._pending is not None:
             # Shouldn't happen in the dispatch loop's sequencing; drain
             # rather than leak a future.
-            self._pending[2].result()
+            self._pending[1].result()
             self._pending = None
         self._pending = (
-            idx_list, stack, self._pool.submit(self._upload, idx_list, stack)
+            idx_list, self._pool.submit(self._upload, idx_list)
         )
 
-    def acquire(self, idx_list, stack: bool = False):
+    def acquire(self, idx_list):
         """Collect the upload for ``idx_list``, preferring the prefetched
         one. Returns ``((x, y, m, sizes, idx_dev), stats)`` where stats
         is this upload's contribution to the stream record."""
         arrays = None
         if self._pending is not None:
-            pend_idx, pend_stack, fut = self._pending
+            pend_idx, fut = self._pending
             self._pending = None
             if (
-                pend_stack == stack
-                and len(pend_idx) == len(idx_list)
+                len(pend_idx) == len(idx_list)
                 and all(
                     np.array_equal(a, b)
                     for a, b in zip(pend_idx, idx_list)
@@ -309,7 +286,7 @@ class CohortStreamer:
                 self.totals["h2d_bytes"] += stale_bytes
                 self.totals["h2d_seconds"] += stale_dt
         if arrays is None:
-            arrays, nbytes, dt = self._upload(idx_list, stack)
+            arrays, nbytes, dt = self._upload(idx_list)
             hidden = 0.0
         self.totals["h2d_bytes"] += nbytes
         self.totals["h2d_seconds"] += dt
@@ -335,7 +312,7 @@ class CohortStreamer:
         per-step vote over everyone). The arrays stay device-resident for
         the run — streamed residency then only moves WHERE the startup
         upload is accounted."""
-        arrays, nbytes, dt = self._upload([None], stack=False)
+        arrays, nbytes, dt = self._upload([None])
         self.totals["h2d_bytes"] += nbytes
         self.totals["h2d_seconds"] += dt
         stats = {
@@ -376,7 +353,7 @@ class CohortStreamer:
         if self._pending is not None:
             # Never leak a worker-thread upload past the run.
             try:
-                self._pending[2].result()
+                self._pending[1].result()
             except Exception:
                 pass
             self._pending = None

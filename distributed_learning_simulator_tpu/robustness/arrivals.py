@@ -156,7 +156,7 @@ class AsyncFederation:
         table. Built EAGERLY once at round-fn construction and closed
         over as a constant: the factors depend only on ``arrival_seed``
         and the client index, so recomputing the per-client fold_in
-        chains inside the compiled round (×K under round batching)
+        chains inside the compiled round
         would be pure waste — the round program just gathers from the
         table."""
         return self.speed_factors(jnp.arange(n_clients))
@@ -224,9 +224,9 @@ class AsyncFederation:
     def init_state(self, global_params) -> dict:
         """Round-0 buffer state: one f32 param-sized accumulator of
         discounted late deltas plus three scalars. This dict is the
-        round program's async carry — threaded through
-        ``rounds_per_dispatch`` scans, checkpointed, and restored on
-        resume like every other piece of round state."""
+        round program's async carry — handed from round to round by the
+        host loop, checkpointed, and restored on resume like every other
+        piece of round state."""
         return {
             "buf_sum": jax.tree_util.tree_map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), global_params

@@ -27,7 +27,6 @@ import signal
 import threading
 import time
 import zlib
-from collections.abc import Mapping
 from contextlib import ExitStack, contextmanager
 
 import jax
@@ -47,12 +46,10 @@ from distributed_learning_simulator_tpu.data.residency import HostShardStore
 from distributed_learning_simulator_tpu.factory import get_algorithm
 from distributed_learning_simulator_tpu.models.registry import get_model, init_params
 from distributed_learning_simulator_tpu.parallel.engine import (
-    make_batched_round_fn,
     make_decoder,
     make_eval_fn,
     make_optimizer,
     make_reshaper,
-    make_streamed_batched_round_fn,
     pad_eval_set,
 )
 from distributed_learning_simulator_tpu.parallel.mesh import (
@@ -308,20 +305,6 @@ def _lr_factor(config, round_idx: int) -> float:
     return config.lr_step_gamma ** (round_idx // config.lr_step_size)
 
 
-def lr_factors(config, start: int, k: int) -> np.ndarray:
-    """Schedule factors for rounds ``start .. start+k-1`` as one f32 vector.
-
-    The single source for BOTH dispatch shapes: the host loop's per-round
-    scalar is ``lr_factors(config, r, 1)[0]`` and the batched dispatch
-    (config.rounds_per_dispatch > 1) passes the whole vector as the scan
-    operand — same _lr_factor values through the same f32 cast, so the
-    two programs see bit-identical schedule operands.
-    """
-    return np.asarray(
-        [_lr_factor(config, start + i) for i in range(k)], dtype=np.float32
-    )
-
-
 def build_base_round_record(config, round_idx: int, metrics: dict,
                             fetched_loss, fetched_tel: dict, extra: dict,
                             round_seconds: float) -> dict:
@@ -375,39 +358,13 @@ _ASYNC_AUX_KEYS = (
 )
 
 
-class _StackedAuxRow(Mapping):
-    """Lazy per-round view of a batched dispatch's scan-stacked aux.
-
-    RoundContext.aux promises per-round device arrays, but no
-    batching-capable algorithm's post_round reads aux today — slicing
-    every stacked leaf eagerly would dispatch K x leaves tiny gather ops
-    per dispatch on exactly the host path round batching exists to
-    shrink. Leaves are sliced only on access."""
-
-    __slots__ = ("_aux_k", "_i")
-
-    def __init__(self, aux_k: dict, i: int):
-        self._aux_k = aux_k
-        self._i = i
-
-    def __getitem__(self, name):
-        return self._aux_k[name][self._i]
-
-    def __iter__(self):
-        return iter(self._aux_k)
-
-    def __len__(self):
-        return len(self._aux_k)
-
-
 def _algo_checkpoint_state(algorithm, metrics, server_state,
                            async_state=None, valuation=None,
                            population=None) -> dict:
     """Assemble the checkpoint's ``algo_state`` dict — the ONE copy shared
-    by the round-loop checkpoint cadence, the batched-dispatch flush, and
-    the SIGTERM force-write path (the copies were one field away from
-    drifting). ``async_state`` is the staleness-buffer carry
-    (robustness/arrivals.py) — persisted so an async resume replays the
+    by the round-loop checkpoint cadence and the SIGTERM force-write path
+    (the copies were one field away from drifting). ``async_state`` is the
+    staleness-buffer carry (robustness/arrivals.py) — persisted so an async resume replays the
     buffer bit-exactly, absent entirely for synchronous runs.
     ``valuation`` is the streaming per-client valuation vector
     (telemetry/valuation.py) — persisted so a resumed run keeps its
@@ -793,23 +750,8 @@ def run_simulation(
                 f"algorithm {config.distributed_algorithm!r} does not support "
                 "lr_schedule (its round program takes no lr_scale operand)"
             )
-        if config.rounds_per_dispatch > 1 and not getattr(
-            algorithm, "supports_round_batching", False
-        ):
-            # Same capability pattern as supports_round_pipelining, but a
-            # refusal rather than a silent fallback: the user asked for a
-            # different dispatch shape, and post_round hooks that must see
-            # every round (Shapley's data-dependent subset evaluation) cannot
-            # run inside one fused program.
-            raise ValueError(
-                f"algorithm {config.distributed_algorithm!r} does not support "
-                "rounds_per_dispatch > 1: its post_round must observe every "
-                "round (for the FedAvg family this includes client_eval=True "
-                "and keep_client_params — their aux/post_round consume "
-                "per-round parameter stacks); set rounds_per_dispatch=1"
-            )
         # Asynchronous federation (robustness/arrivals.py): same capability
-        # pattern as supports_round_batching — a refusal with the cause, not
+        # pattern as supports_lr_schedule — a refusal with the cause, not
         # a silent synchronous run the user didn't ask for.
         async_ctl = AsyncFederation.from_config(config)
         if async_ctl is not None and not getattr(
@@ -823,9 +765,6 @@ def run_simulation(
 
         # --- programs: eval, round, server update (jit wrappers) -----------------
         tracer.section("setup/build")
-        # The raw eval fn is shared by the standalone jitted program (K=1
-        # dispatches) and the batched dispatch, which fuses it into the
-        # round scan (rounds_per_dispatch > 1).
         eval_fn = make_eval_fn(
             model.apply, preprocess=eval_preprocess, name="server_eval"
         )
@@ -855,9 +794,9 @@ def run_simulation(
         if stream_full:
             # Full-cohort streamed convention differs from the resident one
             # only by the idx operand (always None — the cohort is everyone).
-            # Re-adapt so the round loop (and make_batched_round_fn) runs the
-            # SAME call shape as resident — which is what makes this regime
-            # bit-identical by construction.
+            # Re-adapt so the round loop runs the SAME call shape as
+            # resident — which is what makes this regime bit-identical by
+            # construction.
             _streamed_fn = round_fn
 
             def round_fn(global_params, client_state, cx, cy, cmask, sizes,
@@ -1079,7 +1018,7 @@ def run_simulation(
                                 f"{config.server_optimizer_name!r}; resume with "
                                 "the configuration the checkpoint was written with"
                             )
-                        # Donated by server_update_jit/batched dispatch.
+                        # Donated by server_update_jit.
                         server_state = _owned_device_tree(saved_ss)
                 saved_async = ckpt["algo_state"].get("async_state")
                 if async_ctl is None and saved_async is not None:
@@ -1207,7 +1146,7 @@ def run_simulation(
                 # into this host's addressable shards of the client-axis
                 # PartitionSpec. config.validate() pinned the composition
                 # (hashed sampler for sampled cohorts, no dynamic
-                # population / client_stats / valuation / async / K>1).
+                # population / client_stats / valuation / async).
                 store = DistributedShardStore(
                     client_data.x, _pop_y, client_data.mask,
                     client_data.sizes,
@@ -1358,24 +1297,6 @@ def run_simulation(
             config.checkpoint_dir and config.checkpoint_every
             and (is_primary or mh)
         )
-        # Round batching (config.rounds_per_dispatch > 1): K rounds fuse into
-        # one scan dispatch with one metric fetch each; pipelining's
-        # deferred-fetch trick is subsumed (the dispatch itself overlaps the
-        # per-round fetches it absorbed), so the two modes don't compose.
-        K = config.rounds_per_dispatch
-        batched = K > 1
-        if batched and stream_sampled and store.state is not None:
-            # Cohorts inside one fused dispatch may overlap, and a scan
-            # iteration cannot scatter into the host store mid-dispatch —
-            # round r+1's gathered state slice would miss round r's update.
-            raise ValueError(
-                "client_residency='streamed' with rounds_per_dispatch > 1 "
-                "does not compose with persistent per-client state "
-                "(reset_client_optimizer=False / momentum sign_SGD under "
-                "sampling): cohorts within one dispatch may overlap and the "
-                "host store cannot be updated mid-dispatch; set "
-                "rounds_per_dispatch=1 or client_residency='resident'"
-            )
         # Streamed residency with persistent per-client state: the per-round
         # writeback (a device_get of the cohort state) already syncs every
         # round, so a deferred metric fetch hides nothing — and a deferred
@@ -1386,7 +1307,6 @@ def run_simulation(
         )
         pipelined = (
             config.pipeline_rounds
-            and not batched
             and not stream_stateful
             and pop is None
             and algorithm.supports_round_pipelining
@@ -1398,12 +1318,7 @@ def run_simulation(
         if config.pipeline_rounds and not pipelined:
             # The user asked for pipelining; say out loud why it is off (each
             # deferred fetch otherwise silently costs a full host-link RTT).
-            if batched:
-                reason = (
-                    "rounds_per_dispatch > 1 already amortizes the fetch "
-                    "(one device_get per dispatch)"
-                )
-            elif pop is not None:
+            if pop is not None:
                 reason = (
                     "population='dynamic' registration events mutate host "
                     "population state at every round boundary; a deferred "
@@ -1540,11 +1455,11 @@ def run_simulation(
         # the program writes the new global into the old one's buffer, and
         # two f32 copies of the model are alive on the device for three. A
         # pipelined loop keeps round r's global for its deferred evaluation
-        # and finalize, a batched dispatch has a jit of its own, and the
-        # Shapley servers' post_round, the valuation auditor and the server
-        # optimizer each take the previous global: those keep (1,).
+        # and finalize, and the Shapley servers' post_round, the valuation
+        # auditor and the server optimizer each take the previous global:
+        # those keep (1,).
         donate_global = (
-            not pipelined and not batched and auditor is None
+            not pipelined and auditor is None
             and server_update_jit is None
             and algorithm.supports_global_donation
         )
@@ -1668,25 +1583,17 @@ def run_simulation(
                 )
 
         def emit_record(round_idx, metrics, fetched_loss, fetched_tel, ctx,
-                        tel_rec_fn, phase_round=None, stream_rec=None,
+                        tel_rec_fn, stream_rec=None,
                         audit_fn=None, population_rec=None,
                         multihost_rec=None):
             """Build + persist ONE round's metrics record from already-fetched
             host values: post_round hook, then, under the ``record`` span,
             record assembly, quorum/cohort telemetry accumulation, client-stats
-            detection, history append + metrics.jsonl line. The shared tail
-            of the K=1 ``finalize`` and the batched-dispatch
-            ``flush_dispatch`` — one copy, so the record layout (and its
-            byte-identical-at-defaults guarantee) cannot drift between
-            dispatch shapes. ``tel_rec_fn`` builds the telemetry sub-object
-            lazily AFTER post_round (so host-side compiles attribute to this
-            round); ``phase_round`` is where post_round phase time
-            accumulates (the dispatch's last round under batching, so the
-            one telemetry record carries every phase)."""
+            detection, history append + metrics.jsonl line. ``tel_rec_fn``
+            builds the telemetry sub-object lazily AFTER post_round (so
+            host-side compiles attribute to this round)."""
             nonlocal prev_metrics, t_prev_done
-            if phase_round is None:
-                phase_round = round_idx
-            with tracer.span("post_round", "phase", round_idx=phase_round,
+            with tracer.span("post_round", "phase", round_idx=round_idx,
                              phase="post_round"):
                 extra = algorithm.post_round(ctx) or {}
             # Mesh-sharded GTG walk provenance (algorithms/shapley.py): a
@@ -1702,9 +1609,7 @@ def run_simulation(
                 # eval + metric fetch + host post_round (Shapley time included —
                 # it IS per-round server work). Sums to total wall time, less
                 # the periodic checkpoints of a loop that is not pipelined
-                # (``_finalize`` restarts the clock after one) (within
-                # a batched dispatch the dispatch's wall lands on its first
-                # round; later rounds record only their host-side tail).
+                # (``_finalize`` restarts the clock after one).
                 record = build_base_round_record(
                     config, round_idx, metrics, fetched_loss, fetched_tel, extra,
                     round_seconds=now - t_prev_done,
@@ -1819,10 +1724,7 @@ def run_simulation(
                 cm_rec = None
                 if cost_ledger is not None and round_idx == config.round - 1:
                     # The run's measured per-round wall, averaged over the steady
-                    # rounds (round 0 carries compile; under batched dispatch a
-                    # dispatch's wall lands on its first round, so the MEAN over
-                    # steady rounds — elapsed/rounds — is the honest unit in
-                    # every dispatch shape).
+                    # rounds (round 0 carries compile).
                     walls = [h["round_seconds"] for h in history] + [
                         record["round_seconds"]
                     ]
@@ -2027,7 +1929,7 @@ def run_simulation(
                         p["round_idx"], p["round_key"], p["prev_global"],
                         v_ids, vstate.values,
                         lr_scale=float(
-                            lr_factors(config, p["round_idx"], 1)[0]
+                            np.float32(_lr_factor(config, p["round_idx"]))
                         ),
                     )
 
@@ -2079,151 +1981,6 @@ def run_simulation(
             maybe_crash(p["round_idx"])
             return saved
 
-        # Dispatch sizes already compiled this run (rounds_per_dispatch > 1):
-        # a size seen for the first time (remainder/checkpoint-clipped
-        # dispatches) legitimately compiles its own scan program — logged as
-        # warmup, not as the shape-instability warning.
-        seen_dispatch_sizes: set[int] = set()
-
-        def flush_dispatch(d: dict) -> None:
-            """Record a whole batched dispatch (rounds_per_dispatch > 1): ONE
-            device_get for the stacked per-round metrics/telemetry, then one
-            emit_record per round. Phase timings and recompile attribution
-            are per-DISPATCH, attached to the dispatch's LAST round's record
-            (the only one whose post_round has already run when its record is
-            written; docs/OBSERVABILITY.md)."""
-            first, k = d["round_start"], d["k"]
-            last = first + k - 1
-            rounds = range(first, last + 1)
-            aux_k = d["aux"]
-            tel_keys = [
-                name for name in
-                ("survivor_count", "round_rejected", "participants",
-                 "model_counts")
-                if name in aux_k
-            ]
-            # Client-stats cadence at batch granularity: the stacked rows ride
-            # the dispatch's single device_get; records carry them only for
-            # rounds on the client_stats_every cadence (matching K=1).
-            fetch_rounds = {
-                r for r in rounds
-                if client_stats_cfg is not None
-                and client_stats_cfg.fetch_round(r)
-            }
-            cs_keys = [
-                name for name in ("client_stats", "quant_mse", "vote_agreement")
-                if name in aux_k
-            ] if fetch_rounds else []
-            # Valuation scores: stacked [K, N] — every round's row feeds its
-            # own loss-delta fold (no cadence; the vector must not skip
-            # rounds).
-            val_keys = (
-                ["valuation_scores"]
-                if vstate is not None and "valuation_scores" in aux_k
-                else []
-            )
-            async_keys = [name for name in _ASYNC_AUX_KEYS if name in aux_k]
-            with tracer.span("host_sync", "phase", round_idx=last,
-                             phase="host_sync"), _oom_hint(
-                    config, d["new_global"], n_clients,
-                    site="deferred metric fetch"):
-                fetched_metrics, fetched_loss, fetched_tel = jax.device_get(
-                    (d["metrics"], d["mean_loss"],
-                     {name: aux_k[name]
-                      for name in tel_keys + cs_keys + val_keys + async_keys})
-                )
-            if "model_counts" in fetched_tel:
-                tracer.add_counts(fetched_tel["model_counts"])
-
-            def tel_rec_fn():
-                if not tracer.phases.enabled:
-                    return None
-                recompile.attribute(last)
-                events = recompile.take(last)
-                warm = first == start_round or k not in seen_dispatch_sizes
-                seen_dispatch_sizes.add(k)
-                n_compiles = log_round_compiles(logger, last, events, warmup=warm)
-                if not warm:
-                    post_warmup_compiles["count"] += n_compiles
-                tel_rec = {
-                    "phase_seconds": {
-                        name: round(v, 6)
-                        for name, v in sorted(tracer.phases.take(last).items())
-                    },
-                    "compiles": n_compiles,
-                    # Tells consumers (scripts/report_run.py) the phase times
-                    # and compile counts cover this many rounds — render
-                    # per-dispatch, never double-count.
-                    "dispatch_rounds": k,
-                }
-                if warm and n_compiles:
-                    # First dispatch of this length: its compiles are
-                    # expected, so offline reporting must not count them as
-                    # post-warmup shape instability.
-                    tel_rec["warmup"] = True
-                if events:
-                    tel_rec["compiled"] = [name for name, _ in events]
-                peak = peak_hbm_bytes()
-                if peak is not None:
-                    tel_rec["peak_hbm_bytes"] = peak
-                return tel_rec
-
-            for i, round_idx in enumerate(rounds):
-                metrics = {
-                    name: float(v[i]) for name, v in fetched_metrics.items()
-                }
-                row_keys = tel_keys + async_keys + val_keys + (
-                    cs_keys if round_idx in fetch_rounds else []
-                )
-                tel_row = {name: fetched_tel[name][i] for name in row_keys}
-                ctx = RoundContext(
-                    round_idx=round_idx,
-                    # Dispatch-granular params — the supports_round_batching
-                    # contract: post_round sees the dispatch-FINAL model and
-                    # the dispatch-initial previous one.
-                    global_params=d["new_global"],
-                    prev_global_params=d["prev_global"],
-                    sizes=sizes,
-                    aux=_StackedAuxRow(aux_k, i),
-                    metrics=metrics,
-                    prev_metrics=prev_metrics,
-                    eval_batches=eval_batches,
-                    log_dir=log_dir,
-                )
-                if "client_stats" in tel_row:
-                    ctx.extra["client_stats_np"] = np.asarray(
-                        tel_row["client_stats"]
-                    )
-                emit_record(
-                    round_idx, metrics, fetched_loss[i], tel_row, ctx,
-                    tel_rec_fn if round_idx == last else (lambda: None),
-                    phase_round=last,
-                    # Per-DISPATCH transfer stats, on the dispatch's last
-                    # record like the phase timings (docs/OBSERVABILITY.md).
-                    stream_rec=d.get("stream") if round_idx == last else None,
-                )
-            # Dispatch sizes are clipped to checkpoint boundaries, so the
-            # cadence only ever fires on the dispatch's last round — where
-            # the carried client/server/RNG state is exactly that round's.
-            if checkpointing and (last + 1) % config.checkpoint_every == 0:
-                with tracer.span("checkpoint", "host", round_idx=last):
-                    save_checkpoint(
-                        os.path.join(
-                            config.checkpoint_dir, f"round_{last}.ckpt"
-                        ),
-                        last, d["new_global"], d["client_state"],
-                        _algo_checkpoint_state(
-                            algorithm, prev_metrics, d["server_state"],
-                            d.get("async_state"),
-                            vstate.values if vstate is not None else None,
-                        ),
-                        d["key"],
-                    )
-                    gc_checkpoints(
-                        config.checkpoint_dir, config.checkpoint_keep_last
-                    )
-            maybe_crash(last)
-
         profile_from = getattr(config, "profile_from_round", 0)
         # SIGTERM grace hook (TPU preemption notice, docs/ROBUSTNESS.md): the
         # handler only sets a flag; the round loop finishes the in-flight
@@ -2259,588 +2016,359 @@ def run_simulation(
             # the deferred round that already completed on device still gets its
             # metrics line and checkpoint written before unwinding.
             try:
-                if batched:
-                    # Batched dispatches (rounds_per_dispatch > 1): the host
-                    # loop walks batch boundaries instead of rounds. Dispatch
-                    # size = min(K, rounds remaining, distance to the next
-                    # checkpoint boundary), so checkpoint_every and SIGTERM
-                    # finish-in-flight semantics keep working at batch
-                    # granularity; each distinct size compiles its own scan
-                    # program once (cached below — a remainder dispatch is a
-                    # different program, counted as warmup, not instability).
-                    batched_jits: dict[int, object] = {}
-                    lr_active = config.lr_schedule.lower() != "constant"
-                    round_idx = start_round
-
-                    def _dispatch_len(start: int) -> int:
-                        """Dispatch size from ``start``: min(K, rounds
-                        remaining, distance to the next checkpoint boundary).
-                        Clipped from the CONFIG, not `checkpointing` (which
-                        is primary-gated): under multihost SPMD every
-                        process must choose the same dispatch length or they
-                        run different scan programs and the collectives
-                        desync. Only the checkpoint WRITE is primary-only."""
-                        k = min(K, config.round - start)
-                        if config.checkpoint_dir and config.checkpoint_every:
-                            k = min(
-                                k,
-                                config.checkpoint_every
-                                - (start % config.checkpoint_every),
+                # Next round's host-replayed cohort (stream_sampled): the
+                # prefetched upload this index list describes is already
+                # in flight when the round that uses it starts.
+                stream_next_idx = None
+                for round_idx in range(start_round, config.round):
+                    if (
+                        config.profile_dir
+                        and profile_from is not None
+                        and round_idx >= profile_from
+                    ):
+                        # Deferred trace start (config.profile_from_round):
+                        # keeps round 0's XLA compile and its host events
+                        # out of the trace, so the captured window is
+                        # steady-state rounds only
+                        # (scripts/profile_sign_round.py's method). Earlier
+                        # rounds were dispatched asynchronously: wait for
+                        # the device to finish them, or their tail lands
+                        # inside the window (v5e, PR 21: a "one-round"
+                        # flagship trace held 3.6 s of device ops).
+                        jax.block_until_ready(
+                            (global_params, pending and pending["metrics_dev"])
+                        )
+                        profile_stack.enter_context(
+                            profile_session(config.profile_dir)
+                        )
+                        profile_from = None
+                    # One span per loop iteration: the iteration of round
+                    # r dispatches r and, pipelined, finalizes r-1 (whose
+                    # spans carry round r-1 under this parent).
+                    with tracer.span("round", "iter", round_idx=round_idx):
+                        key, round_key = jax.random.split(key)
+                        if span_recorder is not None and streamer is not None:
+                            # Skew/occupancy spans emitted inside the
+                            # streamer (spill exchange, prefetch worker)
+                            # attribute to the round being dispatched.
+                            streamer.span_round = round_idx
+                        with _oom_hint(config, global_params, n_clients):
+                            # The schedule factor is a traced operand only when a
+                            # schedule is active; the constant default uses the
+                            # round_fn's Python default 1.0, which constant-folds
+                            # at trace time (no per-step scale multiply in the
+                            # compiled program).
+                            lr_args = () if config.lr_schedule.lower() == (
+                                "constant"
+                            ) else (
+                                jnp.float32(_lr_factor(config, round_idx)),
                             )
-                        return k
-
-                    def _stream_plan(from_key, k):
-                        """Host replay of the batched scan's key chain
-                        (make_streamed_batched_round_fn does the same k
-                        ``key, round_key = split(key)`` steps): the k
-                        cohorts this dispatch trains, plus the key cursor
-                        AFTER it — which is what lets the next dispatch's
-                        cohorts prefetch before this one returns."""
-                        hk = from_key
-                        idx_list = []
-                        for _ in range(k):
-                            hk, rk = jax.random.split(hk)
-                            idx_list.append(streamer.cohort_for(rk))
-                        return idx_list, hk
-
-                    # (dispatch start round, its cohort plan, key cursor
-                    # after it) — prefetched while the previous dispatch ran.
-                    stream_next = None
-                    while round_idx < config.round:
-                        k = _dispatch_len(round_idx)
-                        last_idx = round_idx + k - 1
-                        if (
-                            config.profile_dir
-                            and profile_from is not None
-                            and round_idx >= profile_from
-                        ):
-                            # Deferred trace start at dispatch granularity
-                            # (rationale: the K=1 loop below).
-                            jax.block_until_ready(global_params)
-                            profile_stack.enter_context(
-                                profile_session(config.profile_dir)
-                            )
-                            profile_from = None
-                        # One span per loop iteration; a batched dispatch's
-                        # spans carry its LAST round as their identifier.
-                        with tracer.span("round", "iter", round_idx=last_idx,
-                                         rounds=k):
-                            dispatch = batched_jits.get(k)
-                            if dispatch is None:
-                                if stream_sampled:
-                                    # Streamed scan: the k cohorts' slices arrive
-                                    # stacked [k, cohort, ...]; server_state is
-                                    # operand 1 (there is no client-state carry —
-                                    # refused above when state exists).
-                                    dispatch = jax.jit(
-                                        make_streamed_batched_round_fn(
-                                            round_fn, server_update_fn, eval_fn,
-                                            k, lr_active,
-                                            async_mode=async_ctl is not None,
-                                        ),
-                                        donate_argnums=(1,),
-                                    )
-                                else:
-                                    dispatch = jax.jit(
-                                        make_batched_round_fn(
-                                            round_fn, server_update_fn, eval_fn, k,
-                                            lr_active,
-                                            async_mode=async_ctl is not None,
-                                        ),
-                                        donate_argnums=(1, 2),
-                                    )
-                                batched_jits[k] = dispatch
-                            # The schedule factors become a length-k f32 operand
-                            # vector (lr_factors — same values, same cast as the
-                            # K=1 scalar operand); the constant default is
-                            # omitted so it constant-folds exactly like the
-                            # unbatched program.
-                            lr_args = (
-                                (jnp.asarray(lr_factors(config, round_idx, k)),)
-                                if lr_active else ()
-                            )
-                            prev_global = global_params
                             async_kw = (
                                 {"async_state": async_state}
                                 if async_ctl is not None else {}
                             )
                             stream_rec = None
-                            with _oom_hint(config, global_params, n_clients):
-                                if stream_sampled:
-                                    if (
-                                        stream_next is not None
-                                        and stream_next[0] == round_idx
-                                    ):
-                                        idx_list, hk_after = stream_next[1:]
-                                    else:
-                                        # First dispatch / resume: the k draws
-                                        # get their own `sample` phase window.
-                                        with tracer.span(
-                                                "sample", "phase",
-                                                round_idx=last_idx, phase="sample"):
-                                            idx_list, hk_after = _stream_plan(
-                                                key, k
-                                            )
-                                    (sx, sy, sm, ssz, sidx), stream_rec = (
-                                        streamer.acquire(idx_list, stack=True)
+                            pop_rec = None
+                            mh_rec = None
+                            mh_plan = None
+                            if stream_sampled:
+                                # Streamed dispatch: cohort slices arrive as
+                                # pre-gathered operands (prefetched while the
+                                # previous round computed); persistent state
+                                # gathers from the host store (post the
+                                # previous round's writeback) and scatters
+                                # back after this dispatch.
+                                pop_events = pop_words = dep_mask = None
+                                if pop is not None:
+                                    # Dynamic population: the cohort is
+                                    # drawn from the PRE-event registered
+                                    # index space (departed masked out of
+                                    # the hashed stream); this round's
+                                    # events come from the fold_in-decoupled
+                                    # registration stream and APPLY after
+                                    # the dispatch — a joiner is sampleable
+                                    # from the next round, a departure that
+                                    # hits this cohort rides the departed
+                                    # operand. Drift levels advance before
+                                    # the gather so sampled drifting
+                                    # clients train on this round's labels.
+                                    pop_words = pop_key_words(
+                                        round_key, pop.seed
                                     )
-                                    if k > 1:
-                                        stream_rec["dispatch_rounds"] = k
                                     with tracer.span(
-                                            "dispatch", "phase", round_idx=last_idx,
-                                            phase="client_step") as _ph:
-                                        out = dispatch(
-                                            global_params, server_state, key,
-                                            sx, sy, sm, ssz, sidx, eval_batches,
-                                            *lr_args, **async_kw,
+                                            "sample", "phase",
+                                            round_idx=round_idx, phase="sample"):
+                                        idx_np = streamer.cohort_for(
+                                            round_key,
+                                            n=pop.n_registered,
+                                            alive=pop.alive,
+                                            k=cohort_n,
                                         )
-                                        if async_ctl is not None:
-                                            (
-                                                global_params, server_state, key,
-                                                metrics_k, aux_k, async_state,
-                                            ) = out
-                                        else:
-                                            (
-                                                global_params, server_state, key,
-                                                metrics_k, aux_k,
-                                            ) = out
-                                        # Prefetch the NEXT dispatch's cohorts
-                                        # while this dispatch computes — BEFORE
-                                        # the fence/flush syncs on its results.
-                                        nxt = last_idx + 1
-                                        stream_next = None
-                                        if nxt < config.round and not preempt["flag"]:
-                                            k2 = _dispatch_len(nxt)
-                                            # The k2 draws overlap this
-                                            # dispatch's compute; carve their
-                                            # host cost out of client_step into
-                                            # the `sample` phase (K=1 rationale
-                                            # above).
+                                    pop_events = pop.draw_events(
+                                        pop_words, round_idx
+                                    )
+                                    dep_mask = pop.cohort_departed_mask(
+                                        pop_events, idx_np
+                                    )
+                                    pop.apply_drift(store, round_idx, idx_np)
+                                elif stream_next_idx is not None:
+                                    idx_np = stream_next_idx
+                                else:
+                                    # First round / resume: the draw is not
+                                    # hidden behind a prior dispatch — its
+                                    # own `sample` phase window (under the
+                                    # distributed store this window also
+                                    # covers the owner assembly + spill
+                                    # exchange).
+                                    with tracer.span(
+                                            "sample", "phase",
+                                            round_idx=round_idx, phase="sample"):
+                                        idx_np = streamer.cohort_for(
+                                            round_key
+                                        )
+                                        if mh:
+                                            idx_np = streamer.plan(idx_np)
+                                stream_next_idx = None
+                                if mh:
+                                    # Owner-sharded assembly: this host's
+                                    # block rows, with ownership-imbalance
+                                    # spill already exchanged at plan time;
+                                    # the upload adds the draw_pos operand
+                                    # that maps rows back to draw order.
+                                    mh_plan = idx_np
+                                    (
+                                        (sx, sy, sm, ssz, sidx, sdpos),
+                                        stream_rec, mh_plan,
+                                    ) = streamer.acquire_plan(mh_plan)
+                                    mh_kw = {"draw_pos": sdpos}
+                                else:
+                                    (sx, sy, sm, ssz, sidx), stream_rec = (
+                                        streamer.acquire([idx_np])
+                                    )
+                                    mh_kw = {}
+                                state_k = None
+                                if store.state is not None:
+                                    if mh:
+                                        # Owner-assembled block state (own
+                                        # rows local, spill rows exchanged),
+                                        # placed straight into the
+                                        # client-axis layout.
+                                        state_k = streamer.gather_state_device(
+                                            mh_plan
+                                        )
+                                    else:
+                                        # Donated operand: owned buffers,
+                                        # not a zero-copy view of the numpy
+                                        # gather.
+                                        state_k = _owned_device_tree(
+                                            algorithm.gather_client_state(
+                                                store, idx_np
+                                            )
+                                        )
+                                        if mesh is not None:
+                                            # Cohort state joins the cohort
+                                            # slice's client-axis layout.
+                                            state_k = shard_client_data(
+                                                state_k, mesh
+                                            )
+                                dyn_kw = (
+                                    {"departed": jnp.asarray(dep_mask)}
+                                    if pop is not None else {}
+                                )
+                                with tracer.span(
+                                        "dispatch", "phase", round_idx=round_idx,
+                                        phase="client_step") as _ph:
+                                    new_global, new_state_k, aux = round_jit(
+                                        global_params, state_k, sx, sy, sm,
+                                        ssz, sidx, round_key,
+                                        *lr_args, **async_kw, **dyn_kw,
+                                        **mh_kw,
+                                    )
+                                    # Prefetch the next round's cohort while
+                                    # this dispatch computes (the upload runs
+                                    # on the streamer's worker thread). The
+                                    # draw deliberately overlaps device
+                                    # compute; its host cost is carved out
+                                    # of this client_step window into the
+                                    # `sample` phase so the ~1 s exact
+                                    # replay at N=1e6 stays visible.
+                                    # Dynamic populations draw synchronously
+                                    # instead: the next cohort depends on
+                                    # this round's registration events
+                                    # (applied below), and the O(cohort)
+                                    # hashed draw is microseconds.
+                                    if pop is None and (
+                                        round_idx + 1 < config.round
+                                    ) and not preempt["flag"]:
+                                        _, _nxt_rk = jax.random.split(key)
+                                        if mh:
+                                            # Plan (incl. the collective
+                                            # spill exchange) on the MAIN
+                                            # thread at the same loop point
+                                            # on every host — collective
+                                            # launch order stays identical
+                                            # across processes; only the
+                                            # device_put assembly rides the
+                                            # worker thread.
                                             _t_s = clock.monotonic()
-                                            idx2, hk2 = _stream_plan(hk_after, k2)
+                                            stream_next_idx = streamer.plan(
+                                                streamer.cohort_for(_nxt_rk)
+                                            )
                                             tracer.phases.carve(
-                                                last_idx, "sample",
+                                                round_idx, "sample",
                                                 clock.monotonic() - _t_s,
                                                 "client_step",
                                             )
-                                            stream_next = (nxt, idx2, hk2)
-                                            streamer.prefetch(idx2, stack=True)
-                                        _ph.fence((global_params, metrics_k))
-                                else:
-                                    if (
-                                        stream_full
-                                        and startup_stream["rec"] is not None
-                                    ):
-                                        # The one-shot population upload lands on
-                                        # the first dispatch's record.
-                                        stream_rec = startup_stream["rec"]
-                                        startup_stream["rec"] = None
-                                        if k > 1:
-                                            stream_rec["dispatch_rounds"] = k
-                                    with tracer.span(
-                                            "dispatch", "phase", round_idx=last_idx,
-                                            phase="client_step") as _ph:
-                                        out = dispatch(
-                                            global_params, client_state,
-                                            server_state, key, cx, cy, cmask,
-                                            sizes, eval_batches,
-                                            *lr_args, **async_kw,
-                                        )
-                                        if async_ctl is not None:
-                                            (
-                                                global_params, client_state,
-                                                server_state, key, metrics_k,
-                                                aux_k, async_state,
-                                            ) = out
-                                        else:
-                                            (
-                                                global_params, client_state,
-                                                server_state, key, metrics_k,
-                                                aux_k,
-                                            ) = out
-                                        _ph.fence((global_params, metrics_k))
-                            if recompile is not None:
-                                recompile.attribute(last_idx)
-                            mean_loss_k = aux_k.get("mean_client_loss")
-                            if mean_loss_k is None:
-                                mean_loss_k = np.full(k, np.nan)
-                            flush_dispatch({
-                                "round_start": round_idx,
-                                "k": k,
-                                "metrics": metrics_k,
-                                "mean_loss": mean_loss_k,
-                                "aux": aux_k,
-                                "new_global": global_params,
-                                "prev_global": prev_global,
-                                "client_state": client_state,
-                                "server_state": server_state,
-                                "async_state": async_state,
-                                "key": key,
-                                "stream": stream_rec,
-                            })
-                        completed_round = last_idx
-                        round_idx = last_idx + 1
-                        if preempt["flag"]:
-                            # Finish-in-flight at batch granularity: the
-                            # dispatched rounds completed and were recorded;
-                            # no new dispatch is launched.
-                            break
-                else:
-                    # Next round's host-replayed cohort (stream_sampled): the
-                    # prefetched upload this index list describes is already
-                    # in flight when the round that uses it starts.
-                    stream_next_idx = None
-                    for round_idx in range(start_round, config.round):
-                        if (
-                            config.profile_dir
-                            and profile_from is not None
-                            and round_idx >= profile_from
-                        ):
-                            # Deferred trace start (config.profile_from_round):
-                            # keeps round 0's XLA compile and its host events
-                            # out of the trace, so the captured window is
-                            # steady-state rounds only
-                            # (scripts/profile_sign_round.py's method). Earlier
-                            # rounds were dispatched asynchronously: wait for
-                            # the device to finish them, or their tail lands
-                            # inside the window (v5e, PR 21: a "one-round"
-                            # flagship trace held 3.6 s of device ops).
-                            jax.block_until_ready(
-                                (global_params, pending and pending["metrics_dev"])
-                            )
-                            profile_stack.enter_context(
-                                profile_session(config.profile_dir)
-                            )
-                            profile_from = None
-                        # One span per loop iteration: the iteration of round
-                        # r dispatches r and, pipelined, finalizes r-1 (whose
-                        # spans carry round r-1 under this parent).
-                        with tracer.span("round", "iter", round_idx=round_idx):
-                            key, round_key = jax.random.split(key)
-                            if span_recorder is not None and streamer is not None:
-                                # Skew/occupancy spans emitted inside the
-                                # streamer (spill exchange, prefetch worker)
-                                # attribute to the round being dispatched.
-                                streamer.span_round = round_idx
-                            with _oom_hint(config, global_params, n_clients):
-                                # The schedule factor is a traced operand only when a
-                                # schedule is active; the constant default uses the
-                                # round_fn's Python default 1.0, which constant-folds
-                                # at trace time (no per-step scale multiply in the
-                                # compiled program). lr_factors is the one
-                                # formula shared with the batched dispatch's
-                                # operand vector.
-                                lr_args = () if config.lr_schedule.lower() == (
-                                    "constant"
-                                ) else (
-                                    jnp.float32(lr_factors(config, round_idx, 1)[0]),
-                                )
-                                async_kw = (
-                                    {"async_state": async_state}
-                                    if async_ctl is not None else {}
-                                )
-                                stream_rec = None
-                                pop_rec = None
-                                mh_rec = None
-                                mh_plan = None
-                                if stream_sampled:
-                                    # Streamed dispatch: cohort slices arrive as
-                                    # pre-gathered operands (prefetched while the
-                                    # previous round computed); persistent state
-                                    # gathers from the host store (post the
-                                    # previous round's writeback) and scatters
-                                    # back after this dispatch.
-                                    pop_events = pop_words = dep_mask = None
-                                    if pop is not None:
-                                        # Dynamic population: the cohort is
-                                        # drawn from the PRE-event registered
-                                        # index space (departed masked out of
-                                        # the hashed stream); this round's
-                                        # events come from the fold_in-decoupled
-                                        # registration stream and APPLY after
-                                        # the dispatch — a joiner is sampleable
-                                        # from the next round, a departure that
-                                        # hits this cohort rides the departed
-                                        # operand. Drift levels advance before
-                                        # the gather so sampled drifting
-                                        # clients train on this round's labels.
-                                        pop_words = pop_key_words(
-                                            round_key, pop.seed
-                                        )
-                                        with tracer.span(
-                                                "sample", "phase",
-                                                round_idx=round_idx, phase="sample"):
-                                            idx_np = streamer.cohort_for(
-                                                round_key,
-                                                n=pop.n_registered,
-                                                alive=pop.alive,
-                                                k=cohort_n,
-                                            )
-                                        pop_events = pop.draw_events(
-                                            pop_words, round_idx
-                                        )
-                                        dep_mask = pop.cohort_departed_mask(
-                                            pop_events, idx_np
-                                        )
-                                        pop.apply_drift(store, round_idx, idx_np)
-                                    elif stream_next_idx is not None:
-                                        idx_np = stream_next_idx
-                                    else:
-                                        # First round / resume: the draw is not
-                                        # hidden behind a prior dispatch — its
-                                        # own `sample` phase window (under the
-                                        # distributed store this window also
-                                        # covers the owner assembly + spill
-                                        # exchange).
-                                        with tracer.span(
-                                                "sample", "phase",
-                                                round_idx=round_idx, phase="sample"):
-                                            idx_np = streamer.cohort_for(
-                                                round_key
-                                            )
-                                            if mh:
-                                                idx_np = streamer.plan(idx_np)
-                                    stream_next_idx = None
-                                    if mh:
-                                        # Owner-sharded assembly: this host's
-                                        # block rows, with ownership-imbalance
-                                        # spill already exchanged at plan time;
-                                        # the upload adds the draw_pos operand
-                                        # that maps rows back to draw order.
-                                        mh_plan = idx_np
-                                        (
-                                            (sx, sy, sm, ssz, sidx, sdpos),
-                                            stream_rec, mh_plan,
-                                        ) = streamer.acquire_plan(mh_plan)
-                                        mh_kw = {"draw_pos": sdpos}
-                                    else:
-                                        (sx, sy, sm, ssz, sidx), stream_rec = (
-                                            streamer.acquire([idx_np])
-                                        )
-                                        mh_kw = {}
-                                    state_k = None
-                                    if store.state is not None:
-                                        if mh:
-                                            # Owner-assembled block state (own
-                                            # rows local, spill rows exchanged),
-                                            # placed straight into the
-                                            # client-axis layout.
-                                            state_k = streamer.gather_state_device(
-                                                mh_plan
+                                            streamer.prefetch_plan(
+                                                stream_next_idx
                                             )
                                         else:
-                                            # Donated operand: owned buffers,
-                                            # not a zero-copy view of the numpy
-                                            # gather.
-                                            state_k = _owned_device_tree(
-                                                algorithm.gather_client_state(
-                                                    store, idx_np
-                                                )
+                                            stream_next_idx = (
+                                                streamer.cohort_for(_nxt_rk)
                                             )
-                                            if mesh is not None:
-                                                # Cohort state joins the cohort
-                                                # slice's client-axis layout.
-                                                state_k = shard_client_data(
-                                                    state_k, mesh
-                                                )
-                                    dyn_kw = (
-                                        {"departed": jnp.asarray(dep_mask)}
-                                        if pop is not None else {}
+                                            tracer.phases.carve(
+                                                round_idx, "sample",
+                                                streamer.last_sample_seconds,
+                                                "client_step",
+                                            )
+                                            streamer.prefetch(
+                                                [stream_next_idx]
+                                            )
+                                    _ph.fence((new_global, aux))
+                                # Host store is the source of truth between
+                                # dispatches: checkpoint/resume read it.
+                                streamer.writeback(
+                                    mh_plan if mh else idx_np, new_state_k,
+                                    stream_rec,
+                                )
+                                if mh:
+                                    mh_rec = streamer.multihost_record(
+                                        mh_plan, stream_rec or {}
                                     )
-                                    with tracer.span(
-                                            "dispatch", "phase", round_idx=round_idx,
-                                            phase="client_step") as _ph:
-                                        new_global, new_state_k, aux = round_jit(
-                                            global_params, state_k, sx, sy, sm,
-                                            ssz, sidx, round_key,
-                                            *lr_args, **async_kw, **dyn_kw,
-                                            **mh_kw,
-                                        )
-                                        # Prefetch the next round's cohort while
-                                        # this dispatch computes (the upload runs
-                                        # on the streamer's worker thread). The
-                                        # draw deliberately overlaps device
-                                        # compute; its host cost is carved out
-                                        # of this client_step window into the
-                                        # `sample` phase so the ~1 s exact
-                                        # replay at N=1e6 stays visible.
-                                        # Dynamic populations draw synchronously
-                                        # instead: the next cohort depends on
-                                        # this round's registration events
-                                        # (applied below), and the O(cohort)
-                                        # hashed draw is microseconds.
-                                        if pop is None and (
-                                            round_idx + 1 < config.round
-                                        ) and not preempt["flag"]:
-                                            _, _nxt_rk = jax.random.split(key)
-                                            if mh:
-                                                # Plan (incl. the collective
-                                                # spill exchange) on the MAIN
-                                                # thread at the same loop point
-                                                # on every host — collective
-                                                # launch order stays identical
-                                                # across processes; only the
-                                                # device_put assembly rides the
-                                                # worker thread.
-                                                _t_s = clock.monotonic()
-                                                stream_next_idx = streamer.plan(
-                                                    streamer.cohort_for(_nxt_rk)
-                                                )
-                                                tracer.phases.carve(
-                                                    round_idx, "sample",
-                                                    clock.monotonic() - _t_s,
-                                                    "client_step",
-                                                )
-                                                streamer.prefetch_plan(
-                                                    stream_next_idx
-                                                )
-                                            else:
-                                                stream_next_idx = (
-                                                    streamer.cohort_for(_nxt_rk)
-                                                )
-                                                tracer.phases.carve(
-                                                    round_idx, "sample",
-                                                    streamer.last_sample_seconds,
-                                                    "client_step",
-                                                )
-                                                streamer.prefetch(
-                                                    [stream_next_idx]
-                                                )
-                                        _ph.fence((new_global, aux))
-                                    # Host store is the source of truth between
-                                    # dispatches: checkpoint/resume read it.
-                                    streamer.writeback(
-                                        mh_plan if mh else idx_np, new_state_k,
-                                        stream_rec,
+                                if pop is not None:
+                                    # Registration events apply at the round
+                                    # boundary, after the writeback and
+                                    # before this round's checkpoint: the
+                                    # persisted state is exactly what the
+                                    # next round's draw sees.
+                                    pop.apply(
+                                        pop_events, store,
+                                        state_proto=pop_state_proto,
+                                        words=pop_words,
                                     )
-                                    if mh:
-                                        mh_rec = streamer.multihost_record(
-                                            mh_plan, stream_rec or {}
-                                        )
-                                    if pop is not None:
-                                        # Registration events apply at the round
-                                        # boundary, after the writeback and
-                                        # before this round's checkpoint: the
-                                        # persisted state is exactly what the
-                                        # next round's draw sees.
-                                        pop.apply(
-                                            pop_events, store,
-                                            state_proto=pop_state_proto,
-                                            words=pop_words,
-                                        )
-                                        pop_rec = pop.round_record(
-                                            pop_events,
-                                            int(np.count_nonzero(dep_mask)),
-                                        )
-                                else:
-                                    if (
-                                        stream_full
-                                        and startup_stream["rec"] is not None
-                                    ):
-                                        # One-shot population upload: recorded on
-                                        # the first round's record.
-                                        stream_rec = startup_stream["rec"]
-                                        startup_stream["rec"] = None
-                                    if mh:
-                                        # Full-cohort distributed upload: shard
-                                        # provenance on every round's record
-                                        # (spill is structurally zero — owner
-                                        # bounds ARE the device blocks).
-                                        mh_rec = streamer.multihost_record(
-                                            None, stream_rec or {}
-                                        )
-                                    with tracer.span(
-                                            "dispatch", "phase", round_idx=round_idx,
-                                            phase="client_step") as _ph:
-                                        new_global, client_state, aux = round_jit(
-                                            global_params, client_state, cx, cy,
-                                            cmask, sizes,
-                                            round_key, *lr_args, **async_kw,
-                                        )
-                                        _ph.fence((new_global, aux))
-                                if async_ctl is not None:
-                                    # Pop the buffer carry before any record/aux
-                                    # consumer sees it; it becomes the next
-                                    # round's async_state operand.
-                                    aux = dict(aux)
-                                    async_state = aux.pop("async_state")
-                                if server_update_jit is not None:
-                                    # When the round program carries a quorum verdict,
-                                    # the server optimizer must see it: a rejected
-                                    # round freezes the optimizer state and leaves the
-                                    # params untouched (momentum alone would otherwise
-                                    # move the "retained" model).
-                                    srv_args = (global_params, new_global, server_state)
-                                    if "round_rejected" in aux:
-                                        srv_args += (aux["round_rejected"],)
-                                    with tracer.span(
-                                            "aggregate", "phase", round_idx=round_idx,
-                                            phase="aggregate") as _ph:
-                                        new_global, server_state = server_update_jit(
-                                            *srv_args
-                                        )
-                                        _ph.fence(new_global)
-                            with tracer.span(
-                                "eval_dispatch", "phase", round_idx=round_idx,
-                                phase="eval",
-                            ) as _ph, _oom_hint(
-                                config, new_global, n_clients, site="eval"
-                            ):
-                                metrics_dev = evaluate(new_global, *eval_batches)
-                                _ph.fence(metrics_dev)
-                            if recompile is not None:
-                                # Compiles are synchronous with trace/lower, so events
-                                # pending here came from this round's dispatches
-                                # (under pipelining, the deferred finalize of round
-                                # r-1 runs after this and must not absorb them).
-                                recompile.attribute(round_idx)
-                            entry = {
-                                "round_idx": round_idx,
-                                "round_key": round_key,
-                                "new_global": new_global,
-                                # A donated global is gone: its buffer
-                                # holds new_global.
-                                "prev_global": (
-                                    None if donate_global else global_params
-                                ),
-                                # Sampled streamed: the (post-writeback) host
-                                # store is what a checkpoint must persist.
-                                "client_state": (
-                                    store.state if stream_sampled
-                                    else None if pipelined else client_state
-                                ),
-                                "aux": aux,
-                                "metrics_dev": metrics_dev,
-                                "mean_loss_dev": aux.get("mean_client_loss", np.nan),
-                                "key": key,
-                                "server_state": server_state,
-                                "async_state": async_state,
-                                "stream": stream_rec,
-                                "population": pop_rec,
-                                "multihost": mh_rec,
-                                # Draw-order cohort for the record's cohort_hash
-                                # (the device operand is owner-permuted under
-                                # the distributed layout).
-                                "participants_host": (
-                                    mh_plan.idx if mh_plan is not None else None
-                                ),
-                            }
-                            global_params = new_global
-                            if pipelined:
-                                # Take ownership of `entry` before finalizing the prior
-                                # round: if that finalize raises, the finally block still
-                                # records this round (the raising round is what's lost).
-                                prev_pending, pending = pending, entry
-                                if prev_pending is not None:
-                                    finalize(prev_pending)
+                                    pop_rec = pop.round_record(
+                                        pop_events,
+                                        int(np.count_nonzero(dep_mask)),
+                                    )
                             else:
-                                finalize(entry)
-                        completed_round = round_idx
-                        if preempt["flag"]:
-                            # Finish-in-flight semantics: this round completed (and
-                            # with pipelining its deferred finalize runs in the
-                            # crash-flush below); no new round is dispatched.
-                            break
+                                if (
+                                    stream_full
+                                    and startup_stream["rec"] is not None
+                                ):
+                                    # One-shot population upload: recorded on
+                                    # the first round's record.
+                                    stream_rec = startup_stream["rec"]
+                                    startup_stream["rec"] = None
+                                if mh:
+                                    # Full-cohort distributed upload: shard
+                                    # provenance on every round's record
+                                    # (spill is structurally zero — owner
+                                    # bounds ARE the device blocks).
+                                    mh_rec = streamer.multihost_record(
+                                        None, stream_rec or {}
+                                    )
+                                with tracer.span(
+                                        "dispatch", "phase", round_idx=round_idx,
+                                        phase="client_step") as _ph:
+                                    new_global, client_state, aux = round_jit(
+                                        global_params, client_state, cx, cy,
+                                        cmask, sizes,
+                                        round_key, *lr_args, **async_kw,
+                                    )
+                                    _ph.fence((new_global, aux))
+                            if async_ctl is not None:
+                                # Pop the buffer carry before any record/aux
+                                # consumer sees it; it becomes the next
+                                # round's async_state operand.
+                                aux = dict(aux)
+                                async_state = aux.pop("async_state")
+                            if server_update_jit is not None:
+                                # When the round program carries a quorum verdict,
+                                # the server optimizer must see it: a rejected
+                                # round freezes the optimizer state and leaves the
+                                # params untouched (momentum alone would otherwise
+                                # move the "retained" model).
+                                srv_args = (global_params, new_global, server_state)
+                                if "round_rejected" in aux:
+                                    srv_args += (aux["round_rejected"],)
+                                with tracer.span(
+                                        "aggregate", "phase", round_idx=round_idx,
+                                        phase="aggregate") as _ph:
+                                    new_global, server_state = server_update_jit(
+                                        *srv_args
+                                    )
+                                    _ph.fence(new_global)
+                        with tracer.span(
+                            "eval_dispatch", "phase", round_idx=round_idx,
+                            phase="eval",
+                        ) as _ph, _oom_hint(
+                            config, new_global, n_clients, site="eval"
+                        ):
+                            metrics_dev = evaluate(new_global, *eval_batches)
+                            _ph.fence(metrics_dev)
+                        if recompile is not None:
+                            # Compiles are synchronous with trace/lower, so events
+                            # pending here came from this round's dispatches
+                            # (under pipelining, the deferred finalize of round
+                            # r-1 runs after this and must not absorb them).
+                            recompile.attribute(round_idx)
+                        entry = {
+                            "round_idx": round_idx,
+                            "round_key": round_key,
+                            "new_global": new_global,
+                            # A donated global is gone: its buffer
+                            # holds new_global.
+                            "prev_global": (
+                                None if donate_global else global_params
+                            ),
+                            # Sampled streamed: the (post-writeback) host
+                            # store is what a checkpoint must persist.
+                            "client_state": (
+                                store.state if stream_sampled
+                                else None if pipelined else client_state
+                            ),
+                            "aux": aux,
+                            "metrics_dev": metrics_dev,
+                            "mean_loss_dev": aux.get("mean_client_loss", np.nan),
+                            "key": key,
+                            "server_state": server_state,
+                            "async_state": async_state,
+                            "stream": stream_rec,
+                            "population": pop_rec,
+                            "multihost": mh_rec,
+                            # Draw-order cohort for the record's cohort_hash
+                            # (the device operand is owner-permuted under
+                            # the distributed layout).
+                            "participants_host": (
+                                mh_plan.idx if mh_plan is not None else None
+                            ),
+                        }
+                        global_params = new_global
+                        if pipelined:
+                            # Take ownership of `entry` before finalizing the prior
+                            # round: if that finalize raises, the finally block still
+                            # records this round (the raising round is what's lost).
+                            prev_pending, pending = pending, entry
+                            if prev_pending is not None:
+                                finalize(prev_pending)
+                        else:
+                            finalize(entry)
+                    completed_round = round_idx
+                    if preempt["flag"]:
+                        # Finish-in-flight semantics: this round completed (and
+                        # with pipelining its deferred finalize runs in the
+                        # crash-flush below); no new round is dispatched.
+                        break
             except BaseException as crash_exc:
                 # Flight recorder (telemetry/spans.py): an unhandled crash
                 # force-flushes the last-K spans plus every still-open span

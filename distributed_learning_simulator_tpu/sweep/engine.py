@@ -7,7 +7,7 @@ front door (strategy selection + refusals live there):
   except the fleet axes (seed, learning_rate) stack on a new leading
   experiment axis: per-point model inits and RNG key chains become
   ``[E, ...]`` operands, per-point learning rates a length-E f32 factor
-  vector (the PR 5 ``lr_factors`` precedent), and ONE jitted program
+  vector, and ONE jitted program
   (``parallel/engine.make_experiment_round_fn``) trains every
   experiment per dispatch. Point ``i``'s metric history is bit-identical
   to a solo ``run_simulation`` with that seed on the shared data
@@ -241,7 +241,6 @@ def lean_supported(cfg) -> bool:
         and (cfg.mesh_devices or 1) <= 1
         and cfg.client_residency.lower() == "resident"
         and getattr(cfg, "population", "static").lower() == "static"
-        and cfg.rounds_per_dispatch == 1
         and cfg.async_mode.lower() == "off"
         and cfg.client_stats.lower() == "off"
         and cfg.client_valuation.lower() == "off"
@@ -290,7 +289,6 @@ class SweepScheduler:
     is a pure operand (model init + RNG chain), so seed-varied config
     hashes share one compiled program. Reusable OUTSIDE run_sweep too:
     bench.py routes its repeated same-program legs through one scheduler
-    so the headline's warm program serves the round_batch K=1 leg
     (warmup paid once, recorded — the ISSUE 11 small fix), and
     scripts/measure_scaling.py gets explicit per-point warmup
     accounting the silent ``history[1:]`` slice used to hide.
@@ -386,8 +384,8 @@ def _run_point_lean(prog: _Program, cfg) -> dict:
     telemetry, streaming, ...) is gated out by ``lean_supported``.
     """
     from distributed_learning_simulator_tpu.simulator import (
+        _lr_factor,
         _oom_hint,
-        lr_factors,
     )
 
     if cfg.client_chunk_size == 0:
@@ -461,7 +459,7 @@ def _run_point_lean(prog: _Program, cfg) -> dict:
         for round_idx in range(cfg.round):
             key, round_key = jax.random.split(key)
             lr_args = (
-                (jnp.float32(lr_factors(cfg, round_idx, 1)[0]),)
+                (jnp.float32(_lr_factor(cfg, round_idx)),)
                 if lr_active else ()
             )
             with _oom_hint(cfg, global_params, prog.n_clients):
@@ -541,10 +539,10 @@ def _run_fleet(spec: SweepSpec, points, dataset, client_data,
         lambda *xs: jnp.stack(xs), *params_list
     )
     keys_E = jnp.stack([_seed_key(s) for s in seeds])
-    # Per-point lr factors against the program's baked base lr (PR 5
-    # lr_factors precedent): exact 1.0 for a pure seed fleet, so the
-    # operand multiply is bit-exact there; an lr-varied point's factor
-    # semantics match config.lr_schedule's outer multiplier.
+    # Per-point lr factors against the program's baked base lr: exact 1.0
+    # for a pure seed fleet, so the operand multiply is bit-exact there; an
+    # lr-varied point's factor semantics match config.lr_schedule's outer
+    # multiplier.
     lr_mults = np.asarray(
         [p.config.learning_rate / fcfg.learning_rate for p in points],
         dtype=np.float32,
@@ -574,7 +572,7 @@ def _run_fleet(spec: SweepSpec, points, dataset, client_data,
             "sweep fleet: %d experiments packed over %d mesh devices",
             E, cfg.mesh_devices,
         )
-    from distributed_learning_simulator_tpu.simulator import lr_factors
+    from distributed_learning_simulator_tpu.simulator import _lr_factor
 
     histories: list[list[dict]] = [[] for _ in points]
     telemetry = [
@@ -585,8 +583,9 @@ def _run_fleet(spec: SweepSpec, points, dataset, client_data,
     for round_idx in range(cfg.round):
         lr_args = ()
         if lr_active:
-            factor = lr_factors(cfg, round_idx, 1)[0]
-            lr_vec = jnp.asarray(lr_mults * np.float32(factor))
+            lr_vec = jnp.asarray(
+                lr_mults * np.float32(_lr_factor(cfg, round_idx))
+            )
             if mesh is not None:
                 lr_vec = shard_client_data(lr_vec, mesh)
             lr_args = (lr_vec,)

@@ -12,9 +12,8 @@ resolves HOW the fleet executes (sweep/engine.py):
   leading experiment axis and run as ONE jitted program: per-point seeds
   become stacked model inits + per-experiment RNG key chains (point ``i``
   is bit-identical to a solo run with that seed on the shared data), and
-  per-point learning rates become a length-E f32 operand vector riding
-  the PR 5 ``lr_factors`` precedent. Compile is paid once for the whole
-  fleet.
+  per-point learning rates become a length-E f32 operand vector.
+  Compile is paid once for the whole fleet.
 * ``scheduled`` — heterogeneous points are grouped by
   ``utils/reporting.config_hash`` (the program-defining-knob identity)
   and each group runs sequentially through one warm program; programs
@@ -205,17 +204,6 @@ class SweepSpec:
                     "shared warm program can serve it; run Shapley "
                     "configs as solo runs"
                 )
-            if (
-                cfg.client_residency.lower() == "streamed"
-                and cfg.rounds_per_dispatch > 1
-            ):
-                raise ValueError(
-                    "client_residency='streamed' with rounds_per_dispatch"
-                    " > 1 does not compose with sweeps: the scheduler "
-                    "cannot host-replay K stacked cohort plans across "
-                    "points sharing one streamer; set "
-                    "rounds_per_dispatch=1 or client_residency='resident'"
-                )
             if cfg.multihost:
                 raise ValueError(
                     "sweeps do not compose with multihost: every process "
@@ -318,11 +306,6 @@ class SweepSpec:
                 "client_residency='streamed' pins the cohort pipeline to "
                 "one host store/streamer pair; the fleet runs resident "
                 "data shared across experiments"
-            )
-        if cfg.rounds_per_dispatch > 1:
-            return False, (
-                "rounds_per_dispatch > 1 fuses the host round loop into "
-                "a scan per run; the fleet owns its own round loop"
             )
         if cfg.server_optimizer_name.lower() not in ("none", ""):
             return False, (

@@ -30,15 +30,11 @@ with client_stats='on'), so an overhead above
 ``--stats-overhead-threshold`` is a regression regardless of the old
 record — the feature's promise is "cheap enough to leave on". The
 ratio is judged ABSOLUTELY, never as a tracked relative metric: it
-hovers near zero, where relative changes are pure noise. The
-``round_batch`` leg's ``amortization_ratio`` (rounds_per_dispatch
-K-vs-1 rate ratio, measured within the run) gets the same treatment:
-``--batch-amortization-threshold`` is an absolute floor — it hovers
-near 1.0, where a relative gate would flap. So does the ``async``
+hovers near zero, where relative changes are pure noise. The ``async``
 leg's ``async_speedup_ratio`` (simulated-clock speedup of deadline
-rounds over the sync counterfactual): ``--async-speedup-threshold``
-is an absolute floor, default 1.0. And the ``stream`` leg's prefetch
-``overlap_ratio`` (fraction of host->HBM upload time hidden behind
+rounds over the sync counterfactual) gets the same treatment:
+``--async-speedup-threshold`` is an absolute floor, default 1.0. And
+the ``stream`` leg's prefetch ``overlap_ratio`` (fraction of host->HBM upload time hidden behind
 compute at the largest swept population, client_residency='streamed'):
 ``--stream-overlap-threshold`` is an absolute floor, default 0.5 —
 and the same leg's ``cohort_rate`` (steady cohort·rounds/s at that
@@ -207,28 +203,6 @@ def overhead_gate(record: dict, threshold: float) -> dict | None:
         ),
         "old": threshold, "new": ratio,
         "relative_change": None, "direction": "lower",
-    }
-
-
-def batch_amortization_gate(record: dict, threshold: float) -> dict | None:
-    """In-record round-batching gate: bench.py's ``round_batch`` leg
-    measures the K-vs-1 rate ratio of ``rounds_per_dispatch`` within one
-    run, so a ratio below ``threshold`` means batching stopped paying for
-    itself — a regression regardless of the old record. Judged
-    ABSOLUTELY (like the client-stats overhead): the ratio hovers near
-    1.0, where a relative-change gate would flap. None when the leg is
-    absent or the ratio holds."""
-    ratio = get_path(record, "round_batch.amortization_ratio")
-    if ratio is None or ratio >= threshold:
-        return None
-    return {
-        "metric": "round_batch.amortization_ratio",
-        "description": (
-            "rounds_per_dispatch=K vs K=1 rate ratio from the same "
-            "bench run (>= 1.0 means batching pays)"
-        ),
-        "old": threshold, "new": ratio,
-        "relative_change": None, "direction": "higher",
     }
 
 
@@ -532,12 +506,6 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--stats-overhead-threshold", type=float, default=0.10,
                     help="max tolerated client_stats=on round-time overhead "
                          "ratio in the NEW record (default 0.10)")
-    ap.add_argument("--batch-amortization-threshold", type=float,
-                    default=0.95,
-                    help="min tolerated rounds_per_dispatch K-vs-1 rate "
-                         "ratio in the NEW record's round_batch leg "
-                         "(default 0.95 — batching must at least break "
-                         "even, modulo run noise)")
     ap.add_argument("--async-speedup-threshold", type=float, default=1.0,
                     help="min tolerated simulated-clock speedup in the "
                          "NEW record's async leg (default 1.0 — deadline "
@@ -628,7 +596,6 @@ def main(argv: list[str] | None = None) -> int:
     result = compare_records(old, new, threshold=args.threshold)
     for gate in (
         overhead_gate(new, args.stats_overhead_threshold),
-        batch_amortization_gate(new, args.batch_amortization_threshold),
         async_speedup_gate(new, args.async_speedup_threshold),
         stream_overlap_gate(new, args.stream_overlap_threshold),
         stream_cohort_rate_gate(new, args.stream_cohort_rate_threshold),

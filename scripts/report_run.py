@@ -477,23 +477,11 @@ def summarize_run(records: list[dict], trace_stats: dict | None = None,
     tels = [(r.get("round"), r["telemetry"]) for r in records
             if isinstance(r.get("telemetry"), dict)]
     if tels:
-        # Batched dispatches (rounds_per_dispatch > 1) write ONE
-        # telemetry sub-object per dispatch, on the dispatch's last
-        # record, with ``dispatch_rounds`` saying how many rounds its
-        # phase times cover — so summing over telemetry-carrying records
-        # never double-counts, and the per-unit mean is labeled honestly
-        # (per dispatch, not per round).
-        batched_tel = any(
-            tel.get("dispatch_rounds", 1) > 1 for _, tel in tels
-        )
         phase_tot: dict[str, float] = {}
         per_round_phases = []
         for rnd, tel in tels:
             phases = tel.get("phase_seconds") or {}
-            entry = {"round": rnd, **phases}
-            if tel.get("dispatch_rounds", 1) > 1:
-                entry["dispatch_rounds"] = tel["dispatch_rounds"]
-            per_round_phases.append(entry)
+            per_round_phases.append({"round": rnd, **phases})
             for name, secs_ in phases.items():
                 phase_tot[name] = phase_tot.get(name, 0.0) + secs_
         grand = sum(phase_tot.values()) or 1.0
@@ -507,7 +495,6 @@ def summarize_run(records: list[dict], trace_stats: dict | None = None,
                 phase_tot.items(), key=lambda kv: -kv[1]
             )
         }
-        summary["phase_unit"] = "dispatch" if batched_tel else "round"
         summary["phase_seconds_per_round"] = per_round_phases
 
         # Only when the records actually carry per-round compile counts
@@ -515,18 +502,12 @@ def summarize_run(records: list[dict], trace_stats: dict | None = None,
         # run-scoped in the result dict): a missing key must not render
         # as a fabricated "0 compiles, shape-stable" verdict.
         if any("compiles" in tel for _, tel in tels):
-            # Warmup = the first telemetry-carrying record (a batched
-            # run's first dispatch records at its LAST round, not round
-            # 0) or any record the simulator stamped ``warmup: true``
-            # (the first dispatch of a new length legitimately compiles
-            # its own scan program).
+            # Warmup = the first telemetry-carrying record.
             warmup_round = tels[0][0]
             compile_rounds = [
                 {"round": rnd, "compiles": tel.get("compiles", 0),
                  "compiled": tel.get("compiled", []),
-                 "warmup": bool(
-                     tel.get("warmup") or rnd == warmup_round
-                 )}
+                 "warmup": rnd == warmup_round}
                 for rnd, tel in tels if tel.get("compiles")
             ]
             summary["compiles"] = {
@@ -716,9 +697,8 @@ def render_summary(summary: dict) -> list[str]:
         lines.append("rejected rounds (quorum): 0")
 
     if "phases" in summary:
-        unit = summary.get("phase_unit", "round")
         lines.append(
-            f"phase breakdown (per-{unit} mean, share of phased time):"
+            "phase breakdown (per-round mean, share of phased time):"
         )
         for name, st in summary["phases"].items():
             bar = "#" * max(1, int(st["share"] * 40))

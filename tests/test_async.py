@@ -10,8 +10,6 @@ The determinism contracts this file pins:
   contract).
 * The staleness discount and the buffer insert/trigger/apply math match
   a hand-computed 3-client trace.
-* ``rounds_per_dispatch`` carries the buffer state as a scan carry:
-  K>1 history equals K=1 bit-for-bit.
 * Checkpoint/resume replays the buffer bit-exactly; config/checkpoint
   async mismatches are refused with the cause.
 * sign_SGD, the Shapley servers, and the threaded oracle refuse
@@ -95,7 +93,7 @@ def test_off_mode_constructs_nothing():
 
 def test_refusals(tiny_config):
     """sign_SGD, Shapley, and the threaded oracle refuse with the flag
-    named — same style as supports_round_batching."""
+    named — same style as supports_lr_schedule."""
     with pytest.raises(ValueError, match="async_mode"):
         _run(tiny_config, distributed_algorithm="sign_SGD",
              learning_rate=0.01, **_ASYNC_ON)
@@ -358,22 +356,6 @@ def test_straggler_fault_routes_into_buffer(tiny_config):
 
 
 # ------------------------------------------------- composition contracts
-
-
-def test_k2_matches_k1_with_faults_and_sampling(tiny_config):
-    """rounds_per_dispatch carries the buffer as the scan carry: K=2
-    (dispatch sizes 2 then 1) reproduces the K=1 async history
-    bit-for-bit under sampling + dropout faults + quorum."""
-    cfg = dataclasses.replace(
-        tiny_config, worker_number=8, round=3,
-        participation_fraction=0.5, failure_mode="dropout",
-        failure_prob=0.3, min_survivors=1, **_ASYNC_ON,
-    )
-    keys = ("test_accuracy", "test_loss", "mean_client_loss",
-            "survivor_count", "round_rejected", "cohort_hash", "async")
-    assert _series(_run(cfg), *keys) == _series(
-        _run(cfg, rounds_per_dispatch=2), *keys
-    )
 
 
 def test_checkpoint_resume_replays_buffer(tiny_config, tmp_path):

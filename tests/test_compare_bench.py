@@ -132,47 +132,6 @@ def test_client_stats_overhead_self_gate(cb, tmp_path):
     assert proc.returncode == 0
 
 
-def test_round_batch_amortization_not_relatively_tracked(cb):
-    """The K-vs-1 amortization ratio hovers near 1.0 — like the
-    client-stats overhead it must never be a relative TRACKED metric;
-    only the absolute in-record floor judges it."""
-    old = _record(round_batch={"amortization_ratio": 1.08})
-    new = _record(round_batch={"amortization_ratio": 1.01})
-    result = cb.compare_records(old, new, threshold=0.05)
-    assert not any(
-        "round_batch" in e["metric"]
-        for e in result["regressions"] + result["improvements"]
-    )
-
-
-def test_round_batch_amortization_self_gate(cb, tmp_path):
-    """In-record absolute floor: batching that stops paying for itself
-    (ratio < threshold) gates on the NEW record alone."""
-    assert cb.batch_amortization_gate(_record(), 0.95) is None  # leg absent
-    ok = _record(round_batch={"amortization_ratio": 1.12})
-    assert cb.batch_amortization_gate(ok, 0.95) is None
-    bad = _record(round_batch={"amortization_ratio": 0.71})
-    entry = cb.batch_amortization_gate(bad, 0.95)
-    assert entry and entry["new"] == 0.71 and entry["direction"] == "higher"
-
-    old_p = tmp_path / "old.json"
-    bad_p = tmp_path / "bad.json"
-    old_p.write_text(json.dumps(_record()))
-    bad_p.write_text(json.dumps(bad))
-    proc = subprocess.run(
-        [sys.executable, _SCRIPT, str(old_p), str(bad_p)],
-        capture_output=True, text=True, timeout=120,
-    )
-    assert proc.returncode == 1
-    assert "round_batch.amortization_ratio" in proc.stdout
-    proc = subprocess.run(
-        [sys.executable, _SCRIPT, str(old_p), str(bad_p),
-         "--batch-amortization-threshold", "0.5"],
-        capture_output=True, text=True, timeout=120,
-    )
-    assert proc.returncode == 0
-
-
 def test_async_speedup_not_relatively_tracked(cb):
     """The async speedup sits at a fixed operating point per config —
     like the other in-record ratios it must never be a relative TRACKED

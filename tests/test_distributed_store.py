@@ -207,7 +207,23 @@ def test_validate_manifest_refusals_name_the_cause():
                           owner_bounds=[0, 6, 8])
 
 
-def test_config_refusals_name_causes():
+CONFIG_REFUSALS = {
+    "GLOBAL device count": dict(mesh_devices=None),
+    "hashed": dict(participation_sampler="exact"),
+    "async": dict(async_mode="on", arrival_model="bimodal"),
+    "client_stats": dict(client_stats="on"),
+    "valuation vector": dict(client_stats="off", client_valuation="on"),
+    "persistent per-client state": dict(
+        participation_fraction=1.0, reset_client_optimizer=False),
+    "re-partition the distributed": dict(
+        population="dynamic", join_rate=1.0),
+    "stochastic-quantization": dict(
+        distributed_algorithm="fed_quant", client_eval=False),
+}
+
+
+@pytest.mark.parametrize("cause", sorted(CONFIG_REFUSALS))
+def test_config_refusals_name_causes(cause):
     """Streamed x multihost composes; every remaining refusal names its
     blocking cause (the PR 2/6/7 discipline)."""
     from distributed_learning_simulator_tpu.config import ExperimentConfig
@@ -222,24 +238,8 @@ def test_config_refusals_name_causes():
         return ExperimentConfig(**base).validate()
 
     cfg()  # the lifted composition validates
-    with pytest.raises(ValueError, match="GLOBAL device count"):
-        cfg(mesh_devices=None)
-    with pytest.raises(ValueError, match="hashed"):
-        cfg(participation_sampler="exact")
-    with pytest.raises(ValueError, match="rounds_per_dispatch=1"):
-        cfg(rounds_per_dispatch=2)
-    with pytest.raises(ValueError, match="async"):
-        cfg(async_mode="on", arrival_model="bimodal")
-    with pytest.raises(ValueError, match="client_stats"):
-        cfg(client_stats="on")
-    with pytest.raises(ValueError, match="valuation vector"):
-        cfg(client_stats="off", client_valuation="on")
-    with pytest.raises(ValueError, match="persistent per-client state"):
-        cfg(participation_fraction=1.0, reset_client_optimizer=False)
-    with pytest.raises(ValueError, match="re-partition the distributed"):
-        cfg(population="dynamic", join_rate=1.0)
-    with pytest.raises(ValueError, match="stochastic-quantization"):
-        cfg(distributed_algorithm="fed_quant", client_eval=False)
+    with pytest.raises(ValueError, match=cause):
+        cfg(**CONFIG_REFUSALS[cause])
 
 
 def test_draw_pos_permutes_back_to_draw_order(tiny_dataset):

@@ -177,7 +177,6 @@ KEEPS_THE_GLOBAL = {
         client_stats="on", client_valuation="on", valuation_audit_every=1,
         valuation_audit_permutations=4),
     "server_optimizer": dict(server_optimizer_name="sgd"),
-    "rounds_per_dispatch_2": dict(rounds_per_dispatch=2),
 }
 
 

@@ -103,8 +103,9 @@ def test_v2_record_validates():
 
 
 def test_v2_batched_dispatch_record_validates():
-    """A batched dispatch's telemetry (rounds_per_dispatch > 1) adds
-    dispatch_rounds + the warmup marker; still plain v2."""
+    """Records of older trees, which could fuse several rounds into one
+    dispatch, carry dispatch_rounds + a warmup marker in their telemetry.
+    Nothing emits either now; the schema still admits them as plain v2."""
     tel = {**_telemetry(), "dispatch_rounds": 8, "warmup": True}
     record = build_round_record(_base(), tel)
     assert record["schema_version"] == 2
@@ -161,8 +162,8 @@ def test_v5_record_validates():
     validate(record)
     # stream alone (every other feature off) is still v5.
     validate(build_round_record(_base(), None, None, None, _stream()))
-    # Stateless runs carry no d2h fields; batched dispatches stamp the
-    # rounds their transfer covers.
+    # Stateless runs carry no d2h fields; older trees' records may stamp
+    # the rounds a transfer covered (dispatch_rounds).
     validate(build_round_record(_base(), None, None, None, {
         "h2d_bytes": 655360, "h2d_seconds": 0.0123,
         "hidden_seconds": 0.0, "overlap_ratio": 0.0,

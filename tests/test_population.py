@@ -276,23 +276,24 @@ def test_drift_schedule_absolute_and_idempotent():
 # ---- config refusals / off-gate --------------------------------------------
 
 
-def test_validate_refusal_causes():
-    cases = [
-        (dict(client_residency="resident"), "streamed"),
-        (dict(participation_sampler="exact"), "hashed"),
-        (dict(participation_fraction=1.0), "participation_fraction"),
-        (dict(rounds_per_dispatch=2), "rounds_per_dispatch"),
-        (dict(async_mode="on", arrival_model="bimodal"), "speed"),
-        (dict(distributed_algorithm="sign_SGD"), "FedAvg"),
-        (dict(distributed_algorithm="GTG_shapley_value"), "cohort"),
-        (dict(execution_mode="threaded"), "thread"),
-        (dict(client_stats="on", client_valuation="on",
-              valuation_audit_every=2), "audit"),
-    ]
-    for overrides, needle in cases:
-        with pytest.raises(ValueError, match=needle):
-            _dyn(**overrides).validate()
+VALIDATE_REFUSALS = {
+    "streamed": dict(client_residency="resident"),
+    "hashed": dict(participation_sampler="exact"),
+    "participation_fraction": dict(participation_fraction=1.0),
+    "speed": dict(async_mode="on", arrival_model="bimodal"),
+    "FedAvg": dict(distributed_algorithm="sign_SGD"),
+    "cohort": dict(distributed_algorithm="GTG_shapley_value"),
+    "thread": dict(execution_mode="threaded"),
+    "audit": dict(client_stats="on", client_valuation="on",
+                  valuation_audit_every=2),
+}
+
+
+@pytest.mark.parametrize("cause", sorted(VALIDATE_REFUSALS))
+def test_validate_refusal_causes(cause):
     _dyn().validate()  # the composed base is legal
+    with pytest.raises(ValueError, match=cause):
+        _dyn(**VALIDATE_REFUSALS[cause]).validate()
 
 
 def test_static_offgate_hash_and_history(tiny_dataset):

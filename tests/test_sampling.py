@@ -258,22 +258,3 @@ def test_hashed_streamed_matches_resident(tiny_config):
         *_BIT_KEYS,
     )
     assert exact["cohort_hash"] != resident["cohort_hash"]
-
-
-def test_hashed_batched_dispatch_matches_per_round(tiny_config):
-    """rounds_per_dispatch>1 under the hashed sampler: the streamed
-    scan's host-replayed cohorts equal the K=1 loop's bit-for-bit (the
-    key-chain replay discipline is sampler-independent)."""
-    cfg = dataclasses.replace(
-        tiny_config, worker_number=8, round=4, participation_fraction=0.5,
-        participation_sampler="hashed", client_residency="streamed",
-    )
-    k1 = _series(run_simulation(cfg, setup_logging=False), *_BIT_KEYS)
-    k3 = _series(
-        run_simulation(
-            dataclasses.replace(cfg, rounds_per_dispatch=3),
-            setup_logging=False,
-        ),
-        *_BIT_KEYS,
-    )
-    assert k1 == k3

@@ -5,7 +5,7 @@ uploaded per dispatch, double-buffered so the next dispatch's cohort
 transfers while the current one computes. The contract under test: the
 streamed history is BIT-identical to the resident one — cohort hashes,
 failure draws, and training metrics included — across the FedAvg family,
-sign_SGD, fed_quant, rounds_per_dispatch>1, and checkpoint/resume, while
+sign_SGD, fed_quant, and checkpoint/resume, while
 'resident' (the default) keeps the exact pre-feature program.
 
 The HostShardStore unit tests are jax-free by design (the module imports
@@ -88,16 +88,6 @@ def test_shapley_refuses_streamed(tiny_config):
     with pytest.raises(ValueError, match="client_residency"):
         _run(tiny_config, distributed_algorithm="multiround_shapley_value",
              client_residency="streamed")
-
-
-def test_streamed_batched_persistent_state_refused(tiny_config):
-    """Cohorts inside one fused dispatch may overlap and the host store
-    cannot scatter mid-dispatch — streamed + rounds_per_dispatch>1 +
-    persistent per-client state is refused with the cause."""
-    with pytest.raises(ValueError, match="rounds_per_dispatch"):
-        _run(tiny_config, worker_number=8, participation_fraction=0.5,
-             reset_client_optimizer=False, client_residency="streamed",
-             rounds_per_dispatch=2)
 
 
 # ------------------------------------------ host shard store (jax-free)
@@ -271,23 +261,6 @@ def test_streamed_matches_resident_fedavg_full_feature(tiny_config):
     assert None not in base["cohort_hash"]  # sampling actually exercised
 
 
-def test_streamed_matches_resident_batched_k3(tiny_config):
-    """rounds_per_dispatch=3 over 4 rounds (remainder dispatch included):
-    the streamed scan consumes stacked [k, cohort, ...] uploads whose
-    cohorts were host-replayed from the key chain — bit-identical to the
-    resident batched program AND to the K=1 loop."""
-    cfg = dataclasses.replace(
-        tiny_config, worker_number=8, round=4, participation_fraction=0.5,
-        server_optimizer_name="sgd", server_learning_rate=1.0,
-        server_momentum=0.9,
-    )
-    base = _series(_run(cfg), *_BIT_KEYS)
-    assert base == _series(
-        _run(cfg, client_residency="streamed", rounds_per_dispatch=3),
-        *_BIT_KEYS,
-    )
-
-
 def test_streamed_matches_resident_sign_sgd_momentum(tiny_config):
     """sign_SGD's per-step vote synchronizes the whole population — the
     full-cohort streamed regime (one startup upload, resident program
@@ -446,27 +419,21 @@ def test_streamed_mesh_fed_quant(tiny_config):
     )
 
 
-def test_streamed_mesh_batched_and_persistent_state(tiny_config):
-    """The remaining composition axes on one mesh: K>1 batched scan
-    dispatches (stacked [K, cohort, ...] sharded uploads) and the
-    persistent-state writeback path (sharded cohort state gathered
-    from and scattered back to the host store)."""
-    base = dataclasses.replace(
+def test_streamed_mesh_persistent_state(tiny_config):
+    """The remaining composition axis on one mesh: the persistent-state
+    writeback path (sharded cohort state gathered from and scattered
+    back to the host store)."""
+    cfg = dataclasses.replace(
         tiny_config, worker_number=16, round=4, participation_fraction=0.5,
         mesh_devices=4, client_residency="streamed",
+        reset_client_optimizer=False,
     )
-    for overrides in (
-        {"rounds_per_dispatch": 2},
-        {"reset_client_optimizer": False},
-    ):
-        cfg = dataclasses.replace(base, **overrides)
-        streamed = _mesh_series(cfg, *_BIT_KEYS)
-        resident = _mesh_series(cfg, *_BIT_KEYS,
-                                client_residency="resident")
-        assert streamed["cohort_hash"] == resident["cohort_hash"], overrides
-        np.testing.assert_allclose(
-            streamed["test_loss"], resident["test_loss"], atol=1e-4,
-        )
+    streamed = _mesh_series(cfg, *_BIT_KEYS)
+    resident = _mesh_series(cfg, *_BIT_KEYS, client_residency="resident")
+    assert streamed["cohort_hash"] == resident["cohort_hash"]
+    np.testing.assert_allclose(
+        streamed["test_loss"], resident["test_loss"], atol=1e-4,
+    )
 
 
 def test_streamed_mesh_cohort_divisibility_refused(tiny_config):
@@ -590,19 +557,3 @@ def test_report_run_renders_transfer_row(tiny_config, tmp_path):
     assert 0.0 <= s["overlap_ratio"] <= 1.0
     text = "\n".join(report_run.render_summary(summary))
     assert "h2d_stream" in text and "streamed transfers: 3 upload(s)" in text
-
-
-def test_streamed_batched_stream_record_on_last_round(tiny_config,
-                                                      tmp_path):
-    """K>1: ONE upload per dispatch; its stream record lands on the
-    dispatch's last round (like the phase timings) stamped with
-    dispatch_rounds."""
-    cfg = dataclasses.replace(
-        tiny_config, worker_number=8, round=4, participation_fraction=0.5,
-        rounds_per_dispatch=2, client_residency="streamed",
-        log_root=str(tmp_path / "b"),
-    )
-    run_simulation(cfg)
-    records = _read_metrics(tmp_path / "b")
-    assert [("stream" in r) for r in records] == [False, True, False, True]
-    assert records[1]["stream"]["dispatch_rounds"] == 2

@@ -232,39 +232,32 @@ def test_auto_strategy_resolution(shared):
     assert mixed.resolve_strategy() == "scheduled"
 
 
-def test_refusal_causes(shared):
-    base, _, _ = shared
+# cause -> what, built on the shared base config, must be refused.
+REFUSALS = {
     # Threaded oracle: no shared program to warm.
-    with pytest.raises(ValueError, match="threaded"):
-        dataclasses.replace(
-            base, execution_mode="threaded", sweep_seeds="0,1"
-        ).validate()
+    "threaded": lambda base: dataclasses.replace(
+        base, execution_mode="threaded", sweep_seeds="0,1"),
     # Shapley: post_round must observe every round synchronously.
-    with pytest.raises(ValueError, match="post_round"):
-        dataclasses.replace(
-            base, distributed_algorithm="GTG_shapley_value",
-            sweep_seeds="0,1",
-        ).validate()
-    # Streamed residency + K>1: no host-replayable plan across points.
-    with pytest.raises(ValueError, match="rounds_per_dispatch"):
-        dataclasses.replace(
-            base, client_residency="streamed", rounds_per_dispatch=2,
-            participation_fraction=0.5, sweep_seeds="0,1",
-        ).validate()
+    "post_round": lambda base: dataclasses.replace(
+        base, distributed_algorithm="GTG_shapley_value",
+        sweep_seeds="0,1"),
     # Forcing 'vmapped' on a non-fleet feature names the blocker.
-    with pytest.raises(ValueError, match="client_stats"):
-        SweepSpec(
-            dataclasses.replace(base, client_stats="on"),
-            [{"seed": 0}, {"seed": 1}], strategy="vmapped",
-        ).validate()
+    "client_stats": lambda base: SweepSpec(
+        dataclasses.replace(base, client_stats="on"),
+        [{"seed": 0}, {"seed": 1}], strategy="vmapped"),
     # Duplicate points are refused, not silently recomputed.
-    with pytest.raises(ValueError, match="identical"):
-        SweepSpec(base, [{"seed": 3}, {"seed": 3}]).validate()
+    "identical": lambda base: SweepSpec(base, [{"seed": 3}, {"seed": 3}]),
     # sweep_resume without a sweep_dir to resume from.
-    with pytest.raises(ValueError, match="sweep_dir"):
-        dataclasses.replace(
-            base, sweep_seeds="0,1", sweep_resume=True
-        ).validate()
+    "sweep_dir": lambda base: dataclasses.replace(
+        base, sweep_seeds="0,1", sweep_resume=True),
+}
+
+
+@pytest.mark.parametrize("cause", sorted(REFUSALS))
+def test_refusal_causes(shared, cause):
+    base, _, _ = shared
+    with pytest.raises(ValueError, match=cause):
+        REFUSALS[cause](base).validate()
 
 
 def test_sweep_resume_bit_identical(shared, tmp_path):

@@ -183,21 +183,6 @@ def test_off_gate_bit_identity_and_records(tiny_dataset):
     assert off["valuation"] is None and off["valuation_state"] is None
     assert on["valuation_state"] is not None
     assert on["client_valuation"] == "on"
-    # Batched dispatch (rounds_per_dispatch=2): stacked [K, N] score rows
-    # fold per round through the shared emit_record tail — same vector,
-    # same v7 records, as the K=1 loop.
-    batched = _run(
-        dataclasses.replace(base, client_valuation="on",
-                            rounds_per_dispatch=2),
-        dataset=tiny_dataset,
-    )
-    np.testing.assert_array_equal(
-        on["valuation_state"].values, batched["valuation_state"].values
-    )
-    assert all(
-        r["schema_version"] == 7 and "valuation" in r
-        for r in batched["history"]
-    )
 
 
 def test_config_hash_off_gate_invariance():
@@ -227,6 +212,8 @@ def test_config_hash_off_gate_invariance():
         # Off-gated at 'static' (ISSUE 13, robustness/population.py).
         "population", "population_seed", "join_rate", "depart_rate",
         "drift_fraction", "drift_factor",
+        # Off-gated at span_trace='off' (ISSUE 16, telemetry/spans.py).
+        "span_trace", "span_buffer_size", "span_flush_last_k",
     ):
         d.pop(k, None)
     pre_feature = hashlib.sha256(
@@ -251,34 +238,34 @@ def test_config_hash_off_gate_invariance():
     ) != h_default
 
 
-def test_validate_refusals():
-    with pytest.raises(ValueError, match="client_stats='on'"):
-        _tiny(client_valuation="on").validate()
-    with pytest.raises(ValueError, match="sign_SGD"):
-        _tiny(distributed_algorithm="sign_SGD", client_stats="on",
-              client_valuation="on").validate()
-    with pytest.raises(ValueError, match="vmap"):
-        _tiny(execution_mode="threaded", client_stats="on",
-              client_valuation="on").validate()
-    with pytest.raises(ValueError, match="streaming vector to audit"):
-        _tiny(valuation_audit_every=2).validate()
-    ok = dict(client_stats="on", client_valuation="on",
-              valuation_audit_every=2)
-    _tiny(**ok).validate()
-    with pytest.raises(ValueError, match="failure injection"):
-        _tiny(failure_mode="dropout", failure_prob=0.5, **ok).validate()
-    with pytest.raises(ValueError, match="'fed' only"):
-        # fed_quant's per-chunk upload-quantization keys cannot be
-        # replayed exactly on a whole-stack audit.
-        _tiny(distributed_algorithm="fed_quant", **ok).validate()
-    with pytest.raises(ValueError, match="rounds_per_dispatch"):
-        _tiny(rounds_per_dispatch=2, **ok).validate()
-    with pytest.raises(ValueError, match="reset_client_optimizer"):
-        _tiny(reset_client_optimizer=False, **ok).validate()
-    with pytest.raises(ValueError, match="weighted-mean"):
-        _tiny(aggregation="median", **ok).validate()
-    with pytest.raises(ValueError, match="valuation_decay"):
-        _tiny(valuation_decay=1.0).validate()
+_AUDIT_OK = dict(client_stats="on", client_valuation="on",
+                 valuation_audit_every=2)
+VALIDATE_REFUSALS = {
+    "client_stats='on'": dict(client_valuation="on"),
+    "sign_SGD": dict(distributed_algorithm="sign_SGD", client_stats="on",
+                     client_valuation="on"),
+    "vmap": dict(execution_mode="threaded", client_stats="on",
+                 client_valuation="on"),
+    "streaming vector to audit": dict(valuation_audit_every=2),
+    "failure injection": dict(failure_mode="dropout", failure_prob=0.5,
+                              **_AUDIT_OK),
+    # fed_quant's per-chunk upload-quantization keys cannot be replayed
+    # exactly on a whole-stack audit.
+    "'fed' only": dict(distributed_algorithm="fed_quant", **_AUDIT_OK),
+    "reset_client_optimizer": dict(reset_client_optimizer=False,
+                                   **_AUDIT_OK),
+    "weighted-mean": dict(aggregation="median", **_AUDIT_OK),
+    "valuation_decay": dict(valuation_decay=1.0),
+}
+
+
+@pytest.mark.parametrize("cause", sorted(VALIDATE_REFUSALS))
+def test_validate_refusals(cause):
+    """The audited base validates; each override is refused naming its
+    cause."""
+    _tiny(**_AUDIT_OK).validate()
+    with pytest.raises(ValueError, match=cause):
+        _tiny(**VALIDATE_REFUSALS[cause]).validate()
 
 
 # ---- residency / resume ----------------------------------------------------
