@@ -15,8 +15,10 @@ to experts held elsewhere cost no expert FLOPs here and what those experts
 (and the absent heads) would add is left out. No code stands in for the
 other chips.
 
-``__call__`` returns ``(logits, counts)``: ``counts`` are the routing
-counters of the batch (``moe_local_assignments``, ``moe_routed_tokens``,
+``__call__`` returns ``(head, counts)``: ``head`` is the head's kernel and
+input, the logits not yet made (:class:`UnmadeLogits`: the loss and its
+gradient are made inside the head, :func:`head_nll`); ``counts`` are the
+routing counters of the batch (``moe_local_assignments``, ``moe_routed_tokens``,
 ``moe_overflows`` per layer, ``moe_expert_load`` per layer and held
 expert), which the engine sums into the round's aux outputs.
 
@@ -361,9 +363,111 @@ def moe_shared(p, x, *, dtype):
 def lm_head(kernel, x, *, dtype):
     """Logits over the rows of the vocabulary held, accumulated f32 and
     kept in ``dtype``: at ``[tokens, 24576]`` they are the largest
-    activation of the step, and the loss takes its softmax in f32."""
+    activation of the step, and the loss takes its softmax in f32.
+    The model does not hand them to the engine: :func:`head_nll`."""
     with jax.named_scope("lm_head"):
         return _mm(x, kernel, dtype).astype(dtype)
+
+
+def _head_loss(kernel, x, targets, weight):
+    """The head's logits, made once, and everything of vocabulary width
+    that the loss and its gradient need from them; ``kernel`` and ``x``
+    are in the products' dtype. The values are ``jax.nn.log_softmax``'s
+    and ``take_along_axis``'s, operation for operation."""
+    logits = lm_head(kernel, x, dtype=x.dtype)
+    f32 = logits.astype(jnp.float32)
+    shifted = f32 - jnp.max(f32, axis=-1, keepdims=True)
+    e = jnp.exp(shifted)
+    total = jnp.sum(e, axis=-1, keepdims=True)
+    hit = jnp.arange(f32.shape[-1]) == targets[..., None]
+    nll = jnp.log(total)[..., 0] - jnp.sum(
+        jnp.where(hit, shifted, 0.0), axis=-1)
+    correct = (jnp.argmax(logits, axis=-1) == targets).astype(jnp.float32)
+    out = jnp.sum(weight * nll), jnp.sum(weight * correct)
+    return out, (e, total, hit, nll, correct)
+
+
+@jax.custom_vjp
+def _head_nll(kernel, x, targets, weight):
+    return _head_loss(kernel, x, targets, weight)[0]
+
+
+def _head_nll_forward(kernel, x, targets, weight):
+    with jax.named_scope("lm_head"):
+        out, (e, total, hit, nll, correct) = _head_loss(
+            kernel, x, targets, weight)
+        # d(sum weight * nll) / d logits = weight * (softmax - one_hot)
+        # in autodiff's order of operations, rounded to the logits' dtype
+        # as autodiff rounds the cotangent of ``.astype(dtype)``, and
+        # written out ONCE (the barrier): left free it is fused into
+        # both products, or written out in f32, twice the bytes.
+        w = weight[..., None]
+        share = e * (w / total)
+        d_logits = jax.lax.optimization_barrier(
+            jnp.where(hit, share - w, share).astype(x.dtype))
+        # Autodiff's two products: operands in ``dtype``, accumulated
+        # f32, each gradient rounded to its operand's dtype.
+        d_kernel = _ein("...d,...v->dv", x, d_logits, x.dtype).astype(
+            kernel.dtype)
+        d_x = _ein("...v,dv->...d", d_logits, kernel, x.dtype).astype(
+            x.dtype)
+        # Tied: the input gradient cannot leave for the blocks' backward
+        # before the weight gradient exists. Left free, the weight
+        # gradient (it feeds only the parameter update) is scheduled
+        # after the blocks' backward, and what it reads is dropped and
+        # made again for it. Nothing of vocabulary width leaves this rule.
+        d_kernel, d_x = jax.lax.optimization_barrier((d_kernel, d_x))
+        return out, (d_kernel, d_x, nll, correct)
+
+
+def _head_nll_backward(residuals, cotangents):
+    d_kernel, d_x, nll, correct = residuals
+    ct, ct_correct = cotangents  # 1.0 and 0.0 under value_and_grad
+    with jax.named_scope("lm_head"):
+        return (
+            (ct * d_kernel).astype(d_kernel.dtype),
+            (ct * d_x).astype(d_x.dtype),
+            None,  # integer targets
+            ct * nll + ct_correct * correct,
+        )
+
+
+_head_nll.defvjp(_head_nll_forward, _head_nll_backward)
+
+
+def head_nll(kernel, x, targets, weight, *, dtype):
+    """``(sum weight * nll, sum weight * correct)`` of softmax
+    cross-entropy over the head's logits, one target and one weight a
+    position, WITHOUT handing the logits on: the forward rule of the
+    gradient makes the logits once, takes the f32 softmax, and makes
+    ``d_logits``, the input gradient ``d_logits @ kernel^T`` and the
+    weight gradient ``x^T @ d_logits`` there and then; the backward rule
+    scales those two by the scalar cotangent. Roundings are plain
+    autodiff's: logits rounded to ``dtype`` before the f32 softmax,
+    ``d_logits`` rounded to ``dtype`` before both products.
+
+    Do not simplify this back to ``lm_head`` plus the engine's loss, and
+    keep both barriers of the forward rule. Handed the logits, plain
+    autodiff made the loss's backward out of vocabulary-wide f32 tensors
+    (the cotangent of ``take_along_axis`` scattered into a dense ``[4096,
+    24576]`` f32 array over a broadcast of zeros, 403 MB each, then a
+    604 MB subtract), and XLA's rematerialisation dropped the 201 MB
+    logits after the forward softmax and made the product again for the
+    backward: a pair ``fusion.N`` / ``fusion.N.remat`` in each of the two
+    unrolled local steps, 4.62 ms an execution at 91 % of the MXU's
+    peak, 2 x 36.96 = 73.9 ms of a 2502.5 ms round in
+    ``solar_open2_fed_seq4k_c8`` (PERF_LEDGER.jsonl, PR 31). Room did not
+    cure it (PR 30 donated the global model and the compiler spent the
+    3.4 GB elsewhere). Nor did a barrier on the head's two gradients
+    alone (the twin's consumer was the loss's backward), nor this rule
+    without its barriers: the weight gradient feeds only the parameter
+    update, so XLA fused the softmax into it, scheduled it after the
+    blocks' backward and remade the logits for it there. What each form
+    compiled to, and what the chip read: PERF.md § 6, PR 32.
+    """
+    with jax.named_scope("lm_head"):
+        return _head_nll(
+            kernel.astype(dtype), x.astype(dtype), targets, weight)
 
 
 _SCOPES = {
@@ -373,7 +477,8 @@ _SCOPES = {
     "moe/route": (moe_route,),
     "moe/experts": (moe_experts,),
     "moe/shared": (moe_shared,),
-    "lm_head": (lm_head,),
+    "lm_head": (lm_head, head_nll, _head_loss, _head_nll,
+                _head_nll_forward, _head_nll_backward),
 }
 
 
@@ -400,6 +505,31 @@ def scope_of_line(line: int) -> str | None:
 
 
 # --- the module -------------------------------------------------------------
+
+
+@jax.tree_util.register_pytree_node_class
+class UnmadeLogits:
+    """What the model hands on in place of logits: the head's kernel and
+    its normed input. The engine's loss asks it for :meth:`weighted_nll`
+    (:func:`head_nll`: no logits leave the head); whoever wants the
+    logits themselves asks for :meth:`logits`."""
+
+    def __init__(self, kernel, x, dtype):
+        self.kernel, self.x, self.dtype = kernel, x, dtype
+
+    def logits(self):
+        return lm_head(self.kernel, self.x, dtype=self.dtype)
+
+    def weighted_nll(self, targets, weight):
+        return head_nll(self.kernel, self.x, targets, weight,
+                        dtype=self.dtype)
+
+    def tree_flatten(self):
+        return (self.kernel, self.x), self.dtype
+
+    @classmethod
+    def tree_unflatten(cls, dtype, children):
+        return cls(*children, dtype)
 
 
 class _Norm(nn.Module):
@@ -537,6 +667,9 @@ class SolarOpen2(nn.Module):
     #: traced over this many positions.
     jit_init = True
     init_positions = KDA_CHUNK
+    #: The head makes its loss and both its gradients itself and hands
+    #: on no logits (head_nll); the recorder's counter of the same name.
+    head_backward_tied = True
 
     @nn.compact
     def __call__(self, tokens):
@@ -560,7 +693,7 @@ class SolarOpen2(nn.Module):
         x = _Norm(c.rms_norm_eps, name="final_norm")(h)
         kernel = _Params(
             (("kernel", (D, vocab), D),), name="lm_head")()["kernel"]
-        logits = lm_head(kernel, x, dtype=jnp.dtype(c.dtype))
+        head = UnmadeLogits(kernel, x, jnp.dtype(c.dtype))
         load = jnp.stack(loads)  # [layers, held]
         counts = {
             "moe_local_assignments": jnp.sum(load, axis=1),
@@ -569,7 +702,7 @@ class SolarOpen2(nn.Module):
             "moe_overflows": jnp.stack(overflows).astype(jnp.int32),
             "moe_expert_load": load,
         }
-        return logits, counts
+        return head, counts
 
 
 def solar_open2(num_classes: int, vocab_rows: int | None = None, **share):

@@ -59,6 +59,10 @@ def split_model_output(out):
     return out, {}
 
 
+def _target_mask(y, mask):
+    return jnp.broadcast_to(mask[:, None], y.shape) if y.ndim == 2 else mask
+
+
 def target_nll(logits, y, mask):
     """``(nll, mask)`` of softmax cross-entropy, one value a target:
     ``y`` ``[B]`` over ``logits`` ``[B, V]``, or one target a position,
@@ -66,9 +70,17 @@ def target_nll(logits, y, mask):
     samples and comes back in the targets' shape."""
     logp = jax.nn.log_softmax(logits.astype(jnp.float32))
     nll = -jnp.take_along_axis(logp, y[..., None], axis=-1)[..., 0]
-    if y.ndim == 2:
-        mask = jnp.broadcast_to(mask[:, None], y.shape)
-    return nll, mask
+    return nll, _target_mask(y, mask)
+
+
+def makes_own_loss(outputs) -> bool:
+    """Whether a model handed on, in place of logits, a head that has not
+    made them (``weighted_nll(targets, weight) -> (sum weight * nll, sum
+    weight * correct)``; ``logits()`` for whoever wants them): the loss
+    and its gradient are then the head's to make, and no array of
+    vocabulary width crosses this seam (models/solar_open2.py
+    ``head_nll``). An array of logits takes the path it always took."""
+    return hasattr(outputs, "weighted_nll")
 
 
 def make_loss_fn(apply_fn, param_transform: Callable | None = None):
@@ -85,6 +97,11 @@ def make_loss_fn(apply_fn, param_transform: Callable | None = None):
     def loss_fn(params, x, y, mask):
         p = param_transform(params) if param_transform is not None else params
         logits, counts = split_model_output(apply_fn({"params": p}, x))
+        if makes_own_loss(logits):
+            mask = _target_mask(y, mask)
+            loss, acc = logits.weighted_nll(
+                y, mask / jnp.maximum(jnp.sum(mask), 1.0))
+            return loss, (acc, counts)
         nll, mask = target_nll(logits, y, mask)
         denom = jnp.maximum(jnp.sum(mask), 1.0)
         loss = jnp.sum(nll * mask) / denom
@@ -647,9 +664,16 @@ def make_eval_fn(apply_fn, preprocess: Callable | None = None,
             if preprocess is not None:
                 x = preprocess(x)
             logits, _ = split_model_output(apply_fn({"params": params}, x))
+            loss_sum, correct_sum, count = carry
+            if makes_own_loss(logits):
+                m = _target_mask(y, m)
+                nll_sum, correct = logits.weighted_nll(y, m)
+                return (
+                    loss_sum + nll_sum, correct_sum + correct,
+                    count + jnp.sum(m),
+                ), None
             nll, m = target_nll(logits, y, m)
             correct = (jnp.argmax(logits, axis=-1) == y).astype(jnp.float32)
-            loss_sum, correct_sum, count = carry
             return (
                 loss_sum + jnp.sum(nll * m),
                 correct_sum + jnp.sum(correct * m),

@@ -815,6 +815,12 @@ def run_simulation(
             "client_axis_width", min(config.client_chunk_size or n_clients,
                                      config.cohort_size(n_clients)),
         )
+        # 1 where the model's head makes its loss and both its gradients
+        # itself and hands on no logits (models/solar_open2.py head_nll).
+        tracer.set_counter(
+            "head_backward_tied",
+            int(getattr(model, "head_backward_tied", False)),
+        )
 
         # Optional server-side optimizer (FedOpt; exceeds the reference): the
         # aggregate is post-processed by a jitted pseudo-gradient step.

@@ -58,7 +58,9 @@ shared ``span_dir`` is passed via ``--spans``), the cross-host
 timeline section is stitched live through ``scripts/trace_timeline.py``
 — per-host span and event counts with the recorder's build-time
 counters under them (``local_steps_unrolled``: how many local steps the
-round program holds unrolled, 0 = a loop), busy/wait totals, per-round
+round program holds unrolled, 0 = a loop; ``head_backward_tied``: 1 where
+the model's head makes its loss and its gradients itself and hands on no
+logits), busy/wait totals, per-round
 barrier skew with the slowest host named, and the flight-recorder
 postmortem (what each host was doing when it died); ``--host``
 restricts it to one host. The only

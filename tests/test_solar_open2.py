@@ -23,15 +23,16 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HI = jax.lax.Precision.HIGHEST
 
 
-def _bench_module(kind, name):
-    path = os.path.join(ROOT, "benchmark", kind, name + ".py")
-    spec = importlib.util.spec_from_file_location(f"_t_{kind}_{name}", path)
+def _module_at(*parts):
+    path = os.path.join(ROOT, *parts) + ".py"
+    spec = importlib.util.spec_from_file_location(
+        "_t_" + "_".join(parts), path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
 
 
-ref = _bench_module("references", "solar_open2")
+ref = _module_at("benchmark", "references", "solar_open2")
 
 MODEL = {
     "hidden_size": 64, "num_hidden_layers": 4, "gqa_layers": [0],
@@ -232,8 +233,8 @@ def test_whole_model_loss_and_gradients():
             jnp.take_along_axis(logp, targets[..., None], -1))
 
     def program(params):
-        logits, counts = model.apply({"params": params}, tokens)
-        return loss(logits), counts
+        head, counts = model.apply({"params": params}, tokens)
+        return loss(head.logits()), counts
 
     def plain(params):
         return loss(ref.forward(MODEL, params, tokens, **PRODUCTS))
@@ -347,20 +348,152 @@ def test_every_product_is_traced_from_the_line_of_a_scope():
     tokens = jnp.zeros((2, 48), jnp.int32)
 
     def loss(p):
-        logits, _ = model.apply({"params": p}, tokens)
-        return jnp.sum(logits)
+        head, _ = model.apply({"params": p}, tokens)
+        return jnp.sum(head.logits())
+
+    def scopes_of_products(jaxpr):
+        scopes = set()
+        for eqn in _equations(jaxpr):
+            if eqn.primitive.name != "dot_general":
+                continue
+            frame = source_info_util.user_frame(eqn.source_info.traceback)
+            assert frame.file_name.endswith("models/solar_open2.py"), frame
+            scope = so.scope_of_line(frame.start_line)
+            assert scope is not None, frame
+            scopes.add(scope)
+        return scopes
 
     jaxpr = jax.make_jaxpr(jax.grad(loss))(params).jaxpr
-    scopes = set()
-    for eqn in _equations(jaxpr):
-        if eqn.primitive.name != "dot_general":
-            continue
+    assert scopes_of_products(jaxpr) == set(so._SCOPES)
+
+    # The training path: the head makes its loss and its gradients in a
+    # rule of its own (head_nll), and EVERY op of that rule, not only its
+    # products, is traced from a line of scope ``lm_head``.
+    from distributed_learning_simulator_tpu.parallel.engine import (
+        make_loss_fn)
+
+    train = jax.grad(lambda p: make_loss_fn(model.apply)(
+        p, tokens, tokens, jnp.ones((2,)))[0])
+    jaxpr = jax.make_jaxpr(train)(params).jaxpr
+    assert scopes_of_products(jaxpr) == set(so._SCOPES)
+    rule = [
+        eqn for eqn in _equations(jaxpr)
+        if any(getattr(v.aval, "shape", ())[-1:] == (96,)
+               and len(v.aval.shape) == 3 for v in eqn.outvars)
+    ]  # whatever is of vocabulary width: [2, 48, 96]
+    assert len(rule) > 8
+    for eqn in rule:
         frame = source_info_util.user_frame(eqn.source_info.traceback)
         assert frame.file_name.endswith("models/solar_open2.py"), frame
-        scope = so.scope_of_line(frame.start_line)
-        assert scope is not None, frame
-        scopes.add(scope)
-    assert scopes == set(so._SCOPES)
+        assert so.scope_of_line(frame.start_line) == "lm_head", frame
+
+
+def _head_case(dtype, kernel_dtype, length, seed=11):
+    rng = np.random.default_rng(seed)
+    kernel = jnp.asarray(rng.standard_normal((64, 96)) / 8, kernel_dtype)
+    x = jnp.asarray(rng.standard_normal((2, length, 64)), jnp.float32)
+    y = jnp.asarray(rng.integers(0, 96, (2, length)), jnp.int32)
+    return kernel, x, y, jnp.dtype(dtype)
+
+
+@pytest.mark.parametrize("dtype,kernel_dtype,length", [
+    ("float32", "float32", 16), ("float32", "float32", 9),
+    ("bfloat16", "bfloat16", 16), ("bfloat16", "bfloat16", 9),
+    ("bfloat16", "float32", 16),
+])
+def test_head_nll_is_autodiff_of_the_plain_head_and_loss(
+        dtype, kernel_dtype, length):
+    """The head that makes its own loss (``head_nll``: logits, f32
+    softmax, ``d_logits`` and both products in the gradient's forward
+    rule) against plain autodiff of ``lm_head`` and the engine's loss
+    over logits: the loss to an f32 ulp (it weights before it sums), the
+    accuracy equal, both gradients to the order of accumulation in f32
+    and to two bf16 ulps in bf16 (the rule rounds where autodiff rounds:
+    the logits before the f32 softmax, ``d_logits`` before both
+    products, each gradient to its operand's dtype)."""
+    from distributed_learning_simulator_tpu.parallel.engine import (
+        make_loss_fn)
+
+    kernel, x, y, dtype = _head_case(dtype, kernel_dtype, length)
+    mask = jnp.asarray([1.0, 1.0])
+    params = {"kernel": kernel, "scale": jnp.float32(1.5)}
+
+    def unmade(variables, x):
+        p = variables["params"]
+        return so.UnmadeLogits(p["kernel"], x * p["scale"], dtype), {}
+
+    def plain(variables, x):
+        p = variables["params"]
+        return so.lm_head(p["kernel"], x * p["scale"], dtype=dtype), {}
+
+    def run(apply):
+        (loss, (acc, _)), grads = jax.jit(jax.value_and_grad(
+            make_loss_fn(apply), argnums=(0, 1), has_aux=True,
+        ))(params, x, y, mask)
+        return loss, acc, grads
+
+    loss, acc, (grads, d_x) = run(unmade)
+    want_loss, want_acc, (want, want_d_x) = run(plain)
+    assert float(loss) == pytest.approx(float(want_loss), rel=3e-7)
+    assert float(acc) == float(want_acc)
+    assert grads["kernel"].dtype == kernel.dtype and d_x.dtype == x.dtype
+    # f32: the order of accumulation (the rule's products are einsums of
+    # their own); bf16: two ulps of the rounded gradient.
+    tol = 1e-6 if dtype == jnp.float32 else 2 * 2.0 ** -8
+    for got, ref in ((grads["kernel"], want["kernel"]), (d_x, want_d_x),
+                     (grads["scale"], want["scale"])):
+        got, ref = (np.asarray(a, np.float64) for a in (got, ref))
+        assert np.all(np.abs(got - ref) <= tol * np.max(np.abs(ref)))
+    # Not differentiated (the evaluation), it is the plain forward.
+    sums, correct = unmade({"params": params}, x)[0].weighted_nll(
+        y, jnp.ones(y.shape))
+    logits = plain({"params": params}, x)[0]
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+    want_sum = -jnp.sum(jnp.take_along_axis(logp, y[..., None], -1))
+    assert float(sums) == pytest.approx(float(want_sum), rel=3e-7)
+    assert float(correct) == float(jnp.sum(jnp.argmax(logits, -1) == y))
+
+
+def test_one_local_step_makes_the_logits_once():
+    """One local step's loss and gradient hold three products of the
+    head's shape (the logits, ``d_x``, ``d_kernel``) and hand nothing of
+    vocabulary width from the gradient's forward rule to its backward
+    rule: the residuals are the two gradients and two per-position
+    vectors. With a plain head handing logits to the engine's loss, the
+    same program keeps vocabulary-wide residuals."""
+    from distributed_learning_simulator_tpu.parallel.engine import (
+        make_loss_fn)
+
+    model = get_model("solar_open2", num_classes=96, **share_args())
+    params = make_params()
+    tokens = jnp.zeros((2, 40), jnp.int32)  # 80 positions: 96 is the head
+    mask = jnp.ones((2,))
+    wide = (2, 40, 96)
+
+    def head_products(jaxpr):
+        return [
+            eqn for eqn in _equations(jaxpr)
+            if eqn.primitive.name == "dot_general" and 96 in {
+                d for v in (*eqn.invars, *eqn.outvars)
+                for d in v.aval.shape}
+        ]
+
+    def residual_shapes(apply):
+        _, pull = jax.vjp(
+            lambda p: make_loss_fn(apply)(p, tokens, tokens, mask)[0],
+            params)
+        return [leaf.shape for leaf in jax.tree_util.tree_leaves(pull)]
+
+    step = jax.value_and_grad(make_loss_fn(model.apply), has_aux=True)
+    jaxpr = jax.make_jaxpr(step)(params, tokens, tokens, mask).jaxpr
+    assert len(head_products(jaxpr)) == 3
+    assert wide not in residual_shapes(model.apply)
+
+    def plain_apply(variables, x):
+        head, counts = model.apply(variables, x)
+        return head.logits(), counts
+
+    assert wide in residual_shapes(plain_apply)
 
 
 # --- through run_simulation --------------------------------------------------
@@ -390,6 +523,7 @@ def _run(tmp_path, chunk, **over):
         "--eval_batch_size", "4", "--optimizer_name", "sgd",
         "--learning_rate", "0.1", "--momentum", "0",
         "--distributed_algorithm", "fed", "--telemetry_level", "basic",
+        "--span_trace", "on",
         "--log_root", str(tmp_path / f"log{chunk}"),
         "--compilation_cache_dir", "none",
     ]
@@ -406,6 +540,18 @@ def test_one_client_in_flight_is_the_stacked_round(tmp_path):
     stacked, stacked_counts = _run(tmp_path, 4)
     assert one_counts["client_axis_width"] == 1
     assert stacked_counts["client_axis_width"] == 4
+    # The model's head makes its loss and its gradients itself.
+    assert one_counts["head_backward_tied"] == 1
+    assert stacked_counts["head_backward_tied"] == 1
+    journal = one["span_summary"]["journal_path"]
+    with open(journal) as f:
+        events = [json.loads(line) for line in f]
+    assert [e["attrs"]["value"] for e in events
+            if e.get("cat") == "counter"
+            and e["name"] == "head_backward_tied"] == [1]
+    timeline = _module_at("scripts", "trace_timeline")
+    assert "head_backward_tied: 1" in timeline.render_text(
+        timeline.summarize([timeline.load_journal(journal)]))
     for a, b in zip(one["history"], stacked["history"]):
         for name in ("test_loss", "mean_client_loss"):
             assert a[name] == pytest.approx(b[name], rel=1e-5)

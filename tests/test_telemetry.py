@@ -522,6 +522,7 @@ def test_local_steps_unrolled_counter(tiny_config, tmp_path, batch_size,
     assert [(e["kind"], e["name"], e["attrs"]["value"]) for e in events] == [
         ("event", "local_steps_unrolled", unrolled),
         ("event", "client_axis_width", 4),  # the 4 clients in one chunk
+        ("event", "head_backward_tied", 0),  # this model hands on logits
         ("event", "global_donated", 0),  # a pipelined loop keeps the global
     ]
     spec = importlib.util.spec_from_file_location(
@@ -533,6 +534,22 @@ def test_local_steps_unrolled_counter(tiny_config, tmp_path, batch_size,
     spec.loader.exec_module(tt)
     rendered = tt.render_text(tt.summarize([tt.load_journal(path)]))
     assert f"local_steps_unrolled: {unrolled}" in rendered
+    assert "head_backward_tied: 0" in rendered
+
+
+def test_run_simulation_keeps_its_frame():
+    """``run_simulation`` is one function whose frame lies above the first
+    round's lowering: its slots (names + stack) decide where CPython's
+    data stack crosses a chunk there, and ``setup_s`` moves by seconds
+    with a few of them (PERF.md § 6, the data-stack cliff). 164 + 23 since
+    PR 31; change the numbers only with a chip run that compares
+    ``setup_trace_lower_s`` against the parent's."""
+    from distributed_learning_simulator_tpu.simulator import run_simulation
+
+    code = run_simulation.__code__
+    names = set(code.co_varnames) | set(code.co_cellvars) | set(
+        code.co_freevars)
+    assert (len(names), code.co_stacksize) == (164, 23)
 
 
 def test_simulator_telemetry_off_keeps_v1_records(tiny_config, tmp_path):
