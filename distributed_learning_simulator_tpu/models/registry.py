@@ -11,6 +11,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from distributed_learning_simulator_tpu.models.afmoe import afmoe
 from distributed_learning_simulator_tpu.models.cnn import (
     MLP,
     CifarCNN,
@@ -32,6 +33,10 @@ _MODELS = {
     # Token sequences; ``num_classes`` is the vocabulary held, and
     # ``--model_args`` the share (heads_held, experts_held, vocab_rows).
     "solaropen2": solar_open2,
+    # Trinity-Mini's family: banded sliding-window attention with rotary
+    # positions beside gated NoPE full attention, a leading dense layer;
+    # ``--model_args`` the share (experts_held, expert_offset, vocab_rows).
+    "afmoe": afmoe,
 }
 
 

@@ -32,14 +32,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import inspect
 import math
-from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from distributed_learning_simulator_tpu.models import lm_parts as parts
 from distributed_learning_simulator_tpu.models.traced_helpers import (
     HIGHEST,
     causal_conv as _causal_conv,
@@ -52,21 +51,6 @@ from distributed_learning_simulator_tpu.models.traced_helpers import (
 #: Positions per chunk of the delta rule and per sub-block of a chunk.
 KDA_CHUNK = 64
 KDA_SUB = 16
-#: Queries per block of the softmax attention (its scores are
-#: materialised a block at a time).
-GQA_QUERY_BLOCK = 512
-#: Slots a held expert has, as a multiple of its even share of a batch's
-#: assignments (rounded up to 128 rows, 8 below 128); beyond it the layer
-#: falls back to every token (moe_experts).
-EXPERT_CAPACITY_FACTOR = 2.0
-
-
-def _normal(fan_in: int):
-    def init(key, shape, dtype=jnp.float32):
-        return jax.random.normal(key, shape, dtype) / math.sqrt(fan_in)
-
-    return init
-
 
 # --- the gated delta rule, chunked -----------------------------------------
 
@@ -242,60 +226,33 @@ def kda_mixer(p, x, *, heads: int, head_dim: int, eps: float, dtype):
 
 
 def gqa_mixer(p, x, *, heads: int, kv_heads: int, head_dim: int, dtype,
-              query_block: int = GQA_QUERY_BLOCK):
+              query_block: int = parts.QUERY_BLOCK):
     """Causal softmax attention without positions over the query heads
     held here and their key/value heads, gated per output channel."""
     with jax.named_scope("gqa"):
-        B, T, _ = x.shape
-        group = heads // kv_heads
-        q = _mm(x, p["q"], dtype).reshape(B, T, kv_heads, group, head_dim)
-        k = _mm(x, p["k"], dtype).reshape(B, T, kv_heads, head_dim)
-        v = _mm(x, p["v"], dtype).reshape(B, T, kv_heads, head_dim)
-        block = query_block if T % query_block == 0 else T
+        return parts.gated_attention(
+            p, x, heads=heads, kv_heads=kv_heads, head_dim=head_dim,
+            dtype=dtype, query_block=query_block)
 
-        @jax.checkpoint
-        def attend(args):
-            # One block of queries against every key: the scores of a
-            # block are all that is ever live, forward or backward.
-            q_blk, first = args  # [B, block, kv, group, d]
-            s = _ein("btkgd,bskd->bkgts", q_blk, k, dtype) / math.sqrt(
-                head_dim)
-            visible = (
-                first + jnp.arange(block)[:, None] >= jnp.arange(T)[None, :]
-            )
-            w = jax.nn.softmax(jnp.where(visible, s, -jnp.inf), axis=-1)
-            return _ein("bkgts,bskd->btkgd", w, v, dtype)
 
-        blocks = jnp.moveaxis(
-            q.reshape(B, T // block, block, kv_heads, group, head_dim), 1, 0
-        )
-        o = jax.lax.map(
-            attend, (blocks, jnp.arange(T // block) * block)
-        )  # [T / block, B, block, kv, group, d]
-        o = jnp.moveaxis(o, 0, 1)
-        gate = jax.nn.sigmoid(_mm(x, p["g"], dtype))
-        return _mm(o.reshape(B, T, heads * head_dim) * gate, p["o"], dtype)
+# Each of the functions below is the scope's own line around the shared
+# implementation (models/lm_parts.py, which is not user code to JAX): a
+# device trace gives the ops made there THIS file's line.
 
 
 def moe_route(p, x, *, top_k: int, expert_offset: int, experts_held: int):
-    """Score every expert of the layer (f32: a near-tie between the
-    ``top_k``-th and the next score decides where a token goes), choose
-    ``top_k``, normalise their scores; ``x`` ``[N, D]``. Returns the
-    combine weight of each held expert for each token ``[N, held]`` (0
-    where the token did not choose it)."""
+    """Sigmoid scores over every expert of the layer, the ``top_k``
+    largest, normalised: the combine weight of each held expert for each
+    token ``[N, held]`` (:func:`lm_parts.route`)."""
     with jax.named_scope("moe/route"):
-        scores = jax.nn.sigmoid(_mm(x, p["router"], jnp.float32))
-        top, index = jax.lax.top_k(scores, top_k)
-        weight = top / jnp.sum(top, -1, keepdims=True)
-        local = index - expert_offset  # [N, top_k]
-        held = jnp.arange(experts_held)
-        return jnp.sum(
-            weight[..., None] * (local[..., None] == held), axis=-2
-        )
+        return parts.route(p["router"], x, top_k=top_k,
+                           expert_offset=expert_offset,
+                           experts_held=experts_held)
 
 
 def moe_experts(p, x, combine, *, capacity: int, dtype):
-    """The held experts' part of the layer for the tokens routed to them.
+    """The held experts' part of the layer for the tokens routed to them
+    (:func:`lm_parts.experts`, shared with ``models/afmoe.py``).
 
     Each held expert gathers the tokens that chose it into ``capacity``
     slots and the experts run as one grouped product over ``[held,
@@ -303,172 +260,47 @@ def moe_experts(p, x, combine, *, capacity: int, dtype):
     in the batch. No token is dropped: when any held expert is chosen by
     more than ``capacity`` tokens the layer computes every held expert
     over every token with the combine weights as a mask (``lax.cond``;
-    counted in ``overflow``). Returns ``(y [N, D], load [held], overflow)``.
+    counted in ``overflow``).
+
+    ``p`` holds ``gate``, ``up`` ``[held, D, F]`` and ``down`` ``[held,
+    F, D]``; ``x`` ``[N, D]`` the normed tokens; ``combine`` ``[N,
+    held]`` the weights :func:`moe_route` gave (0 where a token did not
+    choose the expert). Returns ``(y [N, D] f32, load [held] int32: the
+    assignments that fell to each held expert, overflow: whether the
+    fallback ran)``.
+
+    The body is one line on purpose: the implementation is registered
+    as not being user code, so every op it makes carries THIS function's
+    line in a device trace, and ``scope_of_line`` gives it to
+    ``moe/experts``.
     """
     with jax.named_scope("moe/experts"):
-        n_tokens = x.shape[0]
-        chosen = combine > 0  # [N, held]
-        load = jnp.sum(chosen, axis=0).astype(jnp.int32)
-
-        def experts(xg):  # [held, rows, D]
-            gate = _ein("erd,edf->erf", xg, p["gate"], dtype)
-            up = _ein("erd,edf->erf", xg, p["up"], dtype)
-            return _ein("erf,efd->erd", jax.nn.silu(gate) * up, p["down"],
-                        dtype)
-
-        def grouped(_):
-            # The first ``capacity`` choosers of each expert, by position.
-            order = jnp.where(
-                chosen, (n_tokens - jnp.arange(n_tokens))[:, None], 0
-            ).astype(jnp.float32).T  # [held, N]
-            rank, index = jax.lax.top_k(order, capacity)
-            weight = jnp.where(
-                rank > 0, jnp.take_along_axis(combine.T, index, axis=1), 0.0
-            )  # [held, capacity]
-            y = experts(jnp.take(x, index, axis=0)) * weight[..., None]
-            return jnp.zeros((n_tokens, x.shape[-1]), jnp.float32).at[
-                index.reshape(-1)
-            ].add(y.reshape(-1, y.shape[-1]))
-
-        def every_token(_):
-            # One held expert after another over every token, the
-            # combine weight as the mask.
-            @jax.checkpoint
-            def one(y, expert):
-                gate, up, down, weight = expert
-                hidden = jax.nn.silu(_mm(x, gate, dtype)) * _mm(x, up, dtype)
-                return y + _mm(hidden, down, dtype) * weight[:, None], None
-
-            y, _ = jax.lax.scan(
-                one, jnp.zeros((n_tokens, x.shape[-1]), jnp.float32),
-                (p["gate"], p["up"], p["down"], combine.T),
-            )
-            return y
-
-        overflow = jnp.any(load > capacity)
-        if capacity >= n_tokens:
-            return every_token(None), load, overflow
-        return jax.lax.cond(overflow, every_token, grouped, None), load, \
-            overflow
+        return parts.experts(p, x, combine, capacity=capacity, dtype=dtype)
 
 
 def moe_shared(p, x, *, dtype):
     with jax.named_scope("moe/shared"):
-        hidden = jax.nn.silu(_mm(x, p["shared_gate"], dtype)) * _mm(
-            x, p["shared_up"], dtype
-        )
-        return _mm(hidden, p["shared_down"], dtype)
+        return parts.swiglu(x, p["shared_gate"], p["shared_up"],
+                            p["shared_down"], dtype=dtype)
 
 
 def lm_head(kernel, x, *, dtype):
-    """Logits over the rows of the vocabulary held, accumulated f32 and
-    kept in ``dtype``: at ``[tokens, 24576]`` they are the largest
-    activation of the step, and the loss takes its softmax in f32.
-    The model does not hand them to the engine: :func:`head_nll`."""
+    """The logits themselves (:func:`lm_parts.lm_head`); the model does
+    not hand them to the engine: :func:`head_nll`."""
     with jax.named_scope("lm_head"):
-        return _mm(x, kernel, dtype).astype(dtype)
-
-
-def _head_loss(kernel, x, targets, weight):
-    """The head's logits, made once, and everything of vocabulary width
-    that the loss and its gradient need from them; ``kernel`` and ``x``
-    are in the products' dtype. The values are ``jax.nn.log_softmax``'s
-    and ``take_along_axis``'s, operation for operation."""
-    logits = lm_head(kernel, x, dtype=x.dtype)
-    f32 = logits.astype(jnp.float32)
-    shifted = f32 - jnp.max(f32, axis=-1, keepdims=True)
-    e = jnp.exp(shifted)
-    total = jnp.sum(e, axis=-1, keepdims=True)
-    hit = jnp.arange(f32.shape[-1]) == targets[..., None]
-    nll = jnp.log(total)[..., 0] - jnp.sum(
-        jnp.where(hit, shifted, 0.0), axis=-1)
-    correct = (jnp.argmax(logits, axis=-1) == targets).astype(jnp.float32)
-    out = jnp.sum(weight * nll), jnp.sum(weight * correct)
-    return out, (e, total, hit, nll, correct)
-
-
-@jax.custom_vjp
-def _head_nll(kernel, x, targets, weight):
-    return _head_loss(kernel, x, targets, weight)[0]
-
-
-def _head_nll_forward(kernel, x, targets, weight):
-    with jax.named_scope("lm_head"):
-        out, (e, total, hit, nll, correct) = _head_loss(
-            kernel, x, targets, weight)
-        # d(sum weight * nll) / d logits = weight * (softmax - one_hot)
-        # in autodiff's order of operations, rounded to the logits' dtype
-        # as autodiff rounds the cotangent of ``.astype(dtype)``, and
-        # written out ONCE (the barrier): left free it is fused into
-        # both products, or written out in f32, twice the bytes.
-        w = weight[..., None]
-        share = e * (w / total)
-        d_logits = jax.lax.optimization_barrier(
-            jnp.where(hit, share - w, share).astype(x.dtype))
-        # Autodiff's two products: operands in ``dtype``, accumulated
-        # f32, each gradient rounded to its operand's dtype.
-        d_kernel = _ein("...d,...v->dv", x, d_logits, x.dtype).astype(
-            kernel.dtype)
-        d_x = _ein("...v,dv->...d", d_logits, kernel, x.dtype).astype(
-            x.dtype)
-        # Tied: the input gradient cannot leave for the blocks' backward
-        # before the weight gradient exists. Left free, the weight
-        # gradient (it feeds only the parameter update) is scheduled
-        # after the blocks' backward, and what it reads is dropped and
-        # made again for it. Nothing of vocabulary width leaves this rule.
-        d_kernel, d_x = jax.lax.optimization_barrier((d_kernel, d_x))
-        return out, (d_kernel, d_x, nll, correct)
-
-
-def _head_nll_backward(residuals, cotangents):
-    d_kernel, d_x, nll, correct = residuals
-    ct, ct_correct = cotangents  # 1.0 and 0.0 under value_and_grad
-    with jax.named_scope("lm_head"):
-        return (
-            (ct * d_kernel).astype(d_kernel.dtype),
-            (ct * d_x).astype(d_x.dtype),
-            None,  # integer targets
-            ct * nll + ct_correct * correct,
-        )
-
-
-_head_nll.defvjp(_head_nll_forward, _head_nll_backward)
+        return parts.lm_head(kernel, x, dtype=dtype)
 
 
 def head_nll(kernel, x, targets, weight, *, dtype):
-    """``(sum weight * nll, sum weight * correct)`` of softmax
-    cross-entropy over the head's logits, one target and one weight a
-    position, WITHOUT handing the logits on: the forward rule of the
-    gradient makes the logits once, takes the f32 softmax, and makes
-    ``d_logits``, the input gradient ``d_logits @ kernel^T`` and the
-    weight gradient ``x^T @ d_logits`` there and then; the backward rule
-    scales those two by the scalar cotangent. Roundings are plain
-    autodiff's: logits rounded to ``dtype`` before the f32 softmax,
-    ``d_logits`` rounded to ``dtype`` before both products.
-
-    Do not simplify this back to ``lm_head`` plus the engine's loss, and
-    keep both barriers of the forward rule. Handed the logits, plain
-    autodiff made the loss's backward out of vocabulary-wide f32 tensors
-    (the cotangent of ``take_along_axis`` scattered into a dense ``[4096,
-    24576]`` f32 array over a broadcast of zeros, 403 MB each, then a
-    604 MB subtract), and XLA's rematerialisation dropped the 201 MB
-    logits after the forward softmax and made the product again for the
-    backward: a pair ``fusion.N`` / ``fusion.N.remat`` in each of the two
-    unrolled local steps, 4.62 ms an execution at 91 % of the MXU's
-    peak, 2 x 36.96 = 73.9 ms of a 2502.5 ms round in
-    ``solar_open2_fed_seq4k_c8`` (PERF_LEDGER.jsonl, PR 31). Room did not
-    cure it (PR 30 donated the global model and the compiler spent the
-    3.4 GB elsewhere). Nor did a barrier on the head's two gradients
-    alone (the twin's consumer was the loss's backward), nor this rule
-    without its barriers: the weight gradient feeds only the parameter
-    update, so XLA fused the softmax into it, scheduled it after the
-    blocks' backward and remade the logits for it there. What each form
-    compiled to, and what the chip read: PERF.md § 6, PR 32.
-    """
+    """The head's loss and both its gradients, made in the head
+    (:func:`lm_parts.head_nll`, which says why): no logits leave it."""
     with jax.named_scope("lm_head"):
-        return _head_nll(
-            kernel.astype(dtype), x.astype(dtype), targets, weight)
+        return parts.head_nll(kernel, x, targets, weight, dtype=dtype)
 
+
+#: What the model hands on in place of logits, with this file's head.
+UnmadeLogits = functools.partial(parts.UnmadeLogits,
+                                 head=(lm_head, head_nll))
 
 _SCOPES = {
     "kda": (kda_mixer, chunked_delta_rule, _intra_chunk,
@@ -477,85 +309,14 @@ _SCOPES = {
     "moe/route": (moe_route,),
     "moe/experts": (moe_experts,),
     "moe/shared": (moe_shared,),
-    "lm_head": (lm_head, head_nll, _head_loss, _head_nll,
-                _head_nll_forward, _head_nll_backward),
+    "lm_head": (lm_head, head_nll),
 }
 
-
-@functools.cache
-def _scope_lines() -> tuple:
-    """``(first line, last line, scope)`` of every function above."""
-    out = []
-    for scope, functions in _SCOPES.items():
-        for fn in functions:
-            lines, first = inspect.getsourcelines(fn)
-            out.append((first, first + len(lines) - 1, scope))
-    return tuple(out)
-
-
-def scope_of_line(line: int) -> str | None:
-    """The named scope whose code holds source line ``line`` of this
-    file (a device trace gives each op the line it was traced from).
-    Code outside the scopes' functions (the blocks' norms and residual
-    adds) has none."""
-    for first, last, scope in _scope_lines():
-        if first <= line <= last:
-            return scope
-    return None
+#: The named scope whose code holds a source line of this file.
+scope_of_line = parts.scope_lookup(_SCOPES)
 
 
 # --- the module -------------------------------------------------------------
-
-
-@jax.tree_util.register_pytree_node_class
-class UnmadeLogits:
-    """What the model hands on in place of logits: the head's kernel and
-    its normed input. The engine's loss asks it for :meth:`weighted_nll`
-    (:func:`head_nll`: no logits leave the head); whoever wants the
-    logits themselves asks for :meth:`logits`."""
-
-    def __init__(self, kernel, x, dtype):
-        self.kernel, self.x, self.dtype = kernel, x, dtype
-
-    def logits(self):
-        return lm_head(self.kernel, self.x, dtype=self.dtype)
-
-    def weighted_nll(self, targets, weight):
-        return head_nll(self.kernel, self.x, targets, weight,
-                        dtype=self.dtype)
-
-    def tree_flatten(self):
-        return (self.kernel, self.x), self.dtype
-
-    @classmethod
-    def tree_unflatten(cls, dtype, children):
-        return cls(*children, dtype)
-
-
-class _Norm(nn.Module):
-    eps: float
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        return _rms_norm(x, scale, self.eps)
-
-
-class _Params(nn.Module):
-    """A bag of named tensors: the mixers and the expert layer are plain
-    functions of a dict, shared with nothing else."""
-
-    shapes: Any  # ((name, shape, fan_in or "ones"/"zeros"), ...)
-
-    @nn.compact
-    def __call__(self):
-        out = {}
-        for name, shape, kind in self.shapes:
-            init = {
-                "ones": nn.initializers.ones, "zeros": nn.initializers.zeros,
-            }.get(kind) or _normal(kind)
-            out[name] = self.param(name, init, tuple(shape))
-        return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -590,10 +351,8 @@ class Share:
         return self.heads_held // group
 
     def expert_capacity(self, n_tokens: int) -> int:
-        even = n_tokens * self.num_experts_per_tok / self.n_routed_experts
-        rows = math.ceil(even * EXPERT_CAPACITY_FACTOR)
-        tile = 128 if rows >= 128 else 8
-        return min(n_tokens, math.ceil(rows / tile) * tile)
+        return parts.expert_capacity(
+            n_tokens, self.num_experts_per_tok, self.n_routed_experts)
 
 
 class _Block(nn.Module):
@@ -607,10 +366,10 @@ class _Block(nn.Module):
         F = c.moe_intermediate_size
         dtype = jnp.dtype(c.dtype)
         back = c.num_attention_heads * hd
-        x = _Norm(c.rms_norm_eps, name="mixer_norm")(h)
+        x = parts.Norm(c.rms_norm_eps, name="mixer_norm")(h)
         if self.is_gqa:
             kv = c.kv_heads_held
-            p = _Params((
+            p = parts.Params((
                 ("q", (D, H * hd), D), ("k", (D, kv * hd), D),
                 ("v", (D, kv * hd), D), ("g", (D, H * hd), D),
                 ("o", (H * hd, D), back),
@@ -619,7 +378,7 @@ class _Block(nn.Module):
                               dtype=dtype)
         else:
             R, taps = c.gate_rank, c.short_conv_kernel_size
-            p = _Params((
+            p = parts.Params((
                 ("q", (D, H * hd), D), ("k", (D, H * hd), D),
                 ("v", (D, H * hd), D),
                 ("conv_q", (taps, H * hd), taps),
@@ -633,9 +392,9 @@ class _Block(nn.Module):
             ), name="kda")()
             h = h + kda_mixer(p, x, heads=H, head_dim=hd,
                               eps=c.rms_norm_eps, dtype=dtype)
-        x = _Norm(c.rms_norm_eps, name="moe_norm")(h)
+        x = parts.Norm(c.rms_norm_eps, name="moe_norm")(h)
         Eh = c.experts_held
-        p = _Params((
+        p = parts.Params((
             ("router", (D, c.n_routed_experts), D),
             ("gate", (Eh, D, F), D), ("up", (Eh, D, F), D),
             ("down", (Eh, F, D), F),
@@ -680,7 +439,7 @@ class SolarOpen2(nn.Module):
             )
         c = self.share
         D, vocab = c.hidden_size, self.num_classes
-        table = _Params((("table", (vocab, D), 1),), name="embed")()["table"]
+        table = parts.Params((("table", (vocab, D), 1),), name="embed")()["table"]
         h = jnp.take(table, tokens, axis=0).astype(jnp.float32)
         block = nn.remat(_Block)
         loads, overflows = [], []
@@ -690,19 +449,11 @@ class SolarOpen2(nn.Module):
             )(h)
             loads.append(load)
             overflows.append(overflow)
-        x = _Norm(c.rms_norm_eps, name="final_norm")(h)
-        kernel = _Params(
+        x = parts.Norm(c.rms_norm_eps, name="final_norm")(h)
+        kernel = parts.Params(
             (("kernel", (D, vocab), D),), name="lm_head")()["kernel"]
         head = UnmadeLogits(kernel, x, jnp.dtype(c.dtype))
-        load = jnp.stack(loads)  # [layers, held]
-        counts = {
-            "moe_local_assignments": jnp.sum(load, axis=1),
-            "moe_routed_tokens": jnp.full(
-                (c.num_hidden_layers,), tokens.size, jnp.int32),
-            "moe_overflows": jnp.stack(overflows).astype(jnp.int32),
-            "moe_expert_load": load,
-        }
-        return head, counts
+        return head, parts.routing_counts(loads, overflows, tokens.size)
 
 
 def solar_open2(num_classes: int, vocab_rows: int | None = None, **share):

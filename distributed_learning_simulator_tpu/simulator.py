@@ -821,6 +821,18 @@ def run_simulation(
             "head_backward_tied",
             int(getattr(model, "head_backward_tied", False)),
         )
+        # The banded attention's window, and the keys a block of queries
+        # reads there at this data's positions (models/afmoe.py; the
+        # positions themselves would say the band did not run); 0 where
+        # no layer is windowed.
+        tracer.set_counter(
+            "attention_window", int(getattr(model, "attention_window", 0)),
+        )
+        tracer.set_counter(
+            "swa_keys_per_query_block",
+            int(getattr(model, "swa_keys_per_query_block", lambda _: 0)(
+                dataset.x_train.shape[-1])),
+        )
 
         # Optional server-side optimizer (FedOpt; exceeds the reference): the
         # aggregate is post-processed by a jitted pseudo-gradient step.

@@ -627,7 +627,8 @@ class SpanRecorder(_Sections):
         intervals), ``host_syncs`` (completed ``host_sync`` spans),
         ``rounds`` (completed rounds) and what :meth:`set_counter` was
         given (``local_steps_unrolled``, ``client_axis_width``,
-        ``head_backward_tied``, ``global_donated``)."""
+        ``head_backward_tied``, ``global_donated``, ``attention_window``,
+        ``swa_keys_per_query_block``)."""
         events = self.duration_events()
         with self._lock:
             cut = self._first_round_t

@@ -200,6 +200,30 @@ def test_attention_in_query_blocks_is_attention():
     )
 
 
+@pytest.mark.parametrize("length,block", [(96, 40), (70, 32)])
+def test_attention_pads_its_last_block_of_queries(length, block):
+    """A length that is not a multiple of the query block still runs a
+    block at a time, the last block padded with queries whose rows are
+    dropped; it used to fall back, silently, to ONE block of every query
+    (at 8,192 positions and 32 heads: 8.6 GB of scores)."""
+    p, x = _layer_case("gqa", length=length)
+    kw = dict(heads=4, kv_heads=2, head_dim=16, dtype=jnp.float32,
+              query_block=block)
+    close(so.gqa_mixer(p, x, **kw), ref.gqa(MODEL, p, x, **PRODUCTS))
+    text = str(jax.make_jaxpr(lambda p, x: so.gqa_mixer(p, x, **kw))(p, x))
+    assert f"{block},{length}]" in text  # scores: a block against the keys
+    assert f"{length},{length}]" not in text
+
+    def grads(fn):
+        return jax.grad(lambda p, x: jnp.sum(jnp.sin(fn(p, x))),
+                        argnums=(0, 1))(p, x)
+
+    tree_close(
+        grads(lambda p, x: so.gqa_mixer(p, x, **kw)),
+        grads(lambda p, x: ref.gqa(MODEL, p, x, **PRODUCTS)), 5e-4,
+    )
+
+
 @pytest.mark.parametrize("factor,overflows", [(1.5, False), (0.25, True)])
 def test_no_token_is_dropped_whatever_the_capacity(
         factor, overflows, monkeypatch):
@@ -208,7 +232,7 @@ def test_no_token_is_dropped_whatever_the_capacity(
     Either way the reference's result, and the load counts every
     assignment."""
     p, x = _layer_case("moe", length=96)
-    monkeypatch.setattr(so, "EXPERT_CAPACITY_FACTOR", factor)
+    monkeypatch.setattr(so.parts, "EXPERT_CAPACITY_FACTOR", factor)
     c = so.Share(**share_args())
     assert c.expert_capacity(2 * 96) < 2 * 96  # the grouped path is there
     y, load, overflow = _program_layer("moe", p, x)

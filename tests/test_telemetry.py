@@ -523,6 +523,8 @@ def test_local_steps_unrolled_counter(tiny_config, tmp_path, batch_size,
         ("event", "local_steps_unrolled", unrolled),
         ("event", "client_axis_width", 4),  # the 4 clients in one chunk
         ("event", "head_backward_tied", 0),  # this model hands on logits
+        ("event", "attention_window", 0),  # no layer is windowed
+        ("event", "swa_keys_per_query_block", 0),
         ("event", "global_donated", 0),  # a pipelined loop keeps the global
     ]
     spec = importlib.util.spec_from_file_location(
