@@ -61,8 +61,9 @@ def _attention(c) -> dict:
 
 def swa(p, x, c):
     """A sliding layer's attention, projections to output: rotary
-    positions, and a block of queries reads only the keys of its band
-    (:func:`lm_parts.banded_attention`)."""
+    positions, and a query reads only the keys of its band (the fused
+    kernel on a TPU at shapes it takes, else
+    :func:`lm_parts.banded_attention`: :func:`lm_parts.attention_core`)."""
     with jax.named_scope("swa"):
         return parts.gated_attention(
             p, x, window=c.sliding_window, rope_theta=c.rope_theta,
@@ -71,7 +72,8 @@ def swa(p, x, c):
 
 def attn_full(p, x, c):
     """A full layer's attention, no positions: Solar-Open2's GQA layer
-    with a norm on ``q`` and ``k`` (:func:`lm_parts.causal_attention`)."""
+    with a norm on ``q`` and ``k`` (the fused kernel or
+    :func:`lm_parts.causal_attention`: :func:`lm_parts.attention_core`)."""
     with jax.named_scope("attn_full"):
         return parts.gated_attention(p, x, **_attention(c))
 
@@ -151,10 +153,12 @@ class Share:
     moe_intermediate_size: int = 1024
     route_scale: float = 2.826
     rms_norm_eps: float = 1e-5
-    #: Queries a block of the attention's scores. 128, not Solar-Open2's
-    #: 512: at 8,192 positions a sliding layer's forward and backward take
-    #: 32.7 ms for 54.6 and the full layer's 126 for 146 (one v5e, this
-    #: layer alone; PERF.md § 6, PR 33).
+    #: Queries a block of the attention's scores in the XLA form, which is
+    #: all it sizes (the fused kernel that takes these layers on a TPU has
+    #: blocks of its own, ``lm_parts.FUSED_BLOCKS``). 128, not
+    #: Solar-Open2's 512: at 8,192 positions a sliding layer's forward and
+    #: backward took 32.7 ms for 54.6 and the full layer's 126 for 146 in
+    #: that form (one v5e, this layer alone; PERF.md § 6, PR 33).
     query_block: int = 128
     dtype: str = "bfloat16"
 
@@ -251,12 +255,25 @@ class AFMoE(nn.Module):
 
     def swa_keys_per_query_block(self, positions: int) -> int:
         """The recorder's counter: keys a block of queries reads on a
-        banded layer at sequences of ``positions`` (``positions`` itself
-        says every key is read: the band did not run)."""
+        banded layer at sequences of ``positions`` IN THE XLA FORM
+        (``positions`` itself says every key is read: the band did not
+        run). Where the fused kernel takes the layer
+        (:meth:`fused_attention_layers`) this is what the XLA band would
+        read; the kernel's blocks are ``lm_parts.FUSED_BLOCKS``."""
         if not self.attention_window:
             return 0
         return parts.band_keys(positions, self.share.sliding_window,
                                self.share.query_block)
+
+    def fused_attention_layers(self, positions: int) -> int:
+        """Layers whose attention core is the fused kernel where the
+        program is lowered for a TPU, at sequences of ``positions``
+        (:func:`lm_parts.fused_attention_applies`: by shapes, so all the
+        layers or none). The recorder's counter of the same name is this
+        on a TPU and 0 anywhere else."""
+        c = self.share
+        return c.num_hidden_layers * parts.fused_attention_applies(
+            positions, c.head_dim, c.dtype)
 
     @nn.compact
     def __call__(self, tokens):

@@ -1,7 +1,14 @@
 """The parts the sparse language models share (models/solar_open2.py,
-models/afmoe.py): softmax attention a block of queries at a time (every
-key, or a band of them), sigmoid routing, the experts told their share,
-a SwiGLU, and the head that makes its own loss.
+models/afmoe.py): softmax attention (every key at or before the query, or
+a band of them) in two forms, sigmoid routing, the experts told their
+share, a SwiGLU, and the head that makes its own loss.
+
+The attention's core (scores, mask, softmax, weighted values, forward and
+backward) has an XLA form, a block of queries at a time with the block's
+scores written out (:func:`causal_attention`, :func:`banded_attention`),
+and a fused form that keeps them on chip (:func:`fused_attention`: the
+installed splash-attention kernel). :func:`attention_core` chooses by what
+it can observe: the platform the program is LOWERED for and the shapes.
 
 A device trace names each op by the innermost frame of user code it was
 traced from, and a model's per-scope device times are read by the lines
@@ -19,7 +26,7 @@ from __future__ import annotations
 import functools
 import inspect
 import math
-from typing import Any
+from typing import Any, NamedTuple
 
 import flax.linen as nn
 import jax
@@ -34,9 +41,28 @@ from distributed_learning_simulator_tpu.models.traced_helpers import (
 
 source_info_util.register_exclusion(__file__)
 
-#: Queries per block of the softmax attention (its scores are
+#: Queries per block of the softmax attention's XLA form (its scores are
 #: materialised a block at a time).
 QUERY_BLOCK = 512
+
+
+class FusedBlocks(NamedTuple):
+    """Blocks of the fused attention kernel along the sequence: queries
+    and keys a grid step, keys a product inside it. The backward takes
+    the same, in ONE kernel (dk/dv with dq's partial sums; splash
+    attention's ``use_fused_bwd_kernel``)."""
+
+    q: int
+    kv: int
+    kv_compute: int
+
+
+#: Constants read on the chip from the layers timed alone at 8,192 and
+#: 4,096 positions, both masks (PERF.md § 6, PR 34: the full layer 8.4 /
+#: 24.9 ms forward / forward + backward for 9.4 / 28.6 at 512s and 46.5 /
+#: 154 at the kernel's default 128s with its two backward kernels; 2,048
+#: queries do not fit VMEM), not knobs.
+FUSED_BLOCKS = FusedBlocks(q=1024, kv=1024, kv_compute=512)
 #: Slots a held expert has, as a multiple of its even share of a batch's
 #: assignments (rounded up to 128 rows, 8 below 128); beyond it the layer
 #: falls back to every token (experts).
@@ -160,6 +186,115 @@ def rotary(x, theta: float):
         [-x2, x1], axis=-1) * jnp.sin(angle)
 
 
+def fused_attention_applies(T: int, head_dim: int, dtype,
+                            blocks=FUSED_BLOCKS) -> bool:
+    """Whether the fused kernel takes sequences of ``T`` positions over
+    heads of ``head_dim`` with products in ``dtype``, by shapes alone:
+    the head a multiple of the 128 lanes, ``T`` whole blocks of the
+    kernel, and the products in bfloat16 (the kernel multiplies f32
+    operands in one bf16 pass, which is not what an f32 configuration
+    states). Any other shape keeps the XLA form and its padded last
+    block. Whether it RUNS is also the lowering platform's to say
+    (:func:`attention_core`)."""
+    return (head_dim % 128 == 0 and T % max(blocks.q, blocks.kv) == 0
+            and jnp.dtype(dtype) == jnp.bfloat16)
+
+
+@functools.lru_cache(maxsize=None)
+def _fused_kernel(T: int, window: int | None, group: int, blocks,
+                  interpret: bool):
+    """The splash-attention kernel of ``group`` query heads over ONE
+    key/value head (its MQA form) at ``T`` positions, built once per
+    shape and not once a layer a trace: the mask's block tables are
+    made on the host. ``LocalMask((window - 1, 0))`` is ``0 <= i - j <
+    window``."""
+    # Imported here: a second of import that only a program with such a
+    # layer pays.
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as _splash,
+        splash_attention_mask as _splash_mask,
+    )
+
+    shape = (T, T)
+    mask = _splash_mask.CausalMask(shape) if window is None else (
+        _splash_mask.LocalMask(shape, (window - 1, 0), 0))
+    with jax.ensure_compile_time_eval():
+        return _splash.make_splash_mqa_single_device(
+            _splash_mask.MultiHeadMask([mask] * group),
+            block_sizes=_splash.BlockSizes(
+                block_q=blocks.q, block_kv=blocks.kv,
+                block_kv_compute=blocks.kv_compute,
+                block_q_dkv=blocks.q, block_kv_dkv=blocks.kv,
+                block_kv_dkv_compute=blocks.kv_compute,
+                use_fused_bwd_kernel=True),
+            interpret=interpret)
+
+
+def fused_attention(q, k, v, *, window: int | None, dtype,
+                    blocks=FUSED_BLOCKS, interpret: bool = False):
+    """:func:`causal_attention` (``window`` None) or
+    :func:`banded_attention` as ONE fused kernel, forward and backward:
+    a block of queries against a block of keys at a time with an online
+    softmax, the scores never leaving VMEM, and key blocks the mask hides
+    whole never read. Shapes as :func:`causal_attention`; ``T`` and the
+    head must satisfy :func:`fused_attention_applies`. Products in
+    ``dtype`` accumulated f32, the softmax f32; ``1 / sqrt(d)`` goes into
+    ``q`` in f32 before the cast. The kernel hands its output on in
+    ``dtype`` (returned as f32 like the XLA form's, whose f32 the gate
+    multiply and the output projection round one step later); its
+    residuals are ``q``, ``k``, ``v``, the output and the rows' log-sum-
+    exp. ``interpret`` runs the kernel in Pallas's interpreter (the CPU
+    tests)."""
+    T, group, head_dim = q.shape[1], q.shape[3], q.shape[4]
+    kernel = _fused_kernel(T, window, group, blocks, interpret)
+    q = (q.astype(jnp.float32) / math.sqrt(head_dim)).astype(dtype)
+    # JAX registers its Pallas ops as user code, and an inclusion beats
+    # this file's exclusion: left alone the kernel's ops would carry a
+    # line of splash_attention_kernel.py and no scope's time would hold
+    # them. They are given the line everything else made here carries:
+    # the caller's. (JAX keeps the kernel's traced body by its shapes,
+    # lines and all: a second model of the same attention shapes in one
+    # process would show the first one's lines. A run holds one model.)
+    here = source_info_util.current()
+    with source_info_util.user_context(here.traceback,
+                                       name_stack=here.name_stack):
+        o = jax.vmap(jax.vmap(kernel))(  # over B and kv
+            jnp.transpose(q, (0, 2, 3, 1, 4)),  # [group, T, d] a call
+            jnp.transpose(k.astype(dtype), (0, 2, 1, 3)),  # [T, d]
+            jnp.transpose(v.astype(dtype), (0, 2, 1, 3)),
+        )  # [B, kv, group, T, d]
+    return jnp.transpose(o, (0, 3, 1, 2, 4)).astype(jnp.float32)
+
+
+def attention_core(q, k, v, *, window: int | None, dtype,
+                   query_block: int = QUERY_BLOCK):
+    """Softmax attention of each query over the keys it sees (every key
+    at or before it, or with ``window`` the band ``0 <= i - j <
+    window``), in the form the code can observe to be the right one:
+
+    * shapes the kernel does not take (:func:`fused_attention_applies`):
+      the XLA form, as ever;
+    * else ``lax.platform_dependent``: :func:`fused_attention` where the
+      program is LOWERED for a TPU (a compile for a described chip under
+      ``JAX_PLATFORMS=cpu`` included), the XLA form on any other
+      platform (the CPU tests' path, and the oracle).
+
+    No flag, no configuration field, no look at ``jax.default_backend()``:
+    tracing does not know the platform, lowering does."""
+    def xla(q, k, v):
+        if window is None:
+            return causal_attention(q, k, v, dtype=dtype,
+                                    query_block=query_block)
+        return banded_attention(q, k, v, window=window, dtype=dtype,
+                                query_block=query_block)
+
+    if not fused_attention_applies(q.shape[1], q.shape[-1], dtype):
+        return xla(q, k, v)
+    return jax.lax.platform_dependent(
+        q, k, v, default=xla,
+        tpu=functools.partial(fused_attention, window=window, dtype=dtype))
+
+
 def gated_attention(p, x, *, heads: int, kv_heads: int, head_dim: int,
                     dtype, query_block: int = QUERY_BLOCK,
                     qk_norm_eps: float | None = None,
@@ -171,8 +306,11 @@ def gated_attention(p, x, *, heads: int, kv_heads: int, head_dim: int,
     the projections ``q``, ``k``, ``v``, ``g``, ``o``. With
     ``qk_norm_eps`` a per-head RMS norm on ``q`` and ``k`` (scales
     ``q_norm``, ``k_norm``); with ``rope_theta`` rotary positions on
-    both; with ``window`` the band of :func:`banded_attention`, else
-    every key at or before the query (:func:`causal_attention`)."""
+    both; with ``window`` the band ``0 <= i - j < window``, else every
+    key at or before the query. Projections, norm, positions, gate and
+    output projection are XLA's everywhere; the core between them is
+    :func:`attention_core`'s choice (``query_block`` sizes its XLA form
+    only)."""
     B, T, _ = x.shape
     q = _mm(x, p["q"], dtype).reshape(
         B, T, kv_heads, heads // kv_heads, head_dim)
@@ -183,11 +321,8 @@ def gated_attention(p, x, *, heads: int, kv_heads: int, head_dim: int,
         k = _rms_norm(k, p["k_norm"], qk_norm_eps)
     if rope_theta is not None:
         q, k = rotary(q, rope_theta), rotary(k, rope_theta)
-    if window is None:
-        o = causal_attention(q, k, v, dtype=dtype, query_block=query_block)
-    else:
-        o = banded_attention(q, k, v, window=window, dtype=dtype,
-                             query_block=query_block)
+    o = attention_core(q, k, v, window=window, dtype=dtype,
+                       query_block=query_block)
     gate = jax.nn.sigmoid(_mm(x, p["g"], dtype))
     return _mm(o.reshape(B, T, heads * head_dim) * gate, p["o"], dtype)
 

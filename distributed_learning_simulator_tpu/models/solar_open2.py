@@ -430,6 +430,16 @@ class SolarOpen2(nn.Module):
     #: on no logits (head_nll); the recorder's counter of the same name.
     head_backward_tied = True
 
+    def fused_attention_layers(self, positions: int) -> int:
+        """GQA layers whose attention core is the fused kernel where the
+        program is lowered for a TPU, at sequences of ``positions``
+        (:func:`lm_parts.fused_attention_applies`); the recorder's
+        counter of the same name is this on a TPU and 0 anywhere else."""
+        c = self.share
+        layers = set(c.gqa_layers) & set(range(c.num_hidden_layers))
+        return len(layers) * parts.fused_attention_applies(
+            positions, c.head_dim, c.dtype)
+
     @nn.compact
     def __call__(self, tokens):
         if self.vocab_rows not in (None, self.num_classes):

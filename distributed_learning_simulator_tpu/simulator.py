@@ -833,6 +833,17 @@ def run_simulation(
             int(getattr(model, "swa_keys_per_query_block", lambda _: 0)(
                 dataset.x_train.shape[-1])),
         )
+        # Layers whose softmax attention runs as the fused kernel
+        # (models/lm_parts.py attention_core): the model's count by its
+        # shapes at this data's positions, on the devices the round
+        # program runs on; 0 on any platform but a TPU, where the XLA
+        # form is what is lowered.
+        tracer.set_counter(
+            "fused_attention_layers",
+            int(jax.devices()[0].platform == "tpu" and getattr(
+                model, "fused_attention_layers", lambda _: 0)(
+                    dataset.x_train.shape[-1])),
+        )
 
         # Optional server-side optimizer (FedOpt; exceeds the reference): the
         # aggregate is post-processed by a jitted pseudo-gradient step.

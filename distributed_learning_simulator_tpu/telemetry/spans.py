@@ -628,7 +628,7 @@ class SpanRecorder(_Sections):
         ``rounds`` (completed rounds) and what :meth:`set_counter` was
         given (``local_steps_unrolled``, ``client_axis_width``,
         ``head_backward_tied``, ``global_donated``, ``attention_window``,
-        ``swa_keys_per_query_block``)."""
+        ``swa_keys_per_query_block``, ``fused_attention_layers``)."""
         events = self.duration_events()
         with self._lock:
             cut = self._first_round_t

@@ -450,6 +450,241 @@ def test_window_counters():
     assert af.afmoe(25024).swa_keys_per_query_block(8192) == 2176
 
 
+# --- the fused kernel ---------------------------------------------------------
+
+#: The kernel at its smallest blocks: 256 positions are two of them.
+_SMALL_BLOCKS = parts.FusedBlocks(128, 128, 128)
+
+
+def _qkv(batch, length, kv, group, head_dim, seed=0):
+    keys = jax.random.split(jax.random.key(seed), 4)
+    return (
+        jax.random.normal(keys[0], (batch, length, kv, group, head_dim)),
+        jax.random.normal(keys[1], (batch, length, kv, head_dim)),
+        jax.random.normal(keys[2], (batch, length, kv, head_dim)),
+        jax.random.normal(keys[3], (batch, length, kv, group, head_dim)),
+    )
+
+
+def _xla_attention(window, dtype=jnp.bfloat16, block=128):
+    if window is None:
+        return lambda q, k, v: parts.causal_attention(
+            q, k, v, dtype=dtype, query_block=block)
+    return lambda q, k, v: parts.banded_attention(
+        q, k, v, window=window, dtype=dtype, query_block=block)
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+@pytest.mark.parametrize("group", [1, 8])
+@pytest.mark.parametrize("window", [None, 96, 300],
+                         ids=["causal", "window<T", "window>=T"])
+def test_the_fused_kernel_is_the_xla_form(window, group, batch):
+    """The splash kernel (Pallas's interpreter: the CPU has no Mosaic)
+    against ``causal_attention`` / ``banded_attention`` with products in
+    bf16: the outputs to a bf16 step of the largest value, each of the
+    three gradients to 2 % of its norm (measured 0.4-0.6 %: the kernel
+    rounds its output and the scaled queries to bf16, the XLA form
+    neither)."""
+    q, k, v, w = _qkv(batch, 256, 2, group, 128)
+    want, want_vjp = jax.vjp(_xla_attention(window), q, k, v)
+    got, got_vjp = jax.vjp(
+        lambda q, k, v: parts.fused_attention(
+            q, k, v, window=window, dtype=jnp.bfloat16,
+            blocks=_SMALL_BLOCKS, interpret=True), q, k, v)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    close(got, want, 2 ** -7)
+    for g, r in zip(got_vjp(w), want_vjp(w)):
+        assert g.shape == r.shape
+        assert float(jnp.linalg.norm(g - r)) <= 0.02 * float(
+            jnp.linalg.norm(r))
+
+
+def test_the_fused_kernel_is_built_once_a_shape():
+    """One kernel (its mask's block tables are made on the host) per
+    (positions, window, group, blocks), whatever the layer or the trace
+    that asks, and what is kept holds no tracer."""
+    q, k, v, _ = _qkv(1, 128, 1, 2, 128)
+
+    def run(q, k, v):
+        return parts.fused_attention(
+            q, k, v, window=64, dtype=jnp.bfloat16, blocks=_SMALL_BLOCKS,
+            interpret=True)
+
+    parts._fused_kernel.cache_clear()
+    first = jax.jit(lambda q, k, v: run(q, k, v) + run(q, k, v))(q, k, v)
+    again = jax.jit(run)(q, k, v)  # another trace, the same kernel
+    info = parts._fused_kernel.cache_info()
+    assert (info.misses, info.hits) == (1, 2)
+    assert jnp.array_equal(first, 2 * again)
+
+
+@pytest.mark.parametrize("length,head_dim,dtype", [
+    (300, 128, "bfloat16"),   # no whole number of the kernel's blocks
+    (1024, 64, "bfloat16"),   # half the lanes
+    (1024, 128, "float32"),   # the kernel would multiply f32 in one bf16 pass
+], ids=["length", "head_dim", "dtype"])
+def test_other_shapes_keep_the_xla_form(length, head_dim, dtype):
+    """Shapes the kernel does not take trace to the XLA form and nothing
+    else (no choice by platform is left in the program) and give its
+    numbers digit for digit, a padded last block included."""
+    assert not parts.fused_attention_applies(length, head_dim, dtype)
+    q, k, v, w = _qkv(1, length, 1, 2, head_dim)
+    dtype = jnp.dtype(dtype)
+
+    def core(q, k, v):
+        return parts.attention_core(q, k, v, window=None, dtype=dtype,
+                                    query_block=128)
+
+    plain = _xla_attention(None, dtype)
+    assert "platform_index" not in str(jax.make_jaxpr(core)(q, k, v))
+
+    def text(fn):  # less the line that names the module after ``fn``
+        return jax.jit(fn).lower(q, k, v).as_text().split("\n", 1)[1]
+
+    assert text(core) == text(plain)
+    got, got_vjp = jax.vjp(core, q, k, v)
+    want, want_vjp = jax.vjp(plain, q, k, v)
+    assert jnp.array_equal(got, want)
+    for g, r in zip(got_vjp(w), want_vjp(w)):
+        assert jnp.array_equal(g, r)
+
+
+@pytest.mark.parametrize("window", [None, 200], ids=["causal", "band"])
+def test_the_lowering_platform_chooses_the_form(window):
+    """Shapes the kernel takes: the traced program holds both forms and
+    the LOWERING says which. For the CPU (the tests' platform, whatever
+    ``jax.default_backend()`` would say elsewhere) no Pallas call is
+    lowered and values and gradients are the XLA form's digit for digit;
+    lowered for a TPU, with no TPU and no libtpu, the same trace holds
+    the kernel's custom calls (forward, backward) and no softmax of a
+    block's scores."""
+    length = parts.FUSED_BLOCKS.q
+    assert parts.fused_attention_applies(length, 128, "bfloat16")
+    q, k, v, w = _qkv(1, length, 1, 1, 128)
+
+    def core(q, k, v):
+        return parts.attention_core(q, k, v, window=window,
+                                    dtype=jnp.bfloat16, query_block=128)
+
+    def loss(f):
+        return jax.jit(jax.grad(
+            lambda q, k, v: jnp.sum(f(q, k, v) * w), argnums=(0, 1, 2)))
+
+    traced = loss(core).trace(q, k, v)
+    assert "platform_index" in str(traced.jaxpr)
+    cpu = traced.lower(lowering_platforms=("cpu",)).as_text()
+    assert "tpu_custom_call" not in cpu
+    tpu = traced.lower(lowering_platforms=("tpu",)).as_text()
+    assert tpu.count("tpu_custom_call") == 2
+    assert "stablehlo.exponential" in cpu
+    assert "stablehlo.exponential" not in tpu  # the softmax is the kernel's
+    plain = _xla_attention(window)
+    assert jnp.array_equal(jax.jit(core)(q, k, v), jax.jit(plain)(q, k, v))
+    for g, r in zip(loss(core)(q, k, v), loss(plain)(q, k, v)):
+        assert jnp.array_equal(g, r)
+
+
+#: Positions of the models lowered below: one block of the kernel.
+_POSITIONS = parts.FUSED_BLOCKS.q
+
+
+def _lm(name):
+    """Two layers of each language model at head 128 (over ``_POSITIONS``:
+    shapes the kernel takes) and at the tests' tiny preset (shapes it
+    does not)."""
+    if name == "afmoe":
+        big = get_model("afmoe", num_classes=96, **share_args(
+            num_hidden_layers=2, layer_types=["sliding_attention",
+                                              "full_attention"],
+            head_dim=128, num_attention_heads=4, num_key_value_heads=2,
+            sliding_window=256, dtype="bfloat16"))
+        return big, get_model("afmoe", num_classes=96, **share_args())
+    solar = dict(
+        hidden_size=64, num_hidden_layers=2, gqa_layers=[0], head_dim=16,
+        num_attention_heads=8, num_key_value_heads=4, heads_held=4,
+        n_routed_experts=8, experts_held=4, num_experts_per_tok=2,
+        moe_intermediate_size=32, gate_rank=8, dtype="float32")
+    # Four query heads a key/value head where the other model has two:
+    # JAX keeps the kernel's traced body by its shapes, lines and all, so
+    # in ONE process a second model of the same attention shapes would
+    # show the first one's lines (a run holds one model).
+    big = get_model("solar_open2", num_classes=96, **{
+        **solar, "head_dim": 128, "dtype": "bfloat16",
+        "num_key_value_heads": 2})
+    return big, get_model("solar_open2", num_classes=96, **solar)
+
+
+def _loss_program(model, length):
+    tokens = jnp.zeros((1, length), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), tokens)["params"])
+
+    def loss(p, t):
+        head, _ = model.apply({"params": p}, t)
+        return head.weighted_nll(t, jnp.ones(t.shape))[0]
+
+    return jax.jit(jax.grad(loss)).trace(params, tokens)
+
+
+@pytest.mark.parametrize("name", ["afmoe", "solar_open2"])
+def test_a_models_loss_lowers_by_platform(name, monkeypatch):
+    """A model's loss and gradient, its blocks under ``nn.remat``: at
+    shapes the kernel takes the CPU's text holds no Pallas call and the
+    TPU's holds the kernel once a pass of each attention layer (forward,
+    the rematerialised forward, backward); at the tiny preset the text
+    is the one the XLA form alone lowers to (the parent's program)."""
+    big, tiny = _lm(name)
+    layers = big.fused_attention_layers(_POSITIONS)
+    assert layers == (2 if name == "afmoe" else 1)
+    traced = _loss_program(big, _POSITIONS)
+    assert "tpu_custom_call" not in traced.lower(
+        lowering_platforms=("cpu",)).as_text()
+    assert traced.lower(lowering_platforms=("tpu",)).as_text().count(
+        "tpu_custom_call") == 3 * layers
+    # JAX counts its Pallas ops as user code; the kernel's equations are
+    # given the scope's line all the same, or no scope's device time
+    # would hold the kernel.
+    from jax._src import source_info_util
+
+    module = importlib.import_module(type(big).__module__)
+    scopes = [
+        module.scope_of_line(source_info_util.user_frame(
+            eqn.source_info.traceback).start_line)
+        for eqn in _equations(traced.jaxpr.jaxpr)
+        if eqn.primitive.name == "pallas_call"
+        and source_info_util.user_frame(
+            eqn.source_info.traceback).file_name == module.__file__
+    ]
+    assert len(scopes) == 3 * layers
+    assert set(scopes) == (
+        {"swa", "attn_full"} if name == "afmoe" else {"gqa"})
+    assert tiny.fused_attention_layers(80) == 0
+    text = _loss_program(tiny, 80).lower().as_text()
+
+    def parents(q, k, v, *, window, dtype, query_block):
+        return _xla_attention(window, dtype, query_block)(q, k, v)
+
+    monkeypatch.setattr(parts, "attention_core", parents)
+    assert _loss_program(tiny, 80).lower().as_text() == text
+
+
+@pytest.mark.parametrize("model,positions,layers", [
+    (lambda: af.afmoe(25024), 8192, 5),       # trinity_mini_fed_seq8k_c4
+    (lambda: get_model("solar_open2", num_classes=24576), 4096, 1),
+    (lambda: get_model("resnet18", num_classes=10), 3072, 0),
+    (lambda: af.afmoe(25024), 8192 + 512, 0),  # no whole blocks
+    (lambda: af.afmoe(25024, head_dim=64), 8192, 0),
+    (lambda: af.afmoe(25024, dtype="float32"), 8192, 0),
+], ids=["trinity_cell", "solar_cell", "image_cell", "length", "head_dim",
+        "dtype"])
+def test_fused_attention_layers_by_shape(model, positions, layers):
+    """The count behind the recorder's ``fused_attention_layers`` (what
+    ``run_simulation`` asks the model, and reports on a TPU only): the
+    three configurations' shapes read 5, 1 and 0."""
+    count = getattr(model(), "fused_attention_layers", lambda _: 0)
+    assert count(positions) == layers
+
+
 # --- through run_simulation --------------------------------------------------
 
 
@@ -488,6 +723,7 @@ def test_one_client_in_flight_through_run_simulation(tmp_path):
     assert history[1]["test_loss"] < history[0]["test_loss"]
     assert counts["attention_window"] == 32
     assert counts["swa_keys_per_query_block"] == 48
+    assert counts["fused_attention_layers"] == 0  # the CPU: the XLA form
     assert counts["head_backward_tied"] == 1
     assert counts["client_axis_width"] == 1
     assert counts["global_donated"] == 1
@@ -502,7 +738,8 @@ def test_one_client_in_flight_through_run_simulation(tmp_path):
 
 def test_models_without_a_window_report_none(tmp_path):
     """``attention_window`` and ``swa_keys_per_query_block`` read 0 where
-    no layer is windowed."""
+    no layer is windowed, ``fused_attention_layers`` where there is no
+    attention (and on every CPU)."""
     from distributed_learning_simulator_tpu.config import get_config
     from distributed_learning_simulator_tpu.simulator import run_simulation
     from distributed_learning_simulator_tpu.telemetry import spans
@@ -518,3 +755,4 @@ def test_models_without_a_window_report_none(tmp_path):
     counts = spans.last_run().counters()
     assert counts["attention_window"] == 0
     assert counts["swa_keys_per_query_block"] == 0
+    assert counts["fused_attention_layers"] == 0

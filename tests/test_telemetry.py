@@ -525,6 +525,7 @@ def test_local_steps_unrolled_counter(tiny_config, tmp_path, batch_size,
         ("event", "head_backward_tied", 0),  # this model hands on logits
         ("event", "attention_window", 0),  # no layer is windowed
         ("event", "swa_keys_per_query_block", 0),
+        ("event", "fused_attention_layers", 0),  # no attention, and a CPU
         ("event", "global_donated", 0),  # a pipelined loop keeps the global
     ]
     spec = importlib.util.spec_from_file_location(
