@@ -1,7 +1,9 @@
-"""The parts the sparse language models share (models/solar_open2.py,
-models/afmoe.py): softmax attention (every key at or before the query, or
-a band of them) in two forms, sigmoid routing, the experts told their
-share, a SwiGLU, and the head that makes its own loss.
+"""The parts the language models share (models/solar_open2.py,
+models/afmoe.py, models/olmo_hybrid.py): softmax attention (every key at
+or before the query, or a band of them) in two forms, the gated delta
+rule in chunks (a decay per key channel or one per head), sigmoid routing,
+the experts told their share, a SwiGLU, and the head that makes its own
+loss.
 
 The attention's core (scores, mask, softmax, weighted values, forward and
 backward) has an XLA form, a block of queries at a time with the block's
@@ -16,7 +18,8 @@ of the MODEL's file (``benchmark/harness/scopes.py``). This file is
 therefore registered with JAX as not being user code, like
 ``traced_helpers.py``: an op made here carries the line of its CALLER, the
 scope function of the model's file that wraps each call (``moe_experts``
-in ``solar_open2.py``, ``swa`` in ``afmoe.py``, ...). No
+in ``solar_open2.py``, ``swa`` in ``afmoe.py``, ``gdn`` in
+``olmo_hybrid.py``, ...). No
 ``jax.named_scope`` is opened here except inside the head's gradient
 rules, which run outside their caller's.
 """
@@ -34,6 +37,7 @@ import jax.numpy as jnp
 from jax.extend import source_info_util
 
 from distributed_learning_simulator_tpu.models.traced_helpers import (
+    HIGHEST,
     ein as _ein,
     mm as _mm,
     rms_norm as _rms_norm,
@@ -44,6 +48,10 @@ source_info_util.register_exclusion(__file__)
 #: Queries per block of the softmax attention's XLA form (its scores are
 #: materialised a block at a time).
 QUERY_BLOCK = 512
+#: Positions per chunk of the delta rule, and per sub-block of a chunk in
+#: the form with a decay per key channel.
+DELTA_CHUNK = 64
+DELTA_SUB = 16
 
 
 class FusedBlocks(NamedTuple):
@@ -298,33 +306,213 @@ def attention_core(q, k, v, *, window: int | None, dtype,
 def gated_attention(p, x, *, heads: int, kv_heads: int, head_dim: int,
                     dtype, query_block: int = QUERY_BLOCK,
                     qk_norm_eps: float | None = None,
+                    qk_norm: str = "head",
                     window: int | None = None,
                     rope_theta: float | None = None):
     """Grouped-query softmax attention over ``heads`` query heads and
-    their ``kv_heads`` key/value heads, gated per output channel before
-    the output projection; ``x`` the normed input ``[B, T, D]``, ``p``
-    the projections ``q``, ``k``, ``v``, ``g``, ``o``. With
-    ``qk_norm_eps`` a per-head RMS norm on ``q`` and ``k`` (scales
-    ``q_norm``, ``k_norm``); with ``rope_theta`` rotary positions on
-    both; with ``window`` the band ``0 <= i - j < window``, else every
-    key at or before the query. Projections, norm, positions, gate and
-    output projection are XLA's everywhere; the core between them is
-    :func:`attention_core`'s choice (``query_block`` sizes its XLA form
-    only)."""
+    their ``kv_heads`` key/value heads; ``x`` the input ``[B, T, D]``,
+    ``p`` the projections ``q``, ``k``, ``v``, ``o`` and, where it holds
+    ``g``, a sigmoid gate per output channel before the output
+    projection. With ``qk_norm_eps`` an RMS norm on ``q`` and ``k``
+    (scales ``q_norm``, ``k_norm``): per head (``qk_norm="head"``) or over
+    the whole projection (``"projection"``, before it is split into
+    heads); with ``rope_theta`` rotary positions on both; with ``window``
+    the band ``0 <= i - j < window``, else every key at or before the
+    query. Projections, norm, positions, gate and output projection are
+    XLA's everywhere; the core between them is :func:`attention_core`'s
+    choice (``query_block`` sizes its XLA form only)."""
     B, T, _ = x.shape
-    q = _mm(x, p["q"], dtype).reshape(
-        B, T, kv_heads, heads // kv_heads, head_dim)
-    k = _mm(x, p["k"], dtype).reshape(B, T, kv_heads, head_dim)
+    whole = qk_norm_eps is not None and qk_norm == "projection"
+
+    def project(name):
+        y = _mm(x, p[name], dtype)
+        return _rms_norm(y, p[name + "_norm"], qk_norm_eps) if whole else y
+
+    q = project("q").reshape(B, T, kv_heads, heads // kv_heads, head_dim)
+    k = project("k").reshape(B, T, kv_heads, head_dim)
     v = _mm(x, p["v"], dtype).reshape(B, T, kv_heads, head_dim)
-    if qk_norm_eps is not None:
+    if qk_norm_eps is not None and not whole:
         q = _rms_norm(q, p["q_norm"], qk_norm_eps)
         k = _rms_norm(k, p["k_norm"], qk_norm_eps)
     if rope_theta is not None:
         q, k = rotary(q, rope_theta), rotary(k, rope_theta)
     o = attention_core(q, k, v, window=window, dtype=dtype,
                        query_block=query_block)
+    if "g" not in p:
+        return _mm(o.reshape(B, T, heads * head_dim), p["o"], dtype)
     gate = jax.nn.sigmoid(_mm(x, p["g"], dtype))
     return _mm(o.reshape(B, T, heads * head_dim) * gate, p["o"], dtype)
+
+
+# --- the gated delta rule, chunked -------------------------------------------
+
+
+def _intra_chunk(qc, kc, gc, sub: int):
+    """Decay-weighted products inside one chunk, all f32.
+
+    ``A[t, s] = sum_d k_t k_s exp(g_t - g_s)`` for ``s < t`` and ``P[t, s]
+    = sum_d q_t k_s exp(g_t - g_s)`` for ``s <= t``, ``g`` the log decay
+    summed from the chunk's start (falling with ``t``). Shapes ``[..., C,
+    d]`` -> ``[..., C, C]``; ``gc`` ``[..., C, d]``, or ``[..., C, 1]`` for
+    one decay a head (:func:`_intra_chunk_scalar`).
+
+    A decay per key channel: ``exp(-g_s)`` alone overflows under a strong
+    decay, so no factor above 1 is ever formed: between sub-blocks of
+    ``sub`` positions both operands are scaled against the decay at the
+    later block's start; inside a sub-block the ``[sub, sub, d]``
+    differences are exponentiated directly.
+    """
+    if gc.shape[-1] == 1:
+        return _intra_chunk_scalar(qc, kc, gc)
+    C, d = kc.shape[-2:]
+    n = C // sub
+    lead = kc.shape[:-2]
+
+    def blocks(a):
+        return a.reshape(lead + (n, sub, d))
+
+    qb, kb, gb = blocks(qc), blocks(kc), blocks(gc)
+    # Decay at the end of the sub-block before: 0 for the first.
+    start = jnp.concatenate(
+        [jnp.zeros_like(gb[..., :1, -1, :]), gb[..., :-1, -1, :]], axis=-2
+    )  # [..., n, d]
+    left = jnp.exp(gb - start[..., None, :])  # rows of block i: <= 1
+    # Columns s scaled for each later block i: exp(start_i - g_s) <= 1
+    # where s lies before block i; elsewhere unused (masked to 0 below).
+    right = jnp.exp(jnp.minimum(
+        start[..., :, None, :] - gc[..., None, :, :], 0.0
+    ))  # [..., n, C, d]
+    before = (
+        jnp.arange(C)[None, :] // sub < jnp.arange(n)[:, None]
+    )  # [n, C]
+    k_right = kc[..., None, :, :] * right * before[..., None]
+
+    def off_diagonal(rows):
+        out = jnp.einsum("...ntd,...nsd->...nts", rows * left, k_right,
+                         precision=HIGHEST)
+        return out.reshape(lead + (C, C))
+
+    # Inside a sub-block: every pair's own difference, never positive
+    # where it is kept (s <= t).
+    diff = gb[..., :, None, :] - gb[..., None, :, :]  # [..., n, t, s, d]
+    keep = jnp.tril(jnp.ones((sub, sub), bool))
+    decay = jnp.exp(jnp.where(keep[..., None], diff, -jnp.inf))
+
+    def diagonal(rows):
+        out = jnp.einsum("...ntd,...nsd,...ntsd->...nts", rows, kb, decay,
+                         precision=HIGHEST)  # [..., n, sub, sub]
+        eye = jnp.eye(n, dtype=out.dtype)
+        full = jnp.einsum("...nts,nm->...ntms", out, eye)
+        return full.reshape(lead + (C, C))
+
+    strict = jnp.tril(jnp.ones((C, C), bool), -1)
+    A = jnp.where(strict, off_diagonal(kb) + diagonal(kb), 0.0)
+    P = off_diagonal(qb) + diagonal(qb)  # zero above the diagonal already
+    return A, P
+
+
+def _intra_chunk_scalar(qc, kc, gc):
+    """:func:`_intra_chunk` for ONE decay a head and position (``gc``
+    ``[..., C, 1]``): the decay factors out of the sum over channels, so
+    ``A = (K K^T) * G`` and ``P = (Q K^T) * G`` with ``G[t, s] = exp(g_t -
+    g_s)`` for ``s <= t``: two ``[C, C]`` products and one ``[C, C]``
+    matrix of differences, never positive where it is kept."""
+    C = kc.shape[-2]
+    g = gc[..., 0]
+    keep = jnp.tril(jnp.ones((C, C), bool))
+    decay = jnp.exp(jnp.where(keep, g[..., :, None] - g[..., None, :],
+                              -jnp.inf))
+
+    def product(rows):
+        return jnp.einsum("...td,...sd->...ts", rows, kc, precision=HIGHEST)
+
+    strict = jnp.tril(jnp.ones((C, C), bool), -1)
+    return jnp.where(strict, product(kc) * decay, 0.0), product(qc) * decay
+
+
+def _unit_lower_inverse(L):
+    """``(I - L)^-1`` for strictly lower-triangular ``L`` ``[..., C, C]``:
+    ``L`` is nilpotent, so the inverse is ``prod_j (I + L^(2^j))``."""
+    C = L.shape[-1]
+    eye = jnp.eye(C, dtype=L.dtype)
+    inv = eye + L
+    power = L
+    for _ in range(max(0, math.ceil(math.log2(C)) - 1)):
+        power = jnp.matmul(power, power, precision=HIGHEST)
+        inv = jnp.matmul(inv, eye + power, precision=HIGHEST)
+    return inv
+
+
+def chunked_delta_rule(q, k, v, log_decay, beta, dtype=jnp.bfloat16,
+                       chunk: int = DELTA_CHUNK, sub: int = DELTA_SUB):
+    """``S_t = (I - b_t k_t k_t^T) Diag(a_t) S_(t-1) + b_t k_t v_t^T``,
+    ``o_t = S_t^T q_t``, ``S_0 = 0``, in chunks of ``chunk`` positions.
+
+    Inside a chunk the rank-one updates are folded into one triangular
+    system (the WY / UT-transform form): with ``u_t = b_t (v_t - S_(t-1)^T
+    Diag(a_t) k_t)`` the state is ``S_t = Diag(a_t) S_(t-1) + k_t u_t^T``,
+    and over a chunk ``(I + Diag(b) A) U = Diag(b) (V - K+ S_0)`` with
+    ``A`` of :func:`_intra_chunk` and ``K+ = K exp(g)``. The triangular
+    inverse and ``A``, ``P`` are f32; the products with the ``[d_k,
+    d_v]`` state multiply in ``dtype`` and accumulate f32; the state is
+    carried between chunks by ``lax.scan`` in f32.
+
+    ``q, k`` ``[B, T, H, d_k]``, ``v`` ``[B, T, H, d_v]`` f32, ``beta``
+    ``[B, T, H]``; ``log_decay`` ``[B, T, H, d_k]`` (a decay per key
+    channel: Kimi's KDA) or ``[B, T, H]`` / ``[B, T, H, 1]`` (one a head:
+    Gated DeltaNet), the form chosen by that shape. Returns ``[B, T, H,
+    d_v]`` f32. ``T`` need not be a multiple of ``chunk``: padded
+    positions have ``k = v = 0`` and no decay, so they leave the state as
+    it is.
+    """
+    if log_decay.ndim == 3:
+        log_decay = log_decay[..., None]
+    B, T, H, d = q.shape
+    pad = (-T) % chunk
+    if pad:
+        q, k, v, log_decay = (
+            jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+            for a in (q, k, v, log_decay)
+        )
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    n = (T + pad) // chunk
+
+    def chunks(a):  # [B, T, H, d] -> [n, B, H, C, d]
+        a = a.reshape((B, n, chunk) + a.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
+
+    qc, kc, vc, gc = (chunks(a) for a in (q, k, v, log_decay))
+    bc = jnp.moveaxis(
+        jnp.moveaxis(beta.reshape(B, n, chunk, H), 3, 2), 1, 0
+    )  # [n, B, H, C]
+    gc = jnp.cumsum(gc, axis=-2)
+    # Recomputed in the backward pass: its [sub, sub, d] decay factors are
+    # the mixer's largest tensors.
+    A, P = jax.checkpoint(_intra_chunk, static_argnums=(3,))(qc, kc, gc, sub)
+    solve = _unit_lower_inverse(-bc[..., None] * A) * bc[..., None, :]
+    decay = jnp.exp(gc)
+    k_in = kc * decay  # against the state at the chunk's start
+    q_in = qc * decay
+    total = gc[..., -1:, :]
+    k_out = kc * jnp.exp(total - gc)  # towards the state at its end
+    W = _ein("...ts,...sd->...td", solve, k_in, dtype)
+    U0 = _ein("...ts,...sd->...td", solve, vc, dtype)
+
+    def step(S, xs):
+        W_c, U0_c, q_c, P_c, k_c, total_c = xs
+        U = U0_c - _ein("...td,...de->...te", W_c, S, dtype)
+        out = _ein("...td,...de->...te", q_c, S, dtype) + _ein(
+            "...ts,...se->...te", P_c, U, dtype
+        )
+        S = S * jnp.exp(total_c)[..., 0, :, None] + _ein(
+            "...td,...te->...de", k_c, U, dtype
+        )
+        return S, out
+
+    S0 = jnp.zeros((B, H, d, v.shape[-1]), jnp.float32)
+    _, out = jax.lax.scan(step, S0, (W, U0, q_in, P, k_out, total))
+    out = jnp.moveaxis(jnp.moveaxis(out, 0, 1), 2, 3)  # [B, n, C, H, d]
+    return out.reshape(B, n * chunk, H, -1)[:, :T]
 
 
 # --- the expert layer --------------------------------------------------------

@@ -18,6 +18,7 @@ from distributed_learning_simulator_tpu.models.cnn import (
     TpuCifarCNN,
 )
 from distributed_learning_simulator_tpu.models.lenet import LeNet5
+from distributed_learning_simulator_tpu.models.olmo_hybrid import olmo_hybrid
 from distributed_learning_simulator_tpu.models.resnet import ResNet18, ResNet34
 from distributed_learning_simulator_tpu.models.solar_open2 import solar_open2
 
@@ -37,6 +38,10 @@ _MODELS = {
     # positions beside gated NoPE full attention, a leading dense layer;
     # ``--model_args`` the share (experts_held, expert_offset, vocab_rows).
     "afmoe": afmoe,
+    # Olmo-Hybrid's family: a dense post-norm stack, Gated DeltaNet (one
+    # decay a head) beside ungated full attention; ``--model_args`` the
+    # vocabulary held (vocab_rows).
+    "olmohybrid": olmo_hybrid,
 }
 
 

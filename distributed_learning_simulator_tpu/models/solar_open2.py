@@ -40,158 +40,23 @@ import jax.numpy as jnp
 
 from distributed_learning_simulator_tpu.models import lm_parts as parts
 from distributed_learning_simulator_tpu.models.traced_helpers import (
-    HIGHEST,
     causal_conv as _causal_conv,
-    ein as _ein,
     l2_norm as _l2_norm,
     mm as _mm,
     rms_norm as _rms_norm,
 )
 
-#: Positions per chunk of the delta rule and per sub-block of a chunk.
-KDA_CHUNK = 64
-KDA_SUB = 16
-
-# --- the gated delta rule, chunked -----------------------------------------
+# --- the gated delta rule with a decay per key channel ----------------------
 
 
-def _intra_chunk(qc, kc, gc, sub: int):
-    """Decay-weighted products inside one chunk, all f32.
-
-    ``A[t, s] = sum_d k_t k_s exp(g_t - g_s)`` for ``s < t`` and ``P[t, s]
-    = sum_d q_t k_s exp(g_t - g_s)`` for ``s <= t``, ``g`` the log decay
-    summed from the chunk's start (falling with ``t``). ``exp(-g_s)``
-    alone overflows under a strong decay, so no factor above 1 is ever
-    formed: between sub-blocks of ``sub`` positions both operands are
-    scaled against the decay at the later block's start; inside a
-    sub-block the ``[sub, sub, d]`` differences are exponentiated
-    directly. Shapes ``[..., C, d]`` -> ``[..., C, C]``.
-    """
-    C, d = kc.shape[-2:]
-    n = C // sub
-    lead = kc.shape[:-2]
-
-    def blocks(a):
-        return a.reshape(lead + (n, sub, d))
-
-    qb, kb, gb = blocks(qc), blocks(kc), blocks(gc)
-    # Decay at the end of the sub-block before: 0 for the first.
-    start = jnp.concatenate(
-        [jnp.zeros_like(gb[..., :1, -1, :]), gb[..., :-1, -1, :]], axis=-2
-    )  # [..., n, d]
-    left = jnp.exp(gb - start[..., None, :])  # rows of block i: <= 1
-    # Columns s scaled for each later block i: exp(start_i - g_s) <= 1
-    # where s lies before block i; elsewhere unused (masked to 0 below).
-    right = jnp.exp(jnp.minimum(
-        start[..., :, None, :] - gc[..., None, :, :], 0.0
-    ))  # [..., n, C, d]
-    before = (
-        jnp.arange(C)[None, :] // sub < jnp.arange(n)[:, None]
-    )  # [n, C]
-    k_right = kc[..., None, :, :] * right * before[..., None]
-
-    def off_diagonal(rows):
-        out = jnp.einsum("...ntd,...nsd->...nts", rows * left, k_right,
-                         precision=HIGHEST)
-        return out.reshape(lead + (C, C))
-
-    # Inside a sub-block: every pair's own difference, never positive
-    # where it is kept (s <= t).
-    diff = gb[..., :, None, :] - gb[..., None, :, :]  # [..., n, t, s, d]
-    keep = jnp.tril(jnp.ones((sub, sub), bool))
-    decay = jnp.exp(jnp.where(keep[..., None], diff, -jnp.inf))
-
-    def diagonal(rows):
-        out = jnp.einsum("...ntd,...nsd,...ntsd->...nts", rows, kb, decay,
-                         precision=HIGHEST)  # [..., n, sub, sub]
-        eye = jnp.eye(n, dtype=out.dtype)
-        full = jnp.einsum("...nts,nm->...ntms", out, eye)
-        return full.reshape(lead + (C, C))
-
-    strict = jnp.tril(jnp.ones((C, C), bool), -1)
-    A = jnp.where(strict, off_diagonal(kb) + diagonal(kb), 0.0)
-    P = off_diagonal(qb) + diagonal(qb)  # zero above the diagonal already
-    return A, P
-
-
-def _unit_lower_inverse(L):
-    """``(I - L)^-1`` for strictly lower-triangular ``L`` ``[..., C, C]``:
-    ``L`` is nilpotent, so the inverse is ``prod_j (I + L^(2^j))``."""
-    C = L.shape[-1]
-    eye = jnp.eye(C, dtype=L.dtype)
-    inv = eye + L
-    power = L
-    for _ in range(max(0, math.ceil(math.log2(C)) - 1)):
-        power = jnp.matmul(power, power, precision=HIGHEST)
-        inv = jnp.matmul(inv, eye + power, precision=HIGHEST)
-    return inv
-
-
-def chunked_delta_rule(q, k, v, log_decay, beta, dtype=jnp.bfloat16,
-                       chunk: int = KDA_CHUNK, sub: int = KDA_SUB):
-    """``S_t = (I - b_t k_t k_t^T) Diag(a_t) S_(t-1) + b_t k_t v_t^T``,
-    ``o_t = S_t^T q_t``, ``S_0 = 0``, in chunks of ``chunk`` positions.
-
-    Inside a chunk the rank-one updates are folded into one triangular
-    system (the WY / UT-transform form): with ``u_t = b_t (v_t - S_(t-1)^T
-    Diag(a_t) k_t)`` the state is ``S_t = Diag(a_t) S_(t-1) + k_t u_t^T``,
-    and over a chunk ``(I + Diag(b) A) U = Diag(b) (V - K+ S_0)`` with
-    ``A`` of :func:`_intra_chunk` and ``K+ = K exp(g)``. The triangular
-    inverse and ``A``, ``P`` are f32; the products with the ``[d, d]``
-    state multiply in ``dtype`` and accumulate f32; the state is carried
-    between chunks by ``lax.scan`` in f32.
-
-    ``q, k, v, log_decay`` ``[B, T, H, d]`` f32, ``beta`` ``[B, T, H]``;
-    returns ``[B, T, H, d]`` f32. ``T`` need not be a multiple of
-    ``chunk``: padded positions have ``k = v = 0`` and no decay, so they
-    leave the state as it is.
-    """
-    B, T, H, d = q.shape
-    pad = (-T) % chunk
-    if pad:
-        q, k, v, log_decay = (
-            jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            for a in (q, k, v, log_decay)
-        )
-        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
-    n = (T + pad) // chunk
-
-    def chunks(a):  # [B, T, H, d] -> [n, B, H, C, d]
-        a = a.reshape((B, n, chunk) + a.shape[2:])
-        return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
-
-    qc, kc, vc, gc = (chunks(a) for a in (q, k, v, log_decay))
-    bc = jnp.moveaxis(
-        jnp.moveaxis(beta.reshape(B, n, chunk, H), 3, 2), 1, 0
-    )  # [n, B, H, C]
-    gc = jnp.cumsum(gc, axis=-2)
-    # Recomputed in the backward pass: its [sub, sub, d] decay factors are
-    # the mixer's largest tensors.
-    A, P = jax.checkpoint(_intra_chunk, static_argnums=(3,))(qc, kc, gc, sub)
-    solve = _unit_lower_inverse(-bc[..., None] * A) * bc[..., None, :]
-    decay = jnp.exp(gc)
-    k_in = kc * decay  # against the state at the chunk's start
-    q_in = qc * decay
-    total = gc[..., -1:, :]
-    k_out = kc * jnp.exp(total - gc)  # towards the state at its end
-    W = _ein("...ts,...sd->...td", solve, k_in, dtype)
-    U0 = _ein("...ts,...sd->...td", solve, vc, dtype)
-
-    def step(S, xs):
-        W_c, U0_c, q_c, P_c, k_c, total_c = xs
-        U = U0_c - _ein("...td,...de->...te", W_c, S, dtype)
-        out = _ein("...td,...de->...te", q_c, S, dtype) + _ein(
-            "...ts,...se->...te", P_c, U, dtype
-        )
-        S = S * jnp.exp(total_c)[..., 0, :, None] + _ein(
-            "...td,...te->...de", k_c, U, dtype
-        )
-        return S, out
-
-    S0 = jnp.zeros((B, H, d, v.shape[-1]), jnp.float32)
-    _, out = jax.lax.scan(step, S0, (W, U0, q_in, P, k_out, total))
-    out = jnp.moveaxis(jnp.moveaxis(out, 0, 1), 2, 3)  # [B, n, C, H, d]
-    return out.reshape(B, n * chunk, H, -1)[:, :T]
+def chunked_delta_rule(q, k, v, log_decay, beta, dtype=jnp.bfloat16):
+    """The delta rule with ``log_decay`` ``[B, T, H, d]``, a decay per key
+    channel (:func:`lm_parts.chunked_delta_rule`, shared with
+    ``models/olmo_hybrid.py``, chooses its form by that shape). A function
+    of this file in scope ``kda``: the benchmark's scope test
+    (``benchmark/tests/test_solar_open2_cell.py``) reads a line of the
+    scope by this name."""
+    return parts.chunked_delta_rule(q, k, v, log_decay, beta, dtype)
 
 
 def kda_mixer(p, x, *, heads: int, head_dim: int, eps: float, dtype):
@@ -303,8 +168,7 @@ UnmadeLogits = functools.partial(parts.UnmadeLogits,
                                  head=(lm_head, head_nll))
 
 _SCOPES = {
-    "kda": (kda_mixer, chunked_delta_rule, _intra_chunk,
-            _unit_lower_inverse),
+    "kda": (chunked_delta_rule, kda_mixer),
     "gqa": (gqa_mixer,),
     "moe/route": (moe_route,),
     "moe/experts": (moe_experts,),
@@ -425,10 +289,19 @@ class SolarOpen2(nn.Module):
     #: models/registry.init_params: draw the weights in one program,
     #: traced over this many positions.
     jit_init = True
-    init_positions = KDA_CHUNK
+    init_positions = parts.DELTA_CHUNK
     #: The head makes its loss and both its gradients itself and hands
     #: on no logits (head_nll); the recorder's counter of the same name.
     head_backward_tied = True
+
+    @property
+    def delta_rule_decay_width(self) -> int:
+        """The recorder's counter of the same name: decays a head and
+        position the delta rule is given (one per key channel here), 0
+        where no layer mixes by it."""
+        c = self.share
+        return c.head_dim if set(range(c.num_hidden_layers)) - set(
+            c.gqa_layers) else 0
 
     def fused_attention_layers(self, positions: int) -> int:
         """GQA layers whose attention core is the fused kernel where the

@@ -833,6 +833,14 @@ def run_simulation(
             int(getattr(model, "swa_keys_per_query_block", lambda _: 0)(
                 dataset.x_train.shape[-1])),
         )
+        # Decays a head and position the delta rule was given: 1 (one a
+        # head, Gated DeltaNet), the key head's width (one a channel,
+        # KDA), 0 where no layer mixes by it (models/lm_parts.py
+        # chunked_delta_rule chooses its form by that shape).
+        tracer.set_counter(
+            "delta_rule_decay_width",
+            int(getattr(model, "delta_rule_decay_width", 0)),
+        )
         # Layers whose softmax attention runs as the fused kernel
         # (models/lm_parts.py attention_core): the model's count by its
         # shapes at this data's positions, on the devices the round

@@ -1,12 +1,14 @@
 #!/usr/bin/env python3
-"""Federated next-token fine-tuning of one chip's share of a sparse
-language model (Solar-Open2, or with ``--config trinity_mini`` Trinity-Mini),
-through the program's normal path: ``get_config(argv)`` and one
+"""Federated next-token fine-tuning of one chip's share of a language
+model (Solar-Open2, or with ``--config trinity_mini`` Trinity-Mini, or with
+``--config olmo_hybrid`` a pipeline stage of Olmo-Hybrid-7B), through the
+program's normal path: ``get_config(argv)`` and one
 ``run_simulation(config, dataset=...)``.
 
     python examples/solar_open2_share.py --size tiny   # CPU, seconds
     python examples/solar_open2_share.py --size cell   # one v5e chip
     python examples/solar_open2_share.py --config trinity_mini --size tiny
+    python examples/solar_open2_share.py --config olmo_hybrid --size tiny
 
 ``cell`` is the argv of the benchmark's configuration
 (``benchmark/configs/solar_open2_250b_l4_ep40_tp8.json`` +
@@ -20,7 +22,12 @@ sequences of 64) so that a CPU runs it. ``--config trinity_mini`` is
 afmoe``: banded sliding-window attention with rotary positions beside
 gated full attention, a leading dense layer, 16 of 128 experts, 25,024
 vocabulary rows, 705 M parameters, sequences of 8,192); its tiny preset
-has a window of 16 in query blocks of 8 over 64 positions. The data is the benchmark's seeded
+has a window of 16 in query blocks of 8 over 64 positions. ``--config
+olmo_hybrid`` is ``benchmark/configs/olmo_hybrid_7b_l4_vp8.json``
+(``--model_name olmo_hybrid``: Gated DeltaNet with one decay a head, keys
+of 96 and values of 192, beside ungated full attention, a dense post-norm
+block, 12,544 vocabulary rows, 929 M parameters, sequences of 4,096); its
+tiny preset has 2 heads, keys of 16 and values of 32. The data is the benchmark's seeded
 Markov source (``benchmark/tasks/next_token.py``); token datasets are
 handed in, the dataset registry holds images.
 """
@@ -49,9 +56,16 @@ TINY_AFMOE = {
     "num_experts_per_tok": 4, "moe_intermediate_size": 32,
     "vocab_rows": 96, "dtype": "float32",
 }
+TINY_OLMO = {
+    "hidden_size": 64, "num_attention_heads": 2, "num_key_value_heads": 2,
+    "linear_num_key_heads": 2, "linear_num_value_heads": 2,
+    "linear_key_head_dim": 16, "linear_value_head_dim": 32,
+    "intermediate_size": 96, "vocab_rows": 96, "dtype": "float32",
+}
 CONFIGS = {
     "solar_open2": ("solar_open2_250b_l4_ep40_tp8", TINY_MODEL),
     "trinity_mini": ("trinity_mini_26b_l5_ep8_vp8", TINY_AFMOE),
+    "olmo_hybrid": ("olmo_hybrid_7b_l4_vp8", TINY_OLMO),
 }
 
 
