@@ -551,6 +551,33 @@ def route(router, x, *, top_k: int, expert_offset: int, experts_held: int,
     )
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _gather_rows(n: int, x, token):
+    """``x[token]``: the row of each token ``token`` names, where ``N`` (a
+    padding row) reads token ``N - 1``; the gradient of a padding row is
+    dropped (:func:`_scatter_rows` is this gather's transpose, and it is
+    the scatter's): neither direction selects over the rows."""
+    return x.at[token].get(mode="clip")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _scatter_rows(n: int, rows, token):
+    """``[n, D]``: each token the sum of the ``rows`` that name it, in the
+    rows' dtype; rows naming token ``n`` (padding) are dropped."""
+    return jnp.zeros((n, rows.shape[1]), rows.dtype).at[token].add(
+        rows, mode="drop")
+
+
+def _rows_forward(fn):
+    return lambda n, a, token: (fn(n, a, token), token)
+
+
+_gather_rows.defvjp(_rows_forward(_gather_rows),
+                    lambda n, token, ct: (_scatter_rows(n, ct, token), None))
+_scatter_rows.defvjp(_rows_forward(_scatter_rows),
+                     lambda n, token, ct: (_gather_rows(n, ct, token), None))
+
+
 def _ragged(rows, kernels, sizes, dtype):
     """``rows[i] @ kernels[e]`` for the rows of each expert ``e``, the rows
     sorted by expert and ``sizes[e]`` of them each: multiplied in
@@ -560,15 +587,11 @@ def _ragged(rows, kernels, sizes, dtype):
     Rows past the last expert's belong to no product, and the TPU's
     kernel leaves whatever was in memory there (the CPU's writes zeros),
     in the result AND, through the backward pass's own ragged product, in
-    the gradient of ``rows``: both are masked here, going in and coming
-    out, so that nothing made from those rows is ever read (a NaN there
-    reached token 0's gradient through the gather's transpose)."""
+    the gradient of ``rows``: :func:`experts` never reads them."""
     precision = jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
-    real = (jnp.arange(rows.shape[0]) < jnp.sum(sizes))[:, None]
-    out = jax.lax.ragged_dot(
-        jnp.where(real, rows, 0).astype(dtype), kernels.astype(dtype),
-        sizes, precision=precision, preferred_element_type=jnp.float32)
-    return jnp.where(real, out, 0.0)
+    return jax.lax.ragged_dot(
+        rows.astype(dtype), kernels.astype(dtype), sizes,
+        precision=precision, preferred_element_type=jnp.float32)
 
 
 def experts(p, x, combine, *, capacity: int, dtype):
@@ -590,7 +613,13 @@ def experts(p, x, combine, *, capacity: int, dtype):
       the fullest expert of 16 takes 3.6 times its even share (AFMoE's
       cell, PERF.md § 6, PR 33): slots of its own would overflow in every
       step, shared rows overflow only when ALL the held experts together
-      are chosen twice as often as expected.
+      are chosen twice as often as expected. The rows are a permutation
+      of the assignments: one sort of the (token, expert) pairs, a gather
+      of the tokens and one scatter-add back.
+      Rows past the assignments name token ``N``: they read token ``N -
+      1`` going in and are dropped coming out, both ways
+      (:func:`_gather_rows`), so nothing the kernel leaves unwritten there
+      is read and no select over the rows is needed.
 
     No token is dropped: when the slots (of any expert, or in all) do not
     hold the assignments, the layer computes every held expert over every
@@ -621,24 +650,21 @@ def experts(p, x, combine, *, capacity: int, dtype):
         return empty.at[index.reshape(-1)].add(y.reshape(-1, y.shape[-1]))
 
     def ragged(_):
-        # Row of assignment (token, expert): the expert's first row plus
-        # the token's rank among the expert's choosers; a pair that is no
-        # assignment goes out of range and is dropped by the scatter.
-        rows = held * capacity
-        first = jnp.cumsum(load) - load
-        rank = jnp.cumsum(chosen, axis=0, dtype=jnp.int32) - 1
-        row = jnp.where(chosen, first + rank, rows).reshape(-1)
-        token = jnp.broadcast_to(
-            jnp.arange(n_tokens, dtype=jnp.int32)[:, None], chosen.shape)
-        row_token = jnp.zeros((rows,), jnp.int32).at[row].set(
-            token.reshape(-1), mode="drop")
-        weight = jnp.zeros((rows,), jnp.float32).at[row].set(
-            combine.reshape(-1), mode="drop")
-        xs = jnp.take(x, row_token, axis=0)  # [rows, D], sorted by expert
+        # Pair (token t, expert e) at place e * N + t, past every
+        # assignment where it is none: one sort of unique keys puts the
+        # assignments first, by expert and within one by token.
+        rows, pairs = held * capacity, chosen.size
+        place = jnp.arange(pairs, dtype=jnp.int32)
+        key = jnp.where(chosen.T.reshape(-1), place, place + pairs)
+        pair = jax.lax.sort(key, is_stable=False)[:rows] % pairs
+        real = jnp.arange(rows) < jnp.sum(load)
+        token = jnp.where(real, pair % n_tokens, n_tokens)
+        weight = jnp.where(real, combine.T.reshape(-1)[pair], 0.0)
+        xs = _gather_rows(n_tokens, x, token)
         hidden = jax.nn.silu(_ragged(xs, p["gate"], load, dtype)) * _ragged(
             xs, p["up"], load, dtype)
         y = _ragged(hidden, p["down"], load, dtype) * weight[:, None]
-        return empty.at[row_token].add(y)
+        return _scatter_rows(n_tokens, y, token)
 
     def every_token(_):
         # One held expert after another over every token, the

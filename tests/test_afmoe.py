@@ -6,6 +6,7 @@ query heads of 16 over 2 key/value heads, window 32 in query blocks of 16,
 The reference masks all the keys; the program reads a band of them, groups
 the routed tokens by expert and batches the sequences."""
 
+import functools
 import importlib.util
 import json
 import os
@@ -434,6 +435,119 @@ def test_the_size_chooses_the_form_of_the_experts_product(monkeypatch):
 
     monkeypatch.setattr(parts, "RAGGED_MIN_SLOTS", 32)
     assert ragged(32) and not ragged(24)
+
+
+def _rows_past_the_groups_hold_nan(product):
+    """``product`` (``lm_parts._ragged``) as the TPU's kernel leaves it:
+    the rows past the last group hold NaN in its result and in the
+    gradient of its rows (the CPU's ``ragged_dot`` writes zeros there)."""
+
+    def poison(rows, sizes):
+        real = jnp.arange(rows.shape[0]) < jnp.sum(sizes)
+        return jnp.where(real[:, None], rows, jnp.nan)
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+    def poisoned(rows, kernels, sizes, dtype):
+        return poison(product(rows, kernels, sizes, dtype), sizes)
+
+    def forward(rows, kernels, sizes, dtype):
+        return poisoned(rows, kernels, sizes, dtype), (rows, kernels, sizes)
+
+    def backward(dtype, residuals, ct):
+        rows, kernels, sizes = residuals
+        _, vjp = jax.vjp(lambda r, k: product(r, k, sizes, dtype), rows,
+                         kernels)
+        d_rows, d_kernels = vjp(ct)
+        return poison(d_rows, sizes), d_kernels, None
+
+    poisoned.defvjp(forward, backward)
+    return poisoned
+
+
+def test_the_kernels_unwritten_rows_are_never_read(monkeypatch):
+    """PR 33's fault, which only the chip showed: NaN in the rows the
+    ragged kernel leaves unwritten reached token 0. With those rows NaN
+    here too, the ragged form's output and the gradients of the tokens,
+    the combine weights (and the router, through them) and the three
+    kernels are finite and the reference's."""
+    monkeypatch.setattr(parts, "RAGGED_MIN_SLOTS", 8)
+    monkeypatch.setattr(parts, "_ragged",
+                        _rows_past_the_groups_hold_nan(parts._ragged))
+    p, x = make_params(seed=9)["layer_2"]["moe"], _inputs(100, 9)
+    tokens, c = x.reshape(-1, 64), share()
+    capacity = parts.expert_capacity(200, 2, 16)
+    assert parts.RAGGED_MIN_SLOTS <= capacity < 200
+    kernels = ("gate", "up", "down")
+
+    def program(x, combine, *kernel):
+        return parts.experts(dict(zip(kernels, kernel)), x, combine,
+                             capacity=capacity, dtype=jnp.float32)[0]
+
+    def reference(x, combine, *kernel):
+        return sum(combine[:, e, None] * ref.swiglu(
+            x, *(k[e] for k in kernel), PRODUCTS["dense"]) for e in range(4))
+
+    combine = af.moe_route(p, tokens, c)
+    assert 0 < int(jnp.sum(combine > 0)) < 4 * capacity
+    args = (tokens, combine) + tuple(p[k] for k in kernels)
+    y = program(*args)
+    assert bool(jnp.all(jnp.isfinite(y)))
+    close(y, reference(*args))
+    got = jax.grad(lambda *a: jnp.sum(jnp.sin(program(*a))),
+                   argnums=range(5))(*args)
+    want = jax.grad(lambda *a: jnp.sum(jnp.sin(reference(*a))),
+                    argnums=range(5))(*args)
+    chosen = combine > 0  # a pair no token chose has no weight to move
+    got, want = ((a[0], jnp.where(chosen, a[1], 0.0), *a[2:])
+                 for a in (got, want))
+    for g, w in zip(got, want):
+        assert bool(jnp.all(jnp.isfinite(g)))
+        close(g, w, 1e-3)
+
+    def routed(p, x):  # the model's path, the router's gradient too
+        return parts.experts(p, x, af.moe_route(p, x, c), capacity=capacity,
+                             dtype=jnp.float32)[0]
+
+    def plain(p, x):
+        return ref.moe(MODEL, p, x.reshape(2, 100, 64), **PRODUCTS,
+                       shared=False).reshape(x.shape)
+
+    close(routed(p, tokens), plain(p, tokens))
+    got, want = grads(routed, p, tokens), grads(plain, p, tokens)
+    assert all(bool(jnp.all(jnp.isfinite(g)))
+               for g in jax.tree_util.tree_leaves(got))
+    tree_close(got, want, 1e-3)
+
+
+def test_the_ragged_form_moves_its_tokens_by_a_permutation(monkeypatch):
+    """The ragged branch, forward and gradient, selects over no row-sized
+    operand (``[held x capacity, D]``, ``[held x capacity, F]``: padding
+    rows name token N, which the gather reads as token N - 1 and the
+    scatter-add drops) and scatters no ``N x held`` (token, expert)
+    pairs."""
+    monkeypatch.setattr(parts, "RAGGED_MIN_SLOTS", 8)
+    p, x = make_params(seed=10)["layer_2"]["moe"], _inputs(100, 10)
+    tokens, c = x.reshape(-1, 64), share()
+    n, held = tokens.shape[0], c.experts_held
+    capacity = parts.expert_capacity(n, 2, 16)
+    rows = held * capacity
+    assert len({rows, n}) == 2
+
+    def layer(p, x):
+        return parts.experts(p, x, af.moe_route(p, x, c), capacity=capacity,
+                             dtype=jnp.float32)[0]
+
+    for fn in (layer, jax.grad(lambda p, x: jnp.sum(layer(p, x)),
+                               argnums=(0, 1))):
+        eqns = list(_equations(jax.make_jaxpr(fn)(p, tokens).jaxpr))
+        assert any(e.primitive.name == "ragged_dot_general" for e in eqns)
+        for e in eqns:
+            shapes = {tuple(v.aval.shape) for v in e.invars
+                      if hasattr(v.aval, "shape")}
+            if e.primitive.name == "select_n":
+                assert not shapes & {(rows, 64), (rows, 32)}, shapes
+            if e.primitive.name.startswith("scatter"):
+                assert e.invars[2].aval.size != n * held, shapes
 
 
 def test_window_counters():
