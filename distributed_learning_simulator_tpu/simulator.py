@@ -289,6 +289,13 @@ def _owned_device_tree(tree):
     return jax.tree_util.tree_map(lambda a: jnp.array(a, copy=True), tree)
 
 
+def _place_resumed(tracer, tree):
+    """:func:`_owned_device_tree` of a tree a checkpoint restored, as the
+    tracer's span ``resume/place`` (host tree -> device)."""
+    with tracer.span("resume/place", "setup"):
+        return _owned_device_tree(tree)
+
+
 def _lr_factor(config, round_idx: int) -> float:
     """Per-round lr multiplier from config.lr_schedule (host-side scalar,
     passed into the jitted round program — no retrace across rounds)."""
@@ -962,7 +969,7 @@ def run_simulation(
                 # resume falls back to the newest VALID one instead of
                 # crashing.
                 ckpt_path, ckpt = load_latest_valid_checkpoint(
-                    config.checkpoint_dir
+                    config.checkpoint_dir, tracer
                 )
                 if ckpt_path is None and manifest_rounds(config.checkpoint_dir):
                     raise RuntimeError(
@@ -995,7 +1002,7 @@ def run_simulation(
                 # any round holds); owned buffers, since the loop may
                 # donate the global model.
                 global_params = None
-                global_params = _owned_device_tree(ckpt["global_params"])
+                global_params = _place_resumed(tracer, ckpt["global_params"])
                 want_cs = jax.tree_util.tree_structure(client_state)
                 got_cs = jax.tree_util.tree_structure(ckpt["client_state"])
                 if want_cs != got_cs:
@@ -1024,7 +1031,7 @@ def run_simulation(
                 client_state = (
                     jax.tree_util.tree_map(np.asarray, ckpt["client_state"])
                     if streamed
-                    else _owned_device_tree(ckpt["client_state"])
+                    else _place_resumed(tracer, ckpt["client_state"])
                 )
                 start_round = ckpt["round_idx"] + 1
                 prev_metrics = ckpt["algo_state"].get("prev_metrics")
@@ -1056,7 +1063,7 @@ def run_simulation(
                                 "the configuration the checkpoint was written with"
                             )
                         # Donated by server_update_jit.
-                        server_state = _owned_device_tree(saved_ss)
+                        server_state = _place_resumed(tracer, saved_ss)
                 saved_async = ckpt["algo_state"].get("async_state")
                 if async_ctl is None and saved_async is not None:
                     raise ValueError(
@@ -2007,6 +2014,7 @@ def run_simulation(
                             ),
                             p["round_idx"], p["new_global"],
                             p["client_state"], algo_state, p["key"],
+                            tracer=tracer,
                         )
                         gc_checkpoints(config.checkpoint_dir,
                                        config.checkpoint_keep_last)
@@ -2041,9 +2049,10 @@ def run_simulation(
         preempted_at = None
         with ExitStack() as profile_stack:
             if recompile is not None:
-                # Scoped to the round loop: the monitor owns process-global
-                # logging state (jax_log_compiles + compile-logger capture),
-                # restored on exit even if a round raises.
+                # Scoped to the round loop: the monitor counts the loop's
+                # compiles for the records from here (its listeners have
+                # been on since the first line) and stops on exit, even if
+                # a round raises.
                 profile_stack.enter_context(recompile)
             if config.profile_dir and profile_from <= start_round:
                 profile_stack.enter_context(profile_session(config.profile_dir))

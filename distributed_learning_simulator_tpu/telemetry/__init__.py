@@ -24,9 +24,10 @@ engine) reports through:
   ``telemetry_level='detailed'`` asks for it, so the default program is
   untouched. The threaded oracle times its own phases with it.
 * :mod:`.recompile` — an XLA recompilation counter hooked on
-  ``jax.monitoring`` compile events (names recovered from the
-  ``jax_log_compiles`` log stream): any compile after the warmup round
-  flags a shape-instability bug with the offending function name.
+  ``jax.monitoring`` compile events, which name their program and, with
+  the persistent cache's hit / miss events, say whether it was compiled
+  or loaded: any compile after the warmup round flags a
+  shape-instability bug with the offending function name.
 * :mod:`.memory` — the ONE ``memory_stats()`` probe (HBM watermark +
   capacity), replacing the ad-hoc call sites that used to be duplicated
   in simulator.py and scripts/measure_gtg_scale.py.

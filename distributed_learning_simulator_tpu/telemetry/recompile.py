@@ -1,4 +1,4 @@
-"""XLA recompilation counter.
+"""XLA recompilation counter, and the ledger of the run's programs.
 
 A shape-stable simulation compiles each of its programs exactly once, in
 the first executed round (warmup: the round program, the eval program,
@@ -8,35 +8,36 @@ shape-instability bug that silently multiplies round cost (the round
 program's compile is tens of seconds at flagship scale) — so the round
 loop logs it as a WARNING with the offending function name.
 
-Two hooks, combined:
+Everything comes from jax's own events (jax 0.9.0,
+``jax._src.dispatch.log_elapsed_time``), through two ``jax.monitoring``
+listeners of one monitor object:
 
-* **Count** — a ``jax.monitoring`` duration listener on the
-  ``/jax/core/compile/backend_compile_duration`` event: fires once per
-  program LOWERED to the backend, including persistent-cache hits
-  (verified on the pinned jax: the event wraps compile_or_get_cached
-  unconditionally). That is the right instability signal — a cache hit
-  still means a NEW program shape was traced this round — but it means
-  the per-event duration, not the count alone, says whether the full
-  compile cost was paid.
-* **Names** — the monitoring event carries no function name in this JAX
-  version, so the monitor additionally flips ``jax_log_compiles`` on and
-  captures the ``"Finished XLA compilation of jit(<name>) …"`` lines
-  from the ``jax._src.dispatch`` logger. While the monitor is active,
-  propagation on the two chatty compile loggers is suspended so the
-  capture doesn't spam stderr; both the flag and propagation are
-  restored on ``stop()``.
+* **Durations** — ``jaxpr_trace_duration``, ``jaxpr_to_mlir_module_duration``
+  and ``backend_compile_duration`` (:data:`DURATION_EVENTS`), each with
+  the ``fun_name`` of its program: ``round_fn`` for a trace,
+  ``jit(round_fn)`` for a lowering and a compile. :func:`program_name`
+  makes those one name. The backend event fires once per program
+  LOWERED to the backend, whether XLA compiled it or it was loaded from
+  the persistent cache: a new program shape either way, the right
+  instability signal.
+* **The cache's outcome** — the plain events
+  ``/jax/compilation_cache/cache_hits`` (an executable loaded) and
+  ``/jax/compilation_cache/cache_misses`` (an executable compiled and
+  written as a new entry) fire inside the backend-compile event, on the
+  same thread (:data:`CACHE_EVENTS`). The next backend event on that
+  thread carries the mark; with neither signal it is ``"uncached"`` (no
+  cache, or an entry below the cache's thresholds).
 
-One monitor active per process at a time (it owns process-global logging
-state); the simulator scopes it to the round loop.
+The monitor touches no logger and no ``jax_log_compiles``.
 
-The monitor's is the program's ONE ``jax.monitoring`` listener. A span
-recorder (telemetry/spans.py) makes the run's monitor with an
-``on_event`` callback and calls :meth:`RecompileMonitor.listen` at the
-first line of ``run_simulation``: from then on every tracing, lowering
-and backend-compile duration event is handed to it stamped
-(:data:`DURATION_EVENTS`), so the op-by-op model init is seen. Counting
-and names still begin at :meth:`RecompileMonitor.start`, at the round
-loop, so the warm-up rule does not move.
+The monitor's listeners are the program's ONE pair. A span recorder
+(telemetry/spans.py) makes the run's monitor with an ``on_event``
+callback and calls :meth:`RecompileMonitor.listen` at the first line of
+``run_simulation``: from then on every tracing, lowering and
+backend-compile event is handed to it stamped, named and (compiles)
+marked, so the op-by-op model init is seen. Counting for the records
+still begins at :meth:`RecompileMonitor.start`, at the round loop, so
+the warm-up rule does not move.
 """
 
 from __future__ import annotations
@@ -59,32 +60,28 @@ DURATION_EVENTS = {
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
     _COMPILE_EVENT: "compile_s",
 }
-_COMPILE_LOGGER = "jax._src.dispatch"
-# "Compiling <fn> with global shapes…" (pxla) and "Persistent compilation
-# cache hit…" (compiler) log at the same forced-WARNING level; suspend
-# their propagation too while jax_log_compiles is on.
-_CHATTY_LOGGERS = (
-    _COMPILE_LOGGER,
-    "jax._src.interpreters.pxla",
-    "jax._src.compiler",
-)
-_FINISHED_RE = re.compile(
-    r"Finished XLA compilation of (?:jit\()?([^)\s]+)\)? in ([0-9.eE+-]+) sec"
-)
+#: The persistent cache's plain events -> the outcome they mark on the
+#: backend compile open on the same thread (jax 0.9.0:
+#: ``compiler.compile_or_get_cached`` signals a hit,
+#: ``compilation_cache.put_executable_and_time`` an entry written).
+CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+_JIT_WRAPPED = re.compile(r"jit\((.*)\)")
 
 
-class _CaptureHandler(logging.Handler):
-    def __init__(self, monitor: "RecompileMonitor"):
-        super().__init__(level=logging.DEBUG)
-        self._monitor = monitor
-
-    def emit(self, record: logging.LogRecord) -> None:
-        try:
-            m = _FINISHED_RE.search(record.getMessage())
-        except Exception:  # pragma: no cover - malformed record
-            return
-        if m:
-            self._monitor._record_name(m.group(1), float(m.group(2)))
+def program_name(name: str) -> str:
+    """The one name of a program across jax's events and the device
+    trace: ``jit(round_fn)`` (lowering and compile events),
+    ``jit_round_fn`` (the HLO module a profile names) and ``round_fn``
+    (the trace event) are all ``round_fn``."""
+    m = _JIT_WRAPPED.fullmatch(name)
+    if m:
+        name = m.group(1)
+    while name.startswith("jit_"):
+        name = name[len("jit_"):]
+    return name
 
 
 class RecompileMonitor:
@@ -101,89 +98,69 @@ class RecompileMonitor:
     """
 
     def __init__(self, on_event=None):
-        # ``on_event(counter, t_end, seconds)``: every DURATION_EVENTS
-        # event from listen() on, counted or not (the span recorder's).
+        # ``on_event(counter, t_end, seconds, program, cache)``: every
+        # DURATION_EVENTS event from listen() on, counted or not (the
+        # span recorder's); ``cache`` is the compile's outcome, ``None``
+        # on a trace or a lowering.
         self._on_event = on_event
         self._listening = False
         self._lock = threading.Lock()
-        self._count = 0          # monitoring-event ground truth
+        # The cache outcome signalled inside the backend compile open on
+        # each thread, until that compile's duration event takes it.
+        self._marks = threading.local()
         self._named: list[tuple[str, float]] = []
         self._per_round: dict[int, list[tuple[str, float]]] = {}
         self._active = False
-        self._handler: _CaptureHandler | None = None
-        self._saved_log_compiles = False
-        self._saved_propagate: dict[str, bool] = {}
-        self._null_handlers: dict[str, logging.Handler] = {}
 
     # -- listener callbacks ---------------------------------------------------
+    def _on_cache(self, event: str, **kwargs) -> None:
+        outcome = CACHE_EVENTS.get(event)
+        if outcome is not None:
+            self._marks.cache = outcome
+
     def _on_duration(self, event: str, duration: float, **kwargs) -> None:
         counter = DURATION_EVENTS.get(event)
         if counter is None:
             return
+        program = program_name(str(kwargs.get("fun_name", "")))
+        cache = None
+        if event == _COMPILE_EVENT:
+            cache = getattr(self._marks, "cache", None) or "uncached"
+            self._marks.cache = None
+            if self._active:
+                with self._lock:
+                    self._named.append((program, float(duration)))
         if self._on_event is not None:
-            self._on_event(counter, clock.monotonic(), float(duration))
-        if self._active and event == _COMPILE_EVENT:
-            with self._lock:
-                self._count += 1
-
-    def _record_name(self, name: str, seconds: float) -> None:
-        if not self._active:
-            return
-        with self._lock:
-            self._named.append((name, seconds))
+            self._on_event(counter, clock.monotonic(), float(duration),
+                           program, cache)
 
     # -- lifecycle ------------------------------------------------------------
     def listen(self) -> None:
-        """Register the ``jax.monitoring`` listener (idempotent) without
+        """Register the ``jax.monitoring`` listeners (idempotent) without
         counting yet: ``on_event`` sees the events from here on."""
         if not self._listening:
             self._listening = True
+            jax.monitoring.register_event_listener(self._on_cache)
             jax.monitoring.register_event_duration_secs_listener(
                 self._on_duration
             )
 
     def start(self) -> "RecompileMonitor":
-        if self._active:
-            return self
+        """Count from here on (and listen, if not yet)."""
         self._active = True
         self.listen()
-        self._handler = _CaptureHandler(self)
-        logging.getLogger(_COMPILE_LOGGER).addHandler(self._handler)
-        self._null_handlers = {}
-        for name in _CHATTY_LOGGERS:
-            lg = logging.getLogger(name)
-            self._saved_propagate[name] = lg.propagate
-            lg.propagate = False
-            # propagate=False alone is not silence: a record that finds NO
-            # handler anywhere falls through to logging.lastResort (which
-            # prints WARNINGs to stderr) — park a NullHandler so the
-            # forced-on compile chatter has a sink.
-            nh = logging.NullHandler()
-            self._null_handlers[name] = nh
-            lg.addHandler(nh)
-        self._saved_log_compiles = bool(jax.config.jax_log_compiles)
-        jax.config.update("jax_log_compiles", True)
         return self
 
     def stop(self) -> None:
+        """Stop counting and unregister the listeners (idempotent); what
+        was counted stays drainable."""
+        self._active = False
         if self._listening:
             self._listening = False
             jax.monitoring.unregister_event_duration_listener(
                 self._on_duration
             )
-        if not self._active:
-            return
-        self._active = False
-        jax.config.update("jax_log_compiles", self._saved_log_compiles)
-        for name, prop in self._saved_propagate.items():
-            logging.getLogger(name).propagate = prop
-        self._saved_propagate.clear()
-        for name, nh in getattr(self, "_null_handlers", {}).items():
-            logging.getLogger(name).removeHandler(nh)
-        self._null_handlers = {}
-        if self._handler is not None:
-            logging.getLogger(_COMPILE_LOGGER).removeHandler(self._handler)
-            self._handler = None
+            jax.monitoring.unregister_event_listener(self._on_cache)
 
     def __enter__(self) -> "RecompileMonitor":
         return self.start()
@@ -193,16 +170,10 @@ class RecompileMonitor:
 
     # -- draining -------------------------------------------------------------
     def drain(self) -> list[tuple[str, float]]:
-        """Pop the events recorded since the last drain as
-        ``[(fn_name, compile_seconds), ...]``. The monitoring count is the
-        ground truth; if a JAX upgrade changes the log format and names go
-        missing, the shortfall is padded with ``"<unknown>"`` entries so
-        the COUNT is never under-reported."""
+        """Pop the backend compiles counted since the last drain as
+        ``[(program, compile_seconds), ...]``, one per event."""
         with self._lock:
             named, self._named = self._named, []
-            count, self._count = self._count, 0
-        while len(named) < count:
-            named.append(("<unknown>", 0.0))
         return named
 
     def attribute(self, round_idx: int) -> None:

@@ -30,8 +30,14 @@ Counters sit at the same boundaries: completed rounds with their stamps
 fixed when the programs are built (:meth:`SpanRecorder.set_counter`), and
 the ``jax.monitoring`` durations of tracing, lowering and backend
 compiles (or cache loads), heard from the first line of the run by the
-run's ONE listener, the :class:`RecompileMonitor`'s (``trace_s``,
-``lower_s``, ``compile_s``; :meth:`SpanRecorder.counters`).
+run's ONE monitor, the :class:`RecompileMonitor` (``trace_s``,
+``lower_s``, ``compile_s``; :meth:`SpanRecorder.counters`). Each such
+event is a :class:`DurationEvent` that names its program (one name for
+``round_fn``, ``jit(round_fn)`` and ``jit_round_fn``:
+``recompile.program_name``) and, on a backend compile, says whether the
+persistent cache served it (``hit``), it was compiled and written to the
+cache (``miss``), or neither (``uncached``); :meth:`SpanRecorder.programs`
+is that ledger by program.
 
 At ``telemetry_level='off'`` with ``span_trace='off'`` there is no
 recorder: :class:`NullTracer` enters the profiler annotation and nothing
@@ -78,8 +84,10 @@ waits — the skew signal), ``dcn`` (payload collectives), ``stream``
 ``round_summary()`` folds a round's spans of these into the schema-v12
 ``spans`` record sub-object (``utils/reporting.py``). The envelopes of
 the in-memory record (:data:`ENVELOPE_CATS`: ``run`` the root, ``setup``
-the set-up sections, ``iter`` the per-iteration ``round`` span, ``host``
-for ``record``, ``checkpoint`` and ``valuation_audit``) enclose those
+the set-up sections and, in ``setup/resume``, ``resume/read`` and
+``resume/place``, ``iter`` the per-iteration ``round`` span, ``host``
+for ``record``, ``checkpoint`` (and its ``save/fetch``, ``save/write``)
+and ``valuation_audit``) enclose those
 leaves, so they stay out of ``seconds_by_cat``, of the span counts and
 of the stitcher's busy time: a leaf is counted once.
 
@@ -91,6 +99,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import dataclasses
 import itertools
 import json
 import os
@@ -171,6 +180,24 @@ def self_seconds(spans) -> dict[int, float]:
         )
         for s in spans if "dur" in s and "id" in s
     }
+
+
+@dataclasses.dataclass(frozen=True)
+class DurationEvent:
+    """One ``jax.monitoring`` tracing, lowering or backend-compile event:
+    the counter it feeds, when it ended (:func:`clock.monotonic`), its
+    seconds, its program (``recompile.program_name``) and, on a backend
+    compile, the cache's outcome (``hit``, ``miss`` or ``uncached``).
+    Unpacks as ``(counter, t_end, seconds)``."""
+
+    counter: str
+    t_end: float
+    seconds: float
+    program: str
+    cache: str | None
+
+    def __iter__(self):
+        return iter((self.counter, self.t_end, self.seconds))
 
 
 def _annotation(name: str, cat: str, round_idx):
@@ -257,8 +284,8 @@ class SpanRecorder(_Sections):
         # still held). Readers of a window check it (``evicted_until``).
         self._evicted_until: float | None = None
         # Counters: completed rounds with their stamps, completed
-        # host_sync spans, and the jax.monitoring durations (counter,
-        # t_end, secs) the run's monitor hands over from start() on.
+        # host_sync spans, and the jax.monitoring durations
+        # (DurationEvent) the run's monitor hands over from start() on.
         self.monitor = RecompileMonitor(on_event=self._on_duration)
         self._rounds = 0
         self._first_round_t: float | None = None
@@ -478,14 +505,18 @@ class SpanRecorder(_Sections):
                         name, 0
                     ) + int(np.sum(value))
 
-    def _on_duration(self, counter: str, t_end: float,
-                     seconds: float) -> None:
+    def _on_duration(self, counter: str, t_end: float, seconds: float,
+                     program: str, cache: str | None) -> None:
         """The monitor's ``on_event``: one tracing, lowering or backend
-        compile (or cache load) ended at ``t_end``."""
+        compile (or cache load) of ``program`` ended at ``t_end``."""
+        ev = DurationEvent(counter, t_end, seconds, program, cache)
         with self._lock:
-            (self._jax_setup if self._in_setup else self._jax_ring).append(
-                (counter, t_end, seconds)
-            )
+            if self._in_setup:
+                self._jax_setup.append(ev)
+                return
+            if len(self._jax_ring) == self._jax_ring.maxlen:
+                self._note_evicted_locked(self._jax_ring[0].t_end)
+            self._jax_ring.append(ev)
 
     def event(self, name: str, cat: str, round_idx: int | None = None,
               **attrs) -> None:
@@ -600,9 +631,10 @@ class SpanRecorder(_Sections):
 
     @property
     def evicted_until(self) -> float | None:
-        """End of the newest record the ring has evicted; ``None`` while
-        every span since the run's entry is still held. A reader whose
-        window begins before it is reading a window with holes."""
+        """End of the newest record the rings have evicted (a span, an
+        event or a ``jax.monitoring`` duration); ``None`` while every one
+        since the run's entry is still held. A reader whose window begins
+        before it is reading a window with holes."""
         with self._lock:
             return self._evicted_until
 
@@ -612,14 +644,52 @@ class SpanRecorder(_Sections):
         with self._lock:
             return list(self._round_stamps)
 
-    def duration_events(self) -> list[tuple[str, float, float]]:
-        """``(counter, t_end, seconds)`` per ``jax.monitoring`` duration
-        event: all of set-up's, the newest ``capacity`` after it. Traces
-        nest (a jitted function traced inside another's trace, or inside
-        a lowering), so sum them as intervals ``(t_end - seconds,
-        t_end)`` through :func:`union_seconds`, never as numbers."""
+    def duration_events(self) -> list[DurationEvent]:
+        """One :class:`DurationEvent` (unpacks as ``(counter, t_end,
+        seconds)``; also ``program`` and, on a compile, ``cache``) per
+        ``jax.monitoring`` tracing, lowering and backend-compile event:
+        all of set-up's, the newest ``capacity`` after it. Traces nest (a
+        jitted function traced inside another's trace, or inside a
+        lowering), so sum them as intervals ``(t_end - seconds, t_end)``
+        through :func:`union_seconds`, never as numbers."""
         with self._lock:
             return self._jax_setup + list(self._jax_ring)
+
+    def programs(self) -> dict[str, dict]:
+        """The ledger of programs: per program name, the intervals
+        ``(t0, t1)`` of its ``trace_s``, ``lower_s`` and ``compile_s``
+        events, and of its compiles by the cache's outcome (``cache``:
+        ``hit``, ``miss``, ``uncached``), each as ``[before, after]`` the
+        first round completed, as :meth:`counters` splits them::
+
+            {"round_fn": {"trace_s": [[(t0, t1), ...], [...]],
+                          "lower_s": ..., "compile_s": ...,
+                          "cache": {"hit": [[...], [...]], "miss": ...,
+                                    "uncached": ...}}}
+
+        A program traced inside another's trace is a program of its own
+        whose intervals nest in the other's: take unions, not sums."""
+        events = self.duration_events()
+        with self._lock:
+            cut = self._first_round_t
+        cut = float("inf") if cut is None else cut
+        out: dict[str, dict] = {}
+        for ev in events:
+            entry = out.get(ev.program)
+            if entry is None:
+                entry = out[ev.program] = {
+                    key: [[], []] for key in DURATION_EVENTS.values()
+                }
+                entry["cache"] = {
+                    outcome: [[], []]
+                    for outcome in ("hit", "miss", "uncached")
+                }
+            half = int(ev.t_end > cut)
+            iv = (ev.t_end - ev.seconds, ev.t_end)
+            entry[ev.counter][half].append(iv)
+            if ev.cache is not None:
+                entry["cache"][ev.cache][half].append(iv)
+        return out
 
     def counters(self) -> dict:
         """``trace_s`` / ``lower_s`` / ``compile_s`` as ``[before,
@@ -731,7 +801,7 @@ class SpanRecorder(_Sections):
             # The oldest entry goes. It is lost to the journal only if
             # it never reached one (always, when none is attached).
             old = self._ring[0]
-            self._evicted_until = (
+            self._note_evicted_locked(
                 old["t"] if "t" in old else old["t0"] + old["dur"]
             )
             if self._unflushed >= len(self._ring):
@@ -739,6 +809,10 @@ class SpanRecorder(_Sections):
                 self._unflushed -= 1
         self._ring.append(rec)
         self._unflushed += 1
+
+    def _note_evicted_locked(self, t: float) -> None:
+        if self._evicted_until is None or t > self._evicted_until:
+            self._evicted_until = t
 
     def _take_unflushed_locked(self) -> list[dict]:
         """The records no journal holds yet, oldest first; marks them

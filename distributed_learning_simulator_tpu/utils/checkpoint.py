@@ -24,6 +24,7 @@ promise (partial flush on power loss, bit rot, truncation in transit).
 
 from __future__ import annotations
 
+import contextlib
 import os
 import pickle
 import re
@@ -82,21 +83,42 @@ class _CrcWriter:
         return self._f.write(data)
 
 
+def _span(tracer, name: str, cat: str, round_idx: int | None = None):
+    """The run tracer's span ``name`` (telemetry/spans.py; a profiler
+    annotation of the same name too), or no span without a tracer."""
+    if tracer is None:
+        return contextlib.nullcontext()
+    return tracer.span(name, cat, round_idx=round_idx)
+
+
 def save_checkpoint(path: str, round_idx: int, global_params, client_state,
-                    algo_state: dict | None = None, rng_key=None) -> str:
-    payload = {
-        "round_idx": round_idx,
-        "global_params": jax.device_get(global_params),
-        "client_state": jax.device_get(client_state),
-        "algo_state": algo_state or {},
-        "rng_key": None if rng_key is None else jax.device_get(
-            jax.random.key_data(rng_key)
-        ),
-    }
-    return _write_framed(path, payload)
+                    algo_state: dict | None = None, rng_key=None,
+                    tracer=None) -> str:
+    """Write a whole checkpoint. ``tracer`` (the run's): the fetch from
+    the device is span ``save/fetch``, the pickle, CRC, write and rename
+    ``save/write``."""
+    with _span(tracer, "save/fetch", "host", round_idx):
+        payload = {
+            "round_idx": round_idx,
+            "global_params": jax.device_get(global_params),
+            "client_state": jax.device_get(client_state),
+            "algo_state": algo_state or {},
+            "rng_key": None if rng_key is None else jax.device_get(
+                jax.random.key_data(rng_key)
+            ),
+        }
+    with _span(tracer, "save/write", "host", round_idx):
+        return _write_framed(path, payload)
 
 
-def load_checkpoint(path: str) -> dict:
+def load_checkpoint(path: str, tracer=None) -> dict:
+    """Read, verify and unpickle one checkpoint file into host trees.
+    ``tracer`` (the run's): all of it is span ``resume/read``."""
+    with _span(tracer, "resume/read", "setup"):
+        return _read_checkpoint(path)
+
+
+def _read_checkpoint(path: str) -> dict:
     with open(path, "rb") as f:
         raw = f.read()
     if raw[: len(_MAGIC)] == _MAGIC:
@@ -190,18 +212,21 @@ def latest_checkpoint(directory: str) -> str | None:
     return rounds[-1][1] if rounds else None
 
 
-def load_latest_valid_checkpoint(directory: str) -> tuple[str | None, dict | None]:
+def load_latest_valid_checkpoint(
+    directory: str, tracer=None,
+) -> tuple[str | None, dict | None]:
     """Newest checkpoint that passes integrity verification.
 
     A corrupt/truncated/unreadable candidate is logged and skipped — a
     torn latest checkpoint costs one checkpoint interval of recomputation
     instead of the whole run. Returns ``(path, payload)`` or
-    ``(None, None)`` when nothing valid exists.
+    ``(None, None)`` when nothing valid exists. ``tracer``: each
+    candidate's read is a ``resume/read`` span (:func:`load_checkpoint`).
     """
     sweep_stale_tmps(directory)
     for _, path in reversed(checkpoint_rounds(directory)):
         try:
-            return path, load_checkpoint(path)
+            return path, load_checkpoint(path, tracer)
         except (CheckpointCorruptError, OSError) as e:
             get_logger().warning(
                 "checkpoint %s failed verification (%s); falling back to "

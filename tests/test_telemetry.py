@@ -38,48 +38,69 @@ from distributed_learning_simulator_tpu.utils.reporting import (
 
 def test_recompile_monitor_shape_unstable_run():
     """A deliberately shape-unstable jitted function trips the counter —
-    with its name — while the cached-shape call counts zero."""
+    with its name, from the compile event's ``fun_name`` — while the
+    cached-shape call counts zero."""
+    eight, nine = jnp.ones(8), jnp.ones(9)  # their own programs, outside
     mon = RecompileMonitor()
     with mon:
         @jax.jit
         def wobbly_step(x):
             return x * 2.0
 
-        wobbly_step(jnp.ones(8)).block_until_ready()
+        wobbly_step(eight).block_until_ready()
         mon.attribute(0)  # warmup: first shape compiles
-        wobbly_step(jnp.ones(8)).block_until_ready()
+        wobbly_step(eight).block_until_ready()
         mon.attribute(1)  # cached: no compile
-        wobbly_step(jnp.ones(9)).block_until_ready()  # NEW shape: recompile
+        wobbly_step(nine).block_until_ready()  # NEW shape: recompile
         mon.attribute(2)
     warmup, stable, unstable = mon.take(0), mon.take(1), mon.take(2)
-    assert any("wobbly_step" in name for name, _ in warmup)
+    assert [name for name, _ in warmup] == ["wobbly_step"]
     assert stable == []
-    assert any("wobbly_step" in name for name, _ in unstable)
+    assert [name for name, _ in unstable] == ["wobbly_step"]
+    assert all(secs > 0.0 for _, secs in warmup + unstable)
     # take() pops: a second read is empty.
     assert mon.take(2) == []
 
 
+_COMPILE_LOGGERS = (
+    "jax._src.dispatch", "jax._src.interpreters.pxla", "jax._src.compiler",
+)
+
+
+def _logging_state():
+    return (
+        bool(jax.config.jax_log_compiles),
+        [(logging.getLogger(n).propagate, list(logging.getLogger(n).handlers))
+         for n in _COMPILE_LOGGERS],
+    )
+
+
 def test_recompile_monitor_restores_global_state():
-    """start/stop must restore jax_log_compiles and the compile loggers'
-    propagation — the monitor owns process-global state only while
-    active."""
-    dispatch = logging.getLogger("jax._src.dispatch")
-    before_flag = bool(jax.config.jax_log_compiles)
-    before_prop = dispatch.propagate
-    before_handlers = list(dispatch.handlers)
+    """The monitor hears jax's events and nothing else: it leaves
+    ``jax_log_compiles`` and the compile loggers' propagation and
+    handlers exactly as it found them — no flip while it counts, none on
+    ``stop()`` — and unregisters both of its listeners."""
+    from jax._src import monitoring
+
+    before = _logging_state()
+    listeners = (list(monitoring.get_event_listeners()),
+                 list(monitoring.get_event_duration_listeners()))
+    five = jnp.ones(5)
     mon = RecompileMonitor().start()
-    assert bool(jax.config.jax_log_compiles) is True
-    assert dispatch.propagate is False
+    assert _logging_state() == before
+    jax.jit(lambda x: x + 3.0)(five).block_until_ready()
+    assert _logging_state() == before
+    assert [name for name, _ in mon.drain()] == ["<lambda>"]
     mon.stop()
-    assert bool(jax.config.jax_log_compiles) == before_flag
-    assert dispatch.propagate == before_prop
-    assert dispatch.handlers == before_handlers
+    assert _logging_state() == before
+    assert (list(monitoring.get_event_listeners()),
+            list(monitoring.get_event_duration_listeners())) == listeners
     mon.stop()  # idempotent
 
 
 def test_log_round_compiles_surfaces_offender_name():
-    """Post-warmup compiles WARN with the offending function name; warmup
-    compiles stay at INFO."""
+    """Post-warmup compiles WARN with the offending function name — the
+    name the compile event carries — and warmup compiles stay at INFO."""
     logger = logging.getLogger("test_telemetry_compiles")
     logger.propagate = True
     records = []
@@ -88,18 +109,24 @@ def test_log_round_compiles_surfaces_offender_name():
         def emit(self, r):
             records.append(r)
 
+    def round_fn(x):
+        return x * 5.0
+
+    seven = jnp.ones(7)
+    with RecompileMonitor() as mon:
+        jax.jit(round_fn)(seven).block_until_ready()
+    events = mon.drain()
+    assert [name for name, _ in events] == ["round_fn"]
     h = _Cap()
     logger.addHandler(h)
     logger.setLevel(logging.INFO)
     try:
-        n = log_round_compiles(
-            logger, 7, [("round_fn", 12.5)], warmup=False
-        )
+        n = log_round_compiles(logger, 7, events, warmup=False)
         assert n == 1
         warn = [r for r in records if r.levelno == logging.WARNING]
         assert len(warn) == 1
         msg = warn[0].getMessage()
-        assert "round_fn" in msg and "round 7" in msg
+        assert "round_fn (" in msg and "round 7" in msg
         assert "AFTER warmup" in msg
         records.clear()
         log_round_compiles(logger, 0, [("round_fn", 12.5)], warmup=True)
