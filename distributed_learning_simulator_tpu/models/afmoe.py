@@ -275,6 +275,19 @@ class AFMoE(nn.Module):
         return c.num_hidden_layers * parts.fused_attention_applies(
             positions, c.head_dim, c.dtype)
 
+    def _layers(self) -> tuple:
+        c = self.share
+        return tuple(
+            (f"layer_{layer}", (c, kind == SLIDING, layer < c.num_dense_layers))
+            for layer, kind in enumerate(c.layer_types))
+
+    def remat_blocks(self, params, tokens_shape) -> int:
+        """Leading blocks wrapped in ``nn.remat`` at a step of
+        ``tokens_shape`` (:func:`lm_parts.rematerialised_blocks`); the
+        recorder's counter of the same name."""
+        return parts.rematerialised_blocks(
+            _Block, self._layers(), params, tokens_shape, self.share)
+
     @nn.compact
     def __call__(self, tokens):
         if self.vocab_rows not in (None, self.num_classes):
@@ -288,13 +301,9 @@ class AFMoE(nn.Module):
             (("table", (vocab, D), D),), name="embed")()["table"]
         h = jnp.take(table, tokens, axis=0).astype(
             jnp.float32) * math.sqrt(D)
-        block = nn.remat(_Block)
         loads, overflows = [], []
-        for layer, kind in enumerate(c.layer_types):
-            h, routed = block(
-                c, kind == SLIDING, layer < c.num_dense_layers,
-                name=f"layer_{layer}",
-            )(h)
+        for block in parts.blocks(self, _Block, tokens.shape):
+            h, routed = block(h)
             if routed is not None:
                 loads.append(routed[0])
                 overflows.append(routed[1])

@@ -2,8 +2,9 @@
 models/afmoe.py, models/olmo_hybrid.py): softmax attention (every key at
 or before the query, or a band of them) in two forms, the gated delta
 rule in chunks (a decay per key channel or one per head), sigmoid routing,
-the experts told their share, a SwiGLU, and the head that makes its own
-loss.
+the experts told their share, a SwiGLU, the head that makes its own
+loss, and which leading blocks are rematerialised
+(:func:`rematerialised_blocks`: the rest keep their residuals).
 
 The attention's core (scores, mask, softmax, weighted values, forward and
 backward) has an XLA form, a block of queries at a time with the block's
@@ -34,6 +35,7 @@ from typing import Any, NamedTuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jex_core
 from jax.extend import source_info_util
 
 from distributed_learning_simulator_tpu.models.traced_helpers import (
@@ -41,6 +43,9 @@ from distributed_learning_simulator_tpu.models.traced_helpers import (
     ein as _ein,
     mm as _mm,
     rms_norm as _rms_norm,
+)
+from distributed_learning_simulator_tpu.telemetry.memory import (
+    device_memory_limit,
 )
 
 source_info_util.register_exclusion(__file__)
@@ -844,6 +849,115 @@ class UnmadeLogits:
     @classmethod
     def tree_unflatten(cls, aux, children):
         return cls(*children, *aux)
+
+
+# --- which blocks keep their activations for the backward ------------------
+
+#: The round program's peak with every block rematerialised, by what a
+#: model can see: bytes a parameter (12 of them the engine's state for the
+#: client in flight: the f32 global, the f32 sum of the updates, the
+#: client's bf16 copy and its bf16 gradient) and bytes a value of the
+#: residual stream a step carries (tokens x hidden: the stream between the
+#: blocks, the recomputed block, the head). Fitted to the three
+#: language-model cells on a v5e: peaks of 13.14, 15.18 and 16.45 GB
+#: read, 13.07, 15.10 and 16.26 counted (PERF.md § 6).
+FOOTPRINT_PER_PARAMETER = 15
+FOOTPRINT_PER_STREAM_VALUE = 148
+#: What keeping a block adds to that peak, as a share of what autodiff
+#: keeps of it (:func:`residual_bytes`): XLA fuses elementwise chains into
+#: their consumers, and the first block kept takes the place of the one
+#: recomputed. Read on a v5e, keeping the last one to four blocks of
+#: ``trinity_mini_fed_seq8k_c4``: 0.17, 0.25, 0.31, 0.34. Above all of
+#: them, for a need that is not monotone in the blocks kept (PERF.md § 6).
+KEPT_SHARE = 0.45
+#: The share of the device's ``bytes_limit`` the round may fill (14.8 of
+#: a v5e's 15.75 GiB). The rest covers the runtime holding more than the
+#: compiler counted, and a program that does not fit, which the compiler
+#: fits by recomputing on its own: the blocks are then slower than
+#: rematerialised and the peak reaches the limit (PERF.md § 6).
+REMAT_FILL = 0.94
+
+
+def leading_remat(n: int, last_first, room: float) -> int:
+    """How many LEADING blocks of a stack of ``n`` are rematerialised,
+    given the bytes each block's residuals would hold kept, LAST block
+    first (``last_first``, drawn only while they fit), and the ``room``
+    for them: the last blocks keep theirs while together they fit. A
+    block's residuals live from its forward to its own backward, and the
+    backward runs the blocks in reverse, so the first block's live
+    longest (over every later block's forward and backward): the first
+    blocks are the ones recomputed, and no block is recomputed after one
+    that keeps."""
+    if room <= 0:
+        return n
+    total = 0
+    for kept, size in enumerate(last_first):
+        total += size
+        if total > room:
+            return n - kept
+    return 0
+
+
+def residual_bytes(fn, *args) -> int:
+    """Bytes of what autodiff keeps from ``fn(*args)``'s forward for its
+    backward (``jax.linearize``'s residuals, the arguments themselves
+    left out: they are alive anyway), counted from shapes: ``args`` may
+    be ``jax.ShapeDtypeStruct``."""
+    jaxpr = jax.make_jaxpr(lambda *a: jax.linearize(fn, *a)[1])(*args).jaxpr
+    kept = {v for v in jaxpr.outvars if isinstance(v, jex_core.Var)}
+    return sum(v.aval.size * v.aval.dtype.itemsize
+               for v in kept - set(jaxpr.invars))
+
+
+@functools.lru_cache(maxsize=None)
+def _block_bytes(block, args, leaves, tree, stream) -> int:
+    module = block(*args, parent=None)
+    return residual_bytes(
+        lambda p, h: module.apply({"params": p}, h),
+        jax.tree_util.tree_unflatten(tree, leaves), stream)
+
+
+def rematerialised_blocks(block, layers, params, tokens_shape, share) -> int:
+    """How many leading blocks of a model's stack to wrap in ``nn.remat``
+    (:func:`leading_remat`), from the tokens a step, the model's widths
+    and the device's ``bytes_limit``. ``layers`` is ``((name, args of
+    block), ...)`` in order, ``params`` the model's parameters (arrays or
+    shapes; the client's copy is in the products' dtype, ``share.dtype``).
+    A kept block costs ``KEPT_SHARE`` of what autodiff keeps of it
+    (:func:`residual_bytes` at the stream ``[*tokens_shape,
+    share.hidden_size]`` f32); the room is ``REMAT_FILL`` of the limit
+    less the round's peak with every block rematerialised
+    (``FOOTPRINT_PER_*``)."""
+    stream = jax.ShapeDtypeStruct((*tokens_shape, share.hidden_size),
+                                  jnp.float32)
+    dtype = jnp.dtype(share.dtype)
+
+    def kept_bytes(name, args):
+        leaves, tree = jax.tree_util.tree_flatten(params[name])
+        return KEPT_SHARE * _block_bytes(block, args, tuple(
+            jax.ShapeDtypeStruct(x.shape, dtype) for x in leaves), tree,
+            stream)
+
+    n_params = sum(math.prod(x.shape) for x in jax.tree_util.tree_leaves(
+        params))
+    room = (REMAT_FILL * device_memory_limit()[0]
+            - n_params * FOOTPRINT_PER_PARAMETER
+            - math.prod(stream.shape) * FOOTPRINT_PER_STREAM_VALUE)
+    return leading_remat(len(layers), (
+        kept_bytes(*layer) for layer in reversed(layers)), room)
+
+
+def blocks(model, block, tokens_shape) -> list:
+    """``model``'s blocks (``model._layers()``: ``((name, args of block),
+    ...)`` in order), made inside its ``__call__``: the first
+    ``model.remat_blocks`` of them wrapped in ``nn.remat``; every one
+    while the parameters are made, as there are none yet to count."""
+    layers = model._layers()
+    remat = len(layers) if model.is_initializing() else model.remat_blocks(
+        model.variables["params"], tokens_shape)
+    wrapped = nn.remat(block)
+    return [(wrapped if i < remat else block)(*args, name=name)
+            for i, (name, args) in enumerate(layers)]
 
 
 # --- flax: parameters --------------------------------------------------------

@@ -238,6 +238,17 @@ class OlmoHybrid(nn.Module):
         return c.layer_types.count(FULL) * parts.fused_attention_applies(
             positions, c.head_dim, c.dtype)
 
+    def _layers(self) -> tuple:
+        return tuple((f"layer_{layer}", (self.share, kind == LINEAR))
+                     for layer, kind in enumerate(self.share.layer_types))
+
+    def remat_blocks(self, params, tokens_shape) -> int:
+        """Leading blocks wrapped in ``nn.remat`` at a step of
+        ``tokens_shape`` (:func:`lm_parts.rematerialised_blocks`); the
+        recorder's counter of the same name."""
+        return parts.rematerialised_blocks(
+            _Block, self._layers(), params, tokens_shape, self.share)
+
     @nn.compact
     def __call__(self, tokens):
         if self.vocab_rows not in (None, self.num_classes):
@@ -250,9 +261,8 @@ class OlmoHybrid(nn.Module):
         table = parts.Params(
             (("table", (vocab, D), 1),), name="embed")()["table"]
         h = jnp.take(table, tokens, axis=0).astype(jnp.float32)
-        block = nn.remat(_Block)
-        for layer, kind in enumerate(c.layer_types):
-            h = block(c, kind == LINEAR, name=f"layer_{layer}")(h)
+        for block in parts.blocks(self, _Block, tokens.shape):
+            h = block(h)
         x = parts.Norm(c.rms_norm_eps, name="final_norm")(h)
         kernel = parts.Params(
             (("kernel", (D, vocab), D),), name="lm_head")()["kernel"]

@@ -313,6 +313,18 @@ class SolarOpen2(nn.Module):
         return len(layers) * parts.fused_attention_applies(
             positions, c.head_dim, c.dtype)
 
+    def _layers(self) -> tuple:
+        c = self.share
+        return tuple((f"layer_{layer}", (c, layer in c.gqa_layers))
+                     for layer in range(c.num_hidden_layers))
+
+    def remat_blocks(self, params, tokens_shape) -> int:
+        """Leading blocks wrapped in ``nn.remat`` at a step of
+        ``tokens_shape`` (:func:`lm_parts.rematerialised_blocks`); the
+        recorder's counter of the same name."""
+        return parts.rematerialised_blocks(
+            _Block, self._layers(), params, tokens_shape, self.share)
+
     @nn.compact
     def __call__(self, tokens):
         if self.vocab_rows not in (None, self.num_classes):
@@ -324,12 +336,9 @@ class SolarOpen2(nn.Module):
         D, vocab = c.hidden_size, self.num_classes
         table = parts.Params((("table", (vocab, D), 1),), name="embed")()["table"]
         h = jnp.take(table, tokens, axis=0).astype(jnp.float32)
-        block = nn.remat(_Block)
         loads, overflows = [], []
-        for layer in range(c.num_hidden_layers):
-            h, (load, overflow) = block(
-                c, layer in c.gqa_layers, name=f"layer_{layer}"
-            )(h)
+        for block in parts.blocks(self, _Block, tokens.shape):
+            h, (load, overflow) = block(h)
             loads.append(load)
             overflows.append(overflow)
         x = parts.Norm(c.rms_norm_eps, name="final_norm")(h)

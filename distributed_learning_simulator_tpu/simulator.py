@@ -76,7 +76,7 @@ from distributed_learning_simulator_tpu.telemetry import (
     clock,
     costmodel_record,
     detect_and_record,
-    hbm_limit_bytes,
+    device_memory_limit,
     ledger_totals,
     log_round_compiles,
     peak_hbm_bytes,
@@ -117,33 +117,13 @@ def _f32_param_bytes(global_params) -> int:
     )
 
 
-def _device_memory_limit() -> tuple[int, str]:
-    """Per-device memory capacity and where the number came from.
-
-    An accelerator reports ``bytes_limit``; one that does not is an
-    error, not a guess. Only the CPU backend (which reports no memory
-    stats) gets an assumed 16 GiB, so the CPU tests size chunks the way
-    a v5e run would."""
-    limit = hbm_limit_bytes()
-    if limit:
-        return limit, "reported by the device"
-    platform = jax.local_devices()[0].platform
-    if platform != "cpu":
-        raise RuntimeError(
-            f"{platform} device reports no memory capacity "
-            "(memory_stats()['bytes_limit']); refusing to size "
-            "per-client state against an assumed one"
-        )
-    return 16 * 1024**3, "assumed: the cpu backend reports none"
-
-
 def _device_budget_bytes(config) -> float:
     """Usable device memory for per-client state: 60% of per-device HBM
-    (:func:`_device_memory_limit`) times the mesh size (the client axis
-    is split across mesh devices). The ONE copy of the budget model
-    shared by the chunk auto-sizer, the OOM hint, and the
+    (:func:`telemetry.memory.device_memory_limit`) times the mesh size
+    (the client axis is split across mesh devices). The ONE copy of the
+    budget model shared by the chunk auto-sizer, the OOM hint, and the
     materializing-path feasibility refusal."""
-    return 0.6 * _device_memory_limit()[0] * (config.mesh_devices or 1)
+    return 0.6 * device_memory_limit()[0] * (config.mesh_devices or 1)
 
 
 def _persistent_state_factor(config) -> int:
@@ -648,7 +628,7 @@ def run_simulation(
                     config, global_params, n_clients
                 ),
             )
-            limit, limit_source = _device_memory_limit()
+            limit, limit_source = device_memory_limit()
             logger.info(
                 "auto client_chunk_size=%d (footprint model, %s params, "
                 "%.1f GiB per device %s)",
@@ -858,6 +838,16 @@ def run_simulation(
             int(jax.devices()[0].platform == "tpu" and getattr(
                 model, "fused_attention_layers", lambda _: 0)(
                     dataset.x_train.shape[-1])),
+        )
+        # Leading blocks whose forward is recomputed in the backward
+        # (nn.remat): the model's choice at one local step's tokens and
+        # this device's memory (models/lm_parts.py
+        # rematerialised_blocks); 0 where no block is wrapped.
+        tracer.set_counter(
+            "remat_blocks",
+            int(getattr(model, "remat_blocks", lambda *_: 0)(
+                global_params, (config.batch_size,
+                                *dataset.x_train.shape[1:]))),
         )
 
         # Optional server-side optimizer (FedOpt; exceeds the reference): the

@@ -72,6 +72,7 @@ from distributed_learning_simulator_tpu.telemetry.costmodel import (
     predict_round,
 )
 from distributed_learning_simulator_tpu.telemetry.memory import (
+    device_memory_limit,
     device_memory_stats,
     hbm_limit_bytes,
     peak_hbm_bytes,
@@ -132,6 +133,7 @@ __all__ = [
     "costmodel_record",
     "detect_and_record",
     "detect_anomalies",
+    "device_memory_limit",
     "device_memory_stats",
     "get_topology",
     "hbm_limit_bytes",

@@ -38,11 +38,29 @@ def peak_hbm_bytes(device=None) -> int | None:
 
 def hbm_limit_bytes(device=None) -> int | None:
     """Usable device memory capacity (``bytes_limit``), or ``None`` when
-    unavailable. Feeds the footprint/budget model shared by the chunk
-    auto-sizer, the OOM hint, and the materializing-path feasibility
-    refusal (simulator._device_budget_bytes)."""
+    unavailable. Feeds :func:`device_memory_limit`."""
     stats = device_memory_stats(device)
     if not stats:
         return None
     limit = stats.get("bytes_limit")
     return int(limit) if limit else None
+
+
+def device_memory_limit() -> tuple[int, str]:
+    """Per-device memory capacity and where the number came from.
+
+    An accelerator reports ``bytes_limit``; one that does not is an
+    error, not a guess. Only the CPU backend (which reports no memory
+    stats) gets an assumed 16 GiB, so the CPU tests size chunks, and
+    choose which blocks to rematerialise, the way a v5e run would."""
+    limit = hbm_limit_bytes()
+    if limit:
+        return limit, "reported by the device"
+    platform = jax.local_devices()[0].platform
+    if platform != "cpu":
+        raise RuntimeError(
+            f"{platform} device reports no memory capacity "
+            "(memory_stats()['bytes_limit']); refusing to size "
+            "per-client state against an assumed one"
+        )
+    return 16 * 1024**3, "assumed: the cpu backend reports none"

@@ -699,7 +699,7 @@ class SpanRecorder(_Sections):
         given (``local_steps_unrolled``, ``client_axis_width``,
         ``head_backward_tied``, ``global_donated``, ``attention_window``,
         ``swa_keys_per_query_block``, ``fused_attention_layers``,
-        ``delta_rule_decay_width``)."""
+        ``delta_rule_decay_width``, ``remat_blocks``)."""
         events = self.duration_events()
         with self._lock:
             cut = self._first_round_t

@@ -13,7 +13,10 @@ compiles it with the TPU compiler installed here, which reproduces the
 chip's fusion names. Printed, as one JSON object a line:
 
 * ``memory``: the compiler's count (argument, output, alias, temporaries,
-  generated code; ``peak`` = temp + argument + output - alias) and ``compile_s``;
+  generated code; ``peak`` = temp + argument + output - alias), ``compile_s``
+  and ``remat_blocks`` (the leading blocks a language model recomputes,
+  chosen for a v5e's ``bytes_limit``; the CPU's assumed 16 GiB would
+  choose otherwise);
 * ``remat``: the instructions XLA's rematerialisation cloned (named
   ``<op>.remat...``) outside fused computations, and how many of them are
   PAIRS: ``<op>`` and ``<op>.remat`` both alive in one computation, so the
@@ -65,6 +68,9 @@ _CYCLES = re.compile(r'"estimated_cycles":"(\d+)"')
 #: these is the user that matters.
 _TRANSPARENT = ("bitcast", "get-tuple-element", "copy", "tuple",
                 "opt-barrier", "copy-start", "copy-done")
+#: ``memory_stats()["bytes_limit"]`` of one v5e chip, as the chip reports
+#: it (PERF.md § 6).
+V5E_BYTES_LIMIT = 16_909_336_064
 
 
 def frame_sources(text: str) -> dict:
@@ -192,8 +198,9 @@ def _load_file(path: str):
 
 
 def build_round(root: str, workload: str, seed: int):
-    """``(round_fn, argument shapes, donate_argnums)`` of the cell, built
-    the way ``run_simulation`` builds them (simulator.py, "programs")."""
+    """``(round_fn, argument shapes, donate_argnums, remat_blocks)`` of
+    the cell, built the way ``run_simulation`` builds them (simulator.py,
+    "programs"), the model's blocks chosen for a v5e's memory."""
     for path in (os.path.join(root, "benchmark"), root):
         sys.path.insert(0, path)
     import jax
@@ -210,6 +217,13 @@ def build_round(root: str, workload: str, seed: int):
     )
     from distributed_learning_simulator_tpu.simulator import build_client_data
 
+    from distributed_learning_simulator_tpu.models import lm_parts
+
+    # The blocks a language model keeps for the backward are chosen by the
+    # device's memory (lm_parts.rematerialised_blocks): the described
+    # chip's, not the CPU's assumed 16 GiB.
+    lm_parts.device_memory_limit = lambda: (
+        V5E_BYTES_LIMIT, "a described v5e")
     cell = bench_spec.load_cell(root, workload)
     if cell["cell"]["chips"] != 1:
         raise SystemExit("one described chip: a mesh cell is not built here")
@@ -244,7 +258,9 @@ def build_round(root: str, workload: str, seed: int):
         not pipelined and algorithm.supports_global_donation) else (1,)
     arrays = (client_data.x, client_data.y, client_data.mask,
               client_data.sizes)
-    return round_fn, params, arrays, donate
+    remat = getattr(model, "remat_blocks", lambda *_: 0)(
+        params, (config.batch_size, *dataset.x_train.shape[1:]))
+    return round_fn, params, arrays, donate, remat
 
 
 def main(argv=None) -> int:
@@ -265,7 +281,7 @@ def main(argv=None) -> int:
     # A compile for a described device can be written to the persistent
     # cache but never read back without the chip.
     jax.config.update("jax_enable_compilation_cache", False)
-    round_fn, params, arrays, donate = build_round(
+    round_fn, params, arrays, donate, remat = build_round(
         os.path.abspath(args.root), args.workload, args.seed)
     chip = SingleDeviceSharding(topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices[0])
@@ -289,7 +305,8 @@ def main(argv=None) -> int:
         "generated_code": m.generated_code_size_in_bytes,
         "peak": (m.temp_size_in_bytes + m.argument_size_in_bytes
                  + m.output_size_in_bytes - m.alias_size_in_bytes),
-    }, "donate_argnums": list(donate), "trace_lower_s": round(t1 - t0, 1),
+    }, "donate_argnums": list(donate), "remat_blocks": remat,
+        "trace_lower_s": round(t1 - t0, 1),
         "compile_s": round(t2 - t1, 1)}), flush=True)
     text = compiled.as_text()
     if args.dump:

@@ -742,14 +742,20 @@ def _loss_program(model, length):
 
 @pytest.mark.parametrize("name", ["afmoe", "solar_open2"])
 def test_a_models_loss_lowers_by_platform(name, monkeypatch):
-    """A model's loss and gradient, its blocks under ``nn.remat``: at
-    shapes the kernel takes the CPU's text holds no Pallas call and the
-    TPU's holds the kernel once a pass of each attention layer (forward,
-    the rematerialised forward, backward); at the tiny preset the text
-    is the one the XLA form alone lowers to (the parent's program)."""
+    """A model's loss and gradient, its blocks under ``nn.remat`` (no
+    room on the device): at shapes the kernel takes the CPU's text holds
+    no Pallas call and the TPU's holds the kernel once a pass of each
+    attention layer (forward, the rematerialised forward, backward), and
+    one fewer where the blocks keep their residuals (the CPU's 16 GiB:
+    no recomputed forward); at the tiny preset the text is the one the
+    XLA form alone lowers to (the parent's program)."""
     big, tiny = _lm(name)
     layers = big.fused_attention_layers(_POSITIONS)
     assert layers == (2 if name == "afmoe" else 1)
+    assert _loss_program(big, _POSITIONS).lower(
+        lowering_platforms=("tpu",)).as_text().count(
+            "tpu_custom_call") == 2 * layers
+    monkeypatch.setattr(parts, "device_memory_limit", lambda: (0, "none"))
     traced = _loss_program(big, _POSITIONS)
     assert "tpu_custom_call" not in traced.lower(
         lowering_platforms=("cpu",)).as_text()
@@ -838,6 +844,7 @@ def test_one_client_in_flight_through_run_simulation(tmp_path):
     assert counts["attention_window"] == 32
     assert counts["swa_keys_per_query_block"] == 48
     assert counts["fused_attention_layers"] == 0  # the CPU: the XLA form
+    assert counts["remat_blocks"] == 0  # 16 GiB: every block keeps
     assert counts["head_backward_tied"] == 1
     assert counts["client_axis_width"] == 1
     assert counts["global_donated"] == 1
@@ -853,7 +860,8 @@ def test_one_client_in_flight_through_run_simulation(tmp_path):
 def test_models_without_a_window_report_none(tmp_path):
     """``attention_window`` and ``swa_keys_per_query_block`` read 0 where
     no layer is windowed, ``fused_attention_layers`` where there is no
-    attention (and on every CPU)."""
+    attention (and on every CPU), ``remat_blocks`` where there are no
+    blocks."""
     from distributed_learning_simulator_tpu.config import get_config
     from distributed_learning_simulator_tpu.simulator import run_simulation
     from distributed_learning_simulator_tpu.telemetry import spans
@@ -870,3 +878,4 @@ def test_models_without_a_window_report_none(tmp_path):
     assert counts["attention_window"] == 0
     assert counts["swa_keys_per_query_block"] == 0
     assert counts["fused_attention_layers"] == 0
+    assert counts["remat_blocks"] == 0  # no block is wrapped in nn.remat

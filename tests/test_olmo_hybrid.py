@@ -397,6 +397,7 @@ def test_one_in_flight_fedavg_round_is_the_references(tmp_path):
     counts = spans.last_run().counters()
     assert counts["delta_rule_decay_width"] == 1
     assert counts["fused_attention_layers"] == 0  # the CPU: the XLA form
+    assert counts["remat_blocks"] == 0  # 16 GiB: every block keeps
     assert counts["head_backward_tied"] == 1
     assert counts["client_axis_width"] == 1
     assert counts["global_donated"] == 1
@@ -481,11 +482,17 @@ def test_share_refuses_what_it_cannot_hold():
 
 #: sha256 of the lowered text (``jax.jit(...).lower(...).as_text()``, CPU,
 #: this container's jax) of the engine's loss and its gradient for the
-#: other two language models at their tests' tiny presets, 2 sequences of
-#: 80 positions, at the parent commit (e46d60f), before the delta rule
-#: moved to models/lm_parts.py and ``gated_attention`` took an optional
-#: gate and a norm over the whole projection.
+#: language models at their tests' tiny presets, 2 sequences of 80
+#: positions, every block rematerialised: the other two models' at the
+#: commit (e46d60f) before the delta rule moved to models/lm_parts.py and
+#: ``gated_attention`` took an optional gate and a norm over the whole
+#: projection, this model's at the commit (967e0c6) before the blocks
+#: could keep their residuals.
 PARENT_LOWERED = {
+    ("olmo_hybrid", "float32"):
+        "6b8a5b4b21dc682cf60e8b66cdc42e0b09ef65fc34da2c4e31def3fe082cad1e",
+    ("olmo_hybrid", "bfloat16"):
+        "adae48d593bf064d8f381034833555219d92e5893e136135285e4cee8e19b0d9",
     ("solar_open2", "float32"):
         "74bfe690862036567f24c4ca58dfb932952282c24a8c89374baefb55abfb6e3c",
     ("solar_open2", "bfloat16"):
@@ -508,16 +515,21 @@ _PRESETS = {
         sliding_window=32, intermediate_size=96, num_experts=16,
         experts_held=4, num_experts_per_tok=2, moe_intermediate_size=32,
         query_block=16),
+    "olmo_hybrid": {k: v for k, v in share_args().items() if k != "dtype"},
 }
 
 
 @pytest.mark.parametrize("name,dtype", sorted(PARENT_LOWERED))
-def test_the_other_models_lower_to_the_parents_programs(name, dtype):
-    """Solar-Open2's KDA through the moved rule (a decay per key channel)
-    and both models' attention through ``gated_attention``'s defaults
-    (the gate, the per-head norm) lower, loss and gradient, to the text
-    they lowered to before: the same program, so the same numbers digit
-    for digit (a line number is not in the text)."""
+def test_the_other_models_lower_to_the_parents_programs(name, dtype,
+                                                        monkeypatch):
+    """With no room on the device every block is rematerialised
+    (models/lm_parts.py ``rematerialised_blocks``), and each model lowers,
+    loss and gradient, to the text it lowered to when every block always
+    was: Solar-Open2's KDA through the moved rule (a decay per key
+    channel), both other models' attention through ``gated_attention``'s
+    defaults (the gate, the per-head norm). The same program, so the same
+    numbers digit for digit (a line number is not in the text)."""
+    monkeypatch.setattr(parts, "device_memory_limit", lambda: (0, "none"))
     model = get_model(name, num_classes=96, **_PRESETS[name], dtype=dtype)
     tokens = jnp.zeros((2, 80), jnp.int32)
     params = jax.eval_shape(
