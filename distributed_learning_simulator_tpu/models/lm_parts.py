@@ -917,13 +917,14 @@ def _block_bytes(block, args, leaves, tree, stream) -> int:
         jax.tree_util.tree_unflatten(tree, leaves), stream)
 
 
-def rematerialised_blocks(block, layers, params, tokens_shape, share) -> int:
+def rematerialised_blocks(block, layers, params, tokens_shape, share,
+                          kept_share: float = KEPT_SHARE) -> int:
     """How many leading blocks of a model's stack to wrap in ``nn.remat``
     (:func:`leading_remat`), from the tokens a step, the model's widths
     and the device's ``bytes_limit``. ``layers`` is ``((name, args of
     block), ...)`` in order, ``params`` the model's parameters (arrays or
     shapes; the client's copy is in the products' dtype, ``share.dtype``).
-    A kept block costs ``KEPT_SHARE`` of what autodiff keeps of it
+    A kept block costs ``kept_share`` of what autodiff keeps of it
     (:func:`residual_bytes` at the stream ``[*tokens_shape,
     share.hidden_size]`` f32); the room is ``REMAT_FILL`` of the limit
     less the round's peak with every block rematerialised
@@ -934,7 +935,7 @@ def rematerialised_blocks(block, layers, params, tokens_shape, share) -> int:
 
     def kept_bytes(name, args):
         leaves, tree = jax.tree_util.tree_flatten(params[name])
-        return KEPT_SHARE * _block_bytes(block, args, tuple(
+        return kept_share * _block_bytes(block, args, tuple(
             jax.ShapeDtypeStruct(x.shape, dtype) for x in leaves), tree,
             stream)
 

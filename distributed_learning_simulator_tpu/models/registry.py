@@ -17,6 +17,7 @@ from distributed_learning_simulator_tpu.models.cnn import (
     CifarCNN,
     TpuCifarCNN,
 )
+from distributed_learning_simulator_tpu.models.jamba import jamba
 from distributed_learning_simulator_tpu.models.lenet import LeNet5
 from distributed_learning_simulator_tpu.models.olmo_hybrid import olmo_hybrid
 from distributed_learning_simulator_tpu.models.resnet import ResNet18, ResNet34
@@ -42,6 +43,10 @@ _MODELS = {
     # decay a head) beside ungated full attention; ``--model_args`` the
     # vocabulary held (vocab_rows).
     "olmohybrid": olmo_hybrid,
+    # Jamba's family: Mamba-1 mixers (a selective scan) beside MQA, a
+    # dense pre-norm stack, a tied head; ``--model_args`` the share
+    # (channels_held, heads_held, mlp_columns_held, vocab_rows).
+    "jamba": jamba,
 }
 
 

@@ -849,6 +849,17 @@ def run_simulation(
                 global_params, (config.batch_size,
                                 *dataset.x_train.shape[1:]))),
         )
+        # Layers that mix by the selective scan (models/jamba.py), and 1
+        # where its kernels run (ops/selective_scan.py: the model's shapes
+        # at this data's positions, on a TPU); 0 for the XLA form and
+        # where no layer mixes by it.
+        tracer.set_counter("ssm_layers", int(getattr(model, "ssm_layers", 0)))
+        tracer.set_counter(
+            "selective_scan_kernel",
+            int(jax.devices()[0].platform == "tpu" and getattr(
+                model, "selective_scan_kernel", lambda _: 0)(
+                    dataset.x_train.shape[-1])),
+        )
 
         # Optional server-side optimizer (FedOpt; exceeds the reference): the
         # aggregate is post-processed by a jitted pseudo-gradient step.

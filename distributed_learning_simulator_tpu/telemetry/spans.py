@@ -699,7 +699,8 @@ class SpanRecorder(_Sections):
         given (``local_steps_unrolled``, ``client_axis_width``,
         ``head_backward_tied``, ``global_donated``, ``attention_window``,
         ``swa_keys_per_query_block``, ``fused_attention_layers``,
-        ``delta_rule_decay_width``, ``remat_blocks``)."""
+        ``delta_rule_decay_width``, ``remat_blocks``, ``ssm_layers``,
+        ``selective_scan_kernel``)."""
         events = self.duration_events()
         with self._lock:
             cut = self._first_round_t

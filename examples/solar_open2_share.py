@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
 """Federated next-token fine-tuning of one chip's share of a language
-model (Solar-Open2, or with ``--config trinity_mini`` Trinity-Mini, or with
-``--config olmo_hybrid`` a pipeline stage of Olmo-Hybrid-7B), through the
+model (Solar-Open2, or with ``--config trinity_mini`` Trinity-Mini, with
+``--config olmo_hybrid`` a pipeline stage of Olmo-Hybrid-7B, or with
+``--config jamba`` a tensor-parallel rank of AI21-Jamba2-3B), through the
 program's normal path: ``get_config(argv)`` and one
 ``run_simulation(config, dataset=...)``.
 
@@ -9,6 +10,7 @@ program's normal path: ``get_config(argv)`` and one
     python examples/solar_open2_share.py --size cell   # one v5e chip
     python examples/solar_open2_share.py --config trinity_mini --size tiny
     python examples/solar_open2_share.py --config olmo_hybrid --size tiny
+    python examples/solar_open2_share.py --config jamba --size tiny
 
 ``cell`` is the argv of the benchmark's configuration
 (``benchmark/configs/solar_open2_250b_l4_ep40_tp8.json`` +
@@ -27,7 +29,12 @@ olmo_hybrid`` is ``benchmark/configs/olmo_hybrid_7b_l4_vp8.json``
 (``--model_name olmo_hybrid``: Gated DeltaNet with one decay a head, keys
 of 96 and values of 192, beside ungated full attention, a dense post-norm
 block, 12,544 vocabulary rows, 929 M parameters, sequences of 4,096); its
-tiny preset has 2 heads, keys of 16 and values of 32. The data is the benchmark's seeded
+tiny preset has 2 heads, keys of 16 and values of 32. ``--config jamba``
+is ``benchmark/configs/jamba2_3b_l14_tp4_vp8.json`` (``--model_name
+jamba``: Mamba-1 mixers, a selective scan over 1,280 of 5,120 channels
+with a state of 16, beside MQA over 5 of 20 heads, a tied head, 8,192
+vocabulary rows, 379 M parameters, sequences of 16,384); its tiny preset
+holds 32 of 128 channels with a state of 4. The data is the benchmark's seeded
 Markov source (``benchmark/tasks/next_token.py``); token datasets are
 handed in, the dataset registry holds images.
 """
@@ -62,10 +69,18 @@ TINY_OLMO = {
     "linear_key_head_dim": 16, "linear_value_head_dim": 32,
     "intermediate_size": 96, "vocab_rows": 96, "dtype": "float32",
 }
+TINY_JAMBA = {
+    "hidden_size": 64, "num_hidden_layers": 4, "attn_layer_period": 4,
+    "attn_layer_offset": 1, "num_attention_heads": 4, "heads_held": 2,
+    "channels_held": 32, "mamba_d_state": 4, "mamba_dt_rank": 8,
+    "intermediate_size": 96, "mlp_columns_held": 48, "vocab_rows": 96,
+    "dtype": "float32",
+}
 CONFIGS = {
     "solar_open2": ("solar_open2_250b_l4_ep40_tp8", TINY_MODEL),
     "trinity_mini": ("trinity_mini_26b_l5_ep8_vp8", TINY_AFMOE),
     "olmo_hybrid": ("olmo_hybrid_7b_l4_vp8", TINY_OLMO),
+    "jamba": ("jamba2_3b_l14_tp4_vp8", TINY_JAMBA),
 }
 
 

@@ -35,6 +35,12 @@ chip's fusion names. Printed, as one JSON object a line:
 ``--root`` compiles another checkout's program (the parent's, unpacked with
 ``git archive``), ``--dump`` keeps the compiled module's text, ``--source``
 prints every instruction traced from lines that match (``solar_open2.py:36``).
+``lowered_sha256`` is the sha256 of the lowered program's text: two
+checkouts whose cell lowers to the same text run the same program. A Pallas
+kernel's serialized body carries the source paths of the checkout it was
+traced in, so compare two sides lowered from the same path (unpack each in
+turn into one directory). ``--lower_only`` prints it and stops before the
+compile.
 
 A compile that passes is not a chip run: no time, rate or share comes from
 here. Imported by neither the package nor the benchmark.
@@ -44,6 +50,7 @@ from __future__ import annotations
 
 import argparse
 import collections
+import hashlib
 import importlib.util
 import json
 import os
@@ -270,6 +277,8 @@ def main(argv=None) -> int:
         os.path.dirname(os.path.abspath(__file__))))
     parser.add_argument("--seed", type=int, default=0)
     parser.add_argument("--dump", help="write the compiled module here")
+    parser.add_argument("--lower_only", action="store_true",
+                        help="print the lowered text's sha256 and stop")
     parser.add_argument("--source", help="list instructions traced from "
                         "lines that contain this (file.py:line prefix)")
     args = parser.parse_args(argv)
@@ -294,6 +303,12 @@ def main(argv=None) -> int:
     t0 = time.perf_counter()
     lowered = jax.jit(round_fn, donate_argnums=donate).lower(*shapes, key)
     t1 = time.perf_counter()
+    lowered_sha256 = hashlib.sha256(lowered.as_text().encode()).hexdigest()
+    if args.lower_only:
+        print(json.dumps({"lowered_sha256": lowered_sha256,
+                          "remat_blocks": remat,
+                          "trace_lower_s": round(t1 - t0, 1)}), flush=True)
+        return 0
     compiled = lowered.compile()
     t2 = time.perf_counter()
     m = compiled.memory_analysis()
@@ -306,6 +321,7 @@ def main(argv=None) -> int:
         "peak": (m.temp_size_in_bytes + m.argument_size_in_bytes
                  + m.output_size_in_bytes - m.alias_size_in_bytes),
     }, "donate_argnums": list(donate), "remat_blocks": remat,
+        "lowered_sha256": lowered_sha256,
         "trace_lower_s": round(t1 - t0, 1),
         "compile_s": round(t2 - t1, 1)}), flush=True)
     text = compiled.as_text()
