@@ -44,6 +44,7 @@ from distributed_learning_simulator_tpu.models.traced_helpers import (
     mm as _mm,
     rms_norm as _rms_norm,
 )
+from distributed_learning_simulator_tpu.ops import delta_rule as _delta
 from distributed_learning_simulator_tpu.telemetry.memory import (
     device_memory_limit,
 )
@@ -450,6 +451,32 @@ def _unit_lower_inverse(L):
 
 def chunked_delta_rule(q, k, v, log_decay, beta, dtype=jnp.bfloat16,
                        chunk: int = DELTA_CHUNK, sub: int = DELTA_SUB):
+    """The gated delta rule of :func:`chunked_delta_rule_xla` (shapes,
+    arguments and result as there) in the form the code can observe to be
+    the right one:
+
+    * a decay per key channel, or shapes the kernels do not take
+      (``ops/delta_rule.kernel_applies``): the XLA form, as ever;
+    * else ``lax.platform_dependent``: the Pallas kernels of
+      ``ops/delta_rule.py``, forward and backward, where the program is
+      LOWERED for a TPU (a compile for a described chip under
+      ``JAX_PLATFORMS=cpu`` included), the XLA form on any other platform
+      (the CPU tests' path, and the oracle).
+
+    No flag, no configuration field: as :func:`attention_core`."""
+    xla = functools.partial(chunked_delta_rule_xla, dtype=dtype, chunk=chunk,
+                            sub=sub)
+    scalar = log_decay.ndim == 3 or log_decay.shape[-1] == 1
+    if not (scalar and _delta.kernel_applies(q.shape[1], q.shape[-1],
+                                             v.shape[-1], chunk)):
+        return xla(q, k, v, log_decay, beta)
+    return jax.lax.platform_dependent(
+        q, k, v, log_decay, beta, default=xla,
+        tpu=functools.partial(_delta.delta_rule, dtype=dtype))
+
+
+def chunked_delta_rule_xla(q, k, v, log_decay, beta, dtype=jnp.bfloat16,
+                           chunk: int = DELTA_CHUNK, sub: int = DELTA_SUB):
     """``S_t = (I - b_t k_t k_t^T) Diag(a_t) S_(t-1) + b_t k_t v_t^T``,
     ``o_t = S_t^T q_t``, ``S_0 = 0``, in chunks of ``chunk`` positions.
 

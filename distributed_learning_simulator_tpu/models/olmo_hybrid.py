@@ -50,6 +50,7 @@ from distributed_learning_simulator_tpu.models.traced_helpers import (
     mm as _mm,
     rms_norm as _rms_norm,
 )
+from distributed_learning_simulator_tpu.ops import delta_rule as _delta
 
 LINEAR, FULL = "linear_attention", "full_attention"
 
@@ -228,6 +229,16 @@ class OlmoHybrid(nn.Module):
         position the delta rule is given (one a head here), 0 where no
         layer mixes by it."""
         return int(LINEAR in self.share.layer_types)
+
+    def delta_rule_kernel_layers(self, positions: int) -> int:
+        """Linear layers whose delta rule the Pallas kernels take where the
+        program is lowered for a TPU, at sequences of ``positions``
+        (:func:`ops.delta_rule.kernel_applies`: one decay a head, as here);
+        the recorder's counter of the same name is this on a TPU and 0
+        anywhere else."""
+        c = self.share
+        return c.layer_types.count(LINEAR) * _delta.kernel_applies(
+            positions, c.linear_key_head_dim, c.linear_value_head_dim)
 
     def fused_attention_layers(self, positions: int) -> int:
         """Full layers whose attention core is the fused kernel where the

@@ -860,6 +860,17 @@ def run_simulation(
                 model, "selective_scan_kernel", lambda _: 0)(
                     dataset.x_train.shape[-1])),
         )
+        # Layers whose delta rule runs as the Pallas kernels
+        # (ops/delta_rule.py, chosen in models/lm_parts.py
+        # chunked_delta_rule: one decay a head, the model's shapes at this
+        # data's positions, on a TPU); 0 for the XLA form and where no
+        # layer mixes by the rule.
+        tracer.set_counter(
+            "delta_rule_kernel_layers",
+            int(jax.devices()[0].platform == "tpu" and getattr(
+                model, "delta_rule_kernel_layers", lambda _: 0)(
+                    dataset.x_train.shape[-1])),
+        )
 
         # Optional server-side optimizer (FedOpt; exceeds the reference): the
         # aggregate is post-processed by a jitted pseudo-gradient step.

@@ -700,7 +700,7 @@ class SpanRecorder(_Sections):
         ``head_backward_tied``, ``global_donated``, ``attention_window``,
         ``swa_keys_per_query_block``, ``fused_attention_layers``,
         ``delta_rule_decay_width``, ``remat_blocks``, ``ssm_layers``,
-        ``selective_scan_kernel``)."""
+        ``selective_scan_kernel``, ``delta_rule_kernel_layers``)."""
         events = self.duration_events()
         with self._lock:
             cut = self._first_round_t

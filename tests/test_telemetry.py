@@ -557,6 +557,7 @@ def test_local_steps_unrolled_counter(tiny_config, tmp_path, batch_size,
         ("event", "remat_blocks", 0),  # no block is wrapped in nn.remat
         ("event", "ssm_layers", 0),  # no layer mixes by the selective scan
         ("event", "selective_scan_kernel", 0),
+        ("event", "delta_rule_kernel_layers", 0),  # no delta rule, a CPU
         ("event", "global_donated", 0),  # a pipelined loop keeps the global
     ]
     spec = importlib.util.spec_from_file_location(
